@@ -88,28 +88,38 @@ def _tile(A, m, n):
     return c.payload
 
 
-def backward_error(A, orig_tile: Callable[[int, int], object]) -> float:
+def backward_error(A, orig_tile: Callable[[int, int], object],
+                   device=None) -> float:
     """Exact ||A - L L^T||_F / ||A||_F over the lower triangle of the
     factored tile grid (the effective symmetric A: lower tiles as
     generated, diagonal tiles symmetrized from their lower triangle —
-    Cholesky never read anything else)."""
+    Cholesky never read anything else).
+
+    ``device``: a jax device every tile is brought to as it is used —
+    needed when the factor is spread over several devices (one jitted
+    product cannot take operands committed to different devices)."""
+    import jax
     import jax.numpy as jnp
     k = _kernels()
     NT = A.mt
     num = 0.0
     den = 0.0
 
+    def here(t):
+        t = jnp.asarray(t)
+        return t if device is None else jax.device_put(t, device)
+
     def L_of(i, j):
         # diagonal factor tiles are lower-triangularized ON USE (the
         # tile's upper triangle holds stale A values chol never wrote);
         # no f32 copies are cached — at bench scale (nt=16, mb=6144)
         # cached trils would cost GBs of HBM next to the resident grid
-        t = jnp.asarray(_tile(A, i, j))
+        t = here(_tile(A, i, j))
         return jnp.tril(t.astype(jnp.float32)) if i == j else t
 
     for i in range(NT):
         for j in range(i + 1):
-            O = jnp.asarray(orig_tile(i, j))
+            O = here(orig_tile(i, j))
             A0 = k["symm"](O) if i == j else O.astype(jnp.float32)
             den += float(k["sqn"](A0))
             if i != j:

@@ -140,7 +140,10 @@ def _k(name, maker):
     return fn
 
 
-def _mk_geqrt(ib: int = 0, pallas_gram: bool = False):
+def _mk_geqrt(ib: int = 0, gram=None):
+    """``gram``: replacement for the panel blocks' Gram products
+    (apps/pallas_kernels.pallas_gram_tile); None = the fused XLA
+    matmul."""
     def fn(T, Q):
         import jax
         import jax.numpy as jnp
@@ -169,10 +172,6 @@ def _mk_geqrt(ib: int = 0, pallas_gram: bool = False):
             # explicit — the blocks ARE its orthonormal columns — so
             # the q1 edge and UNMQR are unchanged.
             up = _update_precision()
-            gram = None
-            if pallas_gram:
-                from parsec_tpu.apps.pallas_kernels import pallas_gram_tile
-                gram = pallas_gram_tile()
             A = Tf
             R = jnp.zeros((mb, mb), jnp.float32)
             Qacc = None
@@ -425,7 +424,8 @@ def qr_taskpool(A: TiledMatrix, device: str = "tpu") -> ParameterizedTaskpool:
     # they key the kernel memo so an MCA change cannot alias a stale jit
     ib = effective_ib(mb)
     upd = str(params.get("qr_update_precision", "default")).lower()
-    from parsec_tpu.apps.pallas_kernels import use_pallas_qr_gram
+    from parsec_tpu.apps.pallas_kernels import (pallas_gram_tile,
+                                                use_pallas_qr_gram)
     pg = use_pallas_qr_gram()
     # Owner-computes discipline for the final R tiles: the LAST TSQRT of
     # column k (and the last TSMQR of each row-k tile) runs where
@@ -470,7 +470,8 @@ def qr_taskpool(A: TiledMatrix, device: str = "tpu") -> ParameterizedTaskpool:
     def cpu_geqrt(T, Q):
         q, r = np.linalg.qr(np.asarray(T), mode="complete")
         return {"T": r, "Q": q}
-    bodies(tb, _k(("geqrt", ib, upd, pg), lambda: _mk_geqrt(ib, pg)),
+    bodies(tb, _k(("geqrt", ib, upd, pg),
+                  lambda: _mk_geqrt(ib, pallas_gram_tile() if pg else None)),
            cpu_geqrt)
 
     # UNMQR(k, n): apply Q1^T across the k-th block row

@@ -2,12 +2,13 @@
 accuracy ladder (VERDICT r5 #9 — mirror of apps/potrf_check.py's
 HPL-AI story for the dgeqrf-class driver).
 
-The bench's factorization residual (||R^T R z - A^T A z|| / ||A^T A z||,
-bench.py) bounds how good the FACTOR is: bf16 tile storage rounds R to
-~bf16 epsilon, so the raw residual sits at the 1e-2/1e-3 class.  What
-justifies low-precision storage is the same contract as potrf's
-``refine_solve``: the factor is a fine PRECONDITIONER, and the accuracy
-is recovered where it is consumed — the least-squares/linear solve.
+The factorization residual (||R^T R z - A^T A z|| / ||A^T A z||,
+``factorization_residual`` below) bounds how good the FACTOR is: bf16
+tile storage rounds R to ~bf16 epsilon, so the raw residual sits at the
+1e-2/1e-3 class. What justifies low-precision storage is the same
+contract as potrf's ``refine_solve``: the factor is a fine
+PRECONDITIONER, and the accuracy is recovered where it is consumed — the
+least-squares/linear solve.
 
 ``ls_refine`` solves A x = b through the corrected semi-normal
 equations (CSNE; Björck's refinement for QR factors): with R from the
@@ -126,6 +127,36 @@ def _rtr_solve(A, b):
             rhs = rhs - jnp.matmul(_r_tile(A, i, j), x[j])
         x[i] = k["trsv"](_r_tile(A, i, i), rhs, trans=False)
     return x
+
+
+def factorization_residual(A, orig_tile: Callable[[int, int], object],
+                           seed: int = 123) -> float:
+    """Stochastic factorization check WITHOUT storing Q: an orthogonal
+    QR satisfies R^T R = A^T A, so compare the two quadratic forms on a
+    random probe vector — ||R^T R z - A^T A z|| / ||A^T A z||, O(n^2)
+    matvecs, tile-streamed.  R is the result sitting in A's tiles (upper
+    block triangle; TSQRT zeroed the rest); the original A regenerates
+    from ``orig_tile``."""
+    import jax.numpy as jnp
+    k = _kernels()
+    NT, mb = A.mt, A.mb
+    rng = np.random.default_rng(seed)
+    z = [jnp.asarray(rng.standard_normal(mb).astype(np.float32))
+         for _ in range(NT)]
+    zero = jnp.zeros(mb, jnp.float32)
+    v = [zero] * NT                      # R z
+    for i in range(NT):
+        for j in range(i, NT):
+            v[i] = k["mv"](v[i], _r_tile(A, i, j), z[j])
+    y1 = [zero] * NT                     # R^T (R z)
+    for i in range(NT):
+        for j in range(i, NT):
+            y1[j] = k["mtv"](y1[j], _r_tile(A, i, j), v[i])
+    y2 = _matvec_t(orig_tile, NT, _matvec(orig_tile, NT, z))
+    num = float(jnp.sqrt(sum(jnp.sum((a - b) ** 2)
+                             for a, b in zip(y1, y2))))
+    den = float(jnp.sqrt(sum(jnp.sum(b ** 2) for b in y2)))
+    return num / den if den else float("nan")
 
 
 def ls_refine(A, orig_tile: Callable[[int, int], object],

@@ -54,6 +54,20 @@ params.register("comm_ici_permute_min", 2,
                 "smaller flushes fall back to per-edge puts")
 
 
+def permute_program(mesh, perm):
+    """ONE CollectivePermute over the mesh's device axis ``d``: shard i of
+    the stacked operand goes to shard j for every (i, j) of ``perm``,
+    the other shards come back zero (``lax.ppermute`` semantics)."""
+    import jax
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    def body(t):
+        return lax.ppermute(t, "d", perm)
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=P("d"), out_specs=P("d")))
+
+
 class IciStats:
     __slots__ = ("puts", "put_bytes", "bcasts", "bcast_bytes",
                  "permutes", "permute_edges", "permute_bytes")
@@ -215,7 +229,6 @@ class IciEngine:
     def _permute_round(self, shape, round_edges):
         import jax
         import jax.numpy as jnp
-        from jax import lax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         mesh = self.mesh()
@@ -249,18 +262,7 @@ class IciEngine:
         with self._lock:
             prog = self._prog_cache.get(key)
             if prog is None:
-                try:
-                    from jax import shard_map
-                except ImportError:
-                    # jax 0.4.x ships it under experimental only (the
-                    # top-level alias landed in 0.5); same callable
-                    from jax.experimental.shard_map import shard_map
-
-                def body(t):
-                    return lax.ppermute(t, "d", perm)
-                prog = jax.jit(shard_map(
-                    body, mesh=mesh, in_specs=P("d"), out_specs=P("d")))
-                self._prog_cache[key] = prog
+                prog = self._prog_cache[key] = permute_program(mesh, perm)
         with self._launch_lock:
             # dispatch AND completion inside the lock: async dispatch
             # alone could still leave per-device enqueues of two

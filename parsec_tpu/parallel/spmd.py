@@ -58,7 +58,6 @@ def summa_gemm_fn(mesh, precision: Optional[str] = None) -> Callable:
     """
     import jax
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     @jax.jit
     def sharded(a, b):
@@ -66,7 +65,7 @@ def summa_gemm_fn(mesh, precision: Optional[str] = None) -> Callable:
             a_row = jax.lax.all_gather(a_blk, "q", axis=1, tiled=True)
             b_col = jax.lax.all_gather(b_blk, "p", axis=0, tiled=True)
             return jax.numpy.matmul(a_row, b_col, precision=precision)
-        fm = shard_map(f, mesh=mesh,
+        fm = jax.shard_map(f, mesh=mesh,
                        in_specs=(P("p", "q"), P("p", "q")),
                        out_specs=P("p", "q"))
         return fm(a, b)
@@ -85,7 +84,6 @@ def ring_reduce_gemm_fn(mesh, axis: str = "p",
     """
     import jax
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     @jax.jit
     def sharded(a, b):
@@ -93,7 +91,7 @@ def ring_reduce_gemm_fn(mesh, axis: str = "p",
             part = jax.numpy.matmul(a_blk, b_blk, precision=precision)
             return jax.lax.psum_scatter(part, axis, scatter_dimension=0,
                                         tiled=True)
-        fm = shard_map(f, mesh=mesh,
+        fm = jax.shard_map(f, mesh=mesh,
                        in_specs=(P(None, axis), P(axis, None)),
                        out_specs=P(axis, None))
         return fm(a, b)
@@ -109,7 +107,6 @@ def halo_stencil_fn(mesh, axis: str = "p", radius: int = 1,
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     n = mesh.shape[axis]
     fwd = [(i, (i + 1) % n) for i in range(n)]
@@ -126,7 +123,7 @@ def halo_stencil_fn(mesh, axis: str = "p", radius: int = 1,
                 return new, None
             u, _ = jax.lax.scan(step, x_blk, None, length=steps)
             return u
-        fm = shard_map(f, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
+        fm = jax.shard_map(f, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
         return fm(x)
 
     return sharded

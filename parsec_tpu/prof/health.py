@@ -114,7 +114,10 @@ class HealthMonitor:
         self._thr_crit = float(params.get("health_critical", 0.5))
         self._hyst = float(params.get("health_hysteresis", 0.05))
         self._ranks: Dict[int, _RankHealth] = {}
-        self._last_fold = 0.0
+        #: -inf, not 0.0: time.monotonic() counts from boot, so a 0.0
+        #: stamp would rate-limit the FIRST fold away on a host whose
+        #: uptime is below health_interval_s
+        self._last_fold = float("-inf")
         #: counter baselines (self signals fold as window deltas)
         self._strag_base = 0.0
         self._bail_base: Optional[float] = None

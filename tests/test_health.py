@@ -451,6 +451,12 @@ def test_flightrec_bundle_carries_health_and_comm_delta(tmp_path):
                   "flightrec_min_interval_s", "health_interval_s"):
             params.unset(k)
     assert bundle is not None
+    # the dump runs on its own thread and writes incidents.jsonl last:
+    # reading before that finds health-rank0.json created but empty
+    manifest = os.path.join(bundle, "incidents.jsonl")
+    deadline = time.monotonic() + 30.0
+    while not os.path.exists(manifest) and time.monotonic() < deadline:
+        time.sleep(0.02)
     path = os.path.join(bundle, "health-rank0.json")
     assert os.path.exists(path)
     with open(path) as fh:

@@ -56,3 +56,29 @@ def test_ls_refine_recovers_from_bf16_storage():
     hist = ls_refine(A, orig, steps=4)
     assert hist[0] > 1e-4               # bf16 factor alone is NOT f32
     assert min(hist) <= 1e-6            # ladder recovers f32-class
+
+
+@pytest.mark.parametrize("storage,lo,hi", [("float32", 0.0, 1e-5),
+                                           ("bfloat16", 1e-5, 2e-2)])
+def test_factorization_residual_tracks_the_factor(storage, lo, hi):
+    """R^T R = A^T A on a random probe (what chip_smoke.py and bench.py
+    hold the geqrf factor to): f32 storage sits at f32 class, bf16
+    storage at bf16 class, and a damaged R tile is seen."""
+    import ml_dtypes
+    import jax.numpy as jnp
+    from parsec_tpu.apps.qr_check import factorization_residual
+    dtype = np.float32 if storage == "float32" else ml_dtypes.bfloat16
+    rng = np.random.default_rng(5)
+    n = 64
+    a = (0.05 * rng.standard_normal((n, n)) + np.eye(n)).astype(np.float32)
+    A, mb = _factor(a, dtype)
+    ar = a.astype(dtype).astype(np.float32)      # what was factored
+    orig = lambda i, j: jnp.asarray(
+        ar[i * mb:(i + 1) * mb, j * mb:(j + 1) * mb])
+    res = factorization_residual(A, orig)
+    assert lo <= res < hi
+    # repeatable: the probe vector is seeded
+    assert factorization_residual(A, orig) == res
+    d = A.data_of(0, 1)
+    d.overwrite_host(2.0 * np.asarray(d.pull_to_host().payload))
+    assert factorization_residual(A, orig) > 10 * max(res, 1e-3)

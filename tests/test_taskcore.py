@@ -319,14 +319,24 @@ def test_two_rank_shm_fast_complete_interleave():
         assert nat[r]["counts"] == py[r]["counts"]
         assert nat[r]["nb_tasks"] == py[r]["nb_tasks"] == 0
         assert nat[r]["pending"] == py[r]["pending"] == 0
-        # the ONLY tasks that left the C chain are the 4 cross-rank R
-        # tasks this rank owns: a remote ToTask successor bails at
-        # plan time (comm_buffered), the final writeback task bails
-        # statically — the 24/2 E and 6 S tasks contributed ZERO,
-        # which is the comm-attached fast-complete property
+        # the ONLY tasks that may leave the C chain are the 4
+        # cross-rank R tasks this rank owns: a remote ToTask successor
+        # bails at plan time (comm_buffered), the final writeback task
+        # bails statically — the 24/2 E and 6 S tasks contributed ZERO,
+        # which is the comm-attached fast-complete property (a
+        # regression would add this rank's 18 local tasks).  AT MOST 4,
+        # not exactly 4: an R task whose activation lands while the
+        # worker idles is picked up by the idle probe (spin poll, or
+        # the doorbell's pre-wait probe) and goes straight to the
+        # Python path without entering the C chain, so it counts no
+        # bailout — how many of the 4 are counted is timing, and on a
+        # loaded host fewer are (3 under the six-worker run).  The
+        # reasons are not timing: comm_buffered for an R task with a
+        # remote successor, non_trivial for the last R task's writeback
         bail = nat[r]["bailouts"]
-        assert sum(bail.values()) == 4, bail
-        assert bail.get("comm_buffered", 0) >= 3, bail
+        assert sum(bail.values()) <= 4, bail
+        assert set(bail) <= {"comm_buffered", "non_trivial"}, bail
+        assert bail.get("non_trivial", 0) <= 1, bail
     # cross-rank chain value: tile k ends at k+1, merged across ranks
     merged = {}
     for r in nat:

@@ -1,6 +1,11 @@
 #!/bin/sh
-# Pre-merge bench smoke: run the CPU-only host-side probes and diff each
-# against the last driver artifact (BENCH_r*.json) with bench_guard.
+# Pre-merge bench smoke: run the CPU-only host-side probes and hold each
+# to its ABSOLUTE gate.  (Until PR 21 every probe line was also diffed
+# with tools/bench_guard.py against the newest BENCH_r*.json in the
+# repo; those artifacts held one GEMM metric that no probe shares, so
+# the diff compared nothing, and they were deleted with the rest of the
+# remote-chip records.  bench_guard.py itself stays: --prev FILE diffs
+# any two bench lines.)
 #
 # These probes time the Python+TCP runtime layers (no accelerator), so
 # they run anywhere in ~3 minutes and catch scheduler/transport
@@ -16,9 +21,7 @@
 # edges).  Since r14 the gate bounds the ABSOLUTE per-task tracing
 # cost ($trace_bound_us, default 8 us/task; measured ~2.3 on the
 # 1-core container, down from r7's ~5.4) instead of a ratio — see the
-# usage note.  The tracing-OFF cost staying ~0 is covered by the
-# default tasks probe itself: its task_throughput gates against the
-# last driver artifact above.
+# usage note.
 #
 # r8 adds the CHAOS smoke: a seeded subset of tools/chaos.py fault
 # plans (delayed v0 DTD payload, hard rank kill, transient task faults
@@ -36,7 +39,7 @@
 # process, gating on the MINIMUM pair reading — host-load noise
 # contaminates single pairs in either direction but a real regression
 # shows in all of them; the JSON records both the ratio and
-# overhead_us, and bench_guard compares them by absolute delta).
+# overhead_us).
 #
 # r16 adds the JOURNAL-OVERHEAD gate (control-plane black box,
 # prof/journal.py): the tasks probe armed vs off through bench.py's
@@ -48,7 +51,9 @@
 #
 # Usage:  sh tools/premerge_bench.sh [threshold] [trace_bound_us] \
 #             [telemetry_bound_us] [native_margin] [journal_bound_us]
-#         threshold:   relative regression that fails (default 0.15)
+#         threshold:   unused since PR 21 (was bench_guard's relative
+#             regression bound); kept so the positions of the other
+#             arguments do not move
 #         trace_bound_us: max ABSOLUTE tracing cost in us/task
 #             (default 8.0).  r14 changed this gate from a ratio to an
 #             absolute bound: at the 482k+/s headline (~2 us/task) the
@@ -67,18 +72,16 @@
 #             native-path bailout (coverage, not just speed)
 # r11 adds the NATIVE-vs-PYTHON pairing: the tasks probe (which runs
 # with the native scheduler hot path by default) is re-run with
-# PARSEC_MCA_SCHED_NATIVE=0 — the fallback line goes through
-# bench_guard like every probe (a fallback regression fails), and the
-# native line must (a) actually have the native path active in its
-# JSON (sched_native=1 — a silently-degraded build is a no-op native
-# path) and (b) beat the fallback by >= $native_margin (default 5%).
-# The shm transport gets its own rtt probe through bench_guard (the
-# same-host ring must keep beating the loopback-TCP artifact).
+# PARSEC_MCA_SCHED_NATIVE=0 — the native line must (a) actually have
+# the native path active in its JSON (sched_native=1 — a
+# silently-degraded build is a no-op native path) and (b) beat the
+# fallback by >= $native_margin (default 5%).  The shm transport gets
+# its own rtt probe (it must run to a result).
 #
 # r17 adds the FABRIC smoke (multi-tenant serving fabric,
 # service/fabric.py): the bench fabric probe (many small jobs/s with
 # p50/p99 admission->completion latency, self-auditing its journal)
-# goes through bench_guard like every probe, and a carved-subset smoke
+# must run clean, and a carved-subset smoke
 # runs 3 concurrent tenants on disjoint exclusive device subsets of an
 # 8-device CPU mesh plus one temporal-sharing job, then replays the
 # journal through tools/journal_audit.py's F1/F2/F3 fabric invariants
@@ -92,7 +95,6 @@
 # bench cycle is spent; a violation fails the premerge outright.
 set -e
 repo="$(cd "$(dirname "$0")/.." && pwd)"
-threshold="${1:-0.15}"
 trace_bound="${2:-8.0}"
 telemetry_bound="${3:-0.5}"
 rc=0
@@ -121,16 +123,12 @@ fi
 rm -rf "$scratch"
 for mode in tasks rtt bw; do
     echo "== premerge probe: $mode =="
-    out="/tmp/premerge_${mode}_$$.json"
+    out="${TMPDIR:-/tmp}/premerge_${mode}_$$.json"
     if ! JAX_PLATFORMS=cpu PARSEC_BENCH_APP=$mode \
          python "$repo/bench.py" > "$out" 2>/dev/null; then
         echo "premerge: $mode probe FAILED to run"
         rc=1
         continue
-    fi
-    if ! python "$repo/tools/bench_guard.py" "$out" --repo "$repo" \
-         --threshold "$threshold"; then
-        rc=1
     fi
     if [ "$mode" = tasks ]; then
         tasks_off="$out"     # kept for the tracer-overhead comparison
@@ -139,7 +137,7 @@ for mode in tasks rtt bw; do
     fi
 done
 echo "== premerge probe: tracer overhead (tasks, tracing on) =="
-on="/tmp/premerge_tasks_on_$$.json"
+on="${TMPDIR:-/tmp}/premerge_tasks_on_$$.json"
 if [ -n "$tasks_off" ] && JAX_PLATFORMS=cpu PARSEC_BENCH_APP=tasks \
      PARSEC_BENCH_TRACE=1 python "$repo/bench.py" > "$on" 2>/dev/null; then
     if ! python - "$tasks_off" "$on" "$trace_bound" <<'EOF'
@@ -167,15 +165,9 @@ else
 fi
 echo "== premerge probe: native-vs-python A/B (tasks) =="
 native_margin="${4:-1.05}"
-fb="/tmp/premerge_tasks_fb_$$.json"
+fb="${TMPDIR:-/tmp}/premerge_tasks_fb_$$.json"
 if [ -n "$tasks_off" ] && JAX_PLATFORMS=cpu PARSEC_BENCH_APP=tasks \
      PARSEC_MCA_SCHED_NATIVE=0 python "$repo/bench.py" > "$fb" 2>/dev/null; then
-    # the FALLBACK path regressing is as pre-merge-fatal as the native
-    # one: every probe artifact before r11 was measured on it
-    if ! python "$repo/tools/bench_guard.py" "$fb" --repo "$repo" \
-         --threshold "$threshold"; then
-        rc=1
-    fi
     if ! python - "$tasks_off" "$fb" "$native_margin" <<'EOF'
 import json, sys
 def last_json(path):
@@ -214,17 +206,13 @@ echo "== premerge probe: native-vs-python A/B (ntasks, data-carrying chains) =="
 # non-empty reason means data tasks silently popped back to Python
 # and the number no longer measures the chain).
 ntasks_margin="${6:-1.3}"
-nt_nat="/tmp/premerge_ntasks_$$.json"
-nt_fb="/tmp/premerge_ntasks_fb_$$.json"
+nt_nat="${TMPDIR:-/tmp}/premerge_ntasks_$$.json"
+nt_fb="${TMPDIR:-/tmp}/premerge_ntasks_fb_$$.json"
 if JAX_PLATFORMS=cpu PARSEC_BENCH_APP=ntasks \
      python "$repo/bench.py" > "$nt_nat" 2>/dev/null \
    && JAX_PLATFORMS=cpu PARSEC_BENCH_APP=ntasks \
      PARSEC_MCA_SCHED_NATIVE=0 python "$repo/bench.py" > "$nt_fb" \
      2>/dev/null; then
-    if ! python "$repo/tools/bench_guard.py" "$nt_nat" --repo "$repo" \
-         --threshold "$threshold"; then
-        rc=1
-    fi
     if ! python - "$nt_nat" "$nt_fb" "$ntasks_margin" <<'EOF'
 import json, sys
 def last_json(path):
@@ -263,16 +251,11 @@ rm -f "$nt_nat" "$nt_fb"
 echo "== premerge probe: aggregate multi-rank throughput (shm) =="
 # r17: N same-host ranks over shm, each with a live RemoteDepEngine —
 # comm-attached fast-complete must keep every (purely local) task on
-# the C chain: zero comm_buffered bailouts, on top of the bench_guard
-# diff of the aggregate headline.  Self-scales N to the core count
+# the C chain: zero comm_buffered bailouts.  Self-scales N to the core count
 # (N=2 smoke on a 1-core host, with the skip reason in the JSON).
-agg="/tmp/premerge_aggregate_$$.json"
+agg="${TMPDIR:-/tmp}/premerge_aggregate_$$.json"
 if JAX_PLATFORMS=cpu PARSEC_BENCH_APP=aggregate \
      python "$repo/bench.py" > "$agg" 2>/dev/null; then
-    if ! python "$repo/tools/bench_guard.py" "$agg" --repo "$repo" \
-         --threshold "$threshold"; then
-        rc=1
-    fi
     if ! python - "$agg" <<'EOF'
 import json, sys
 def last_json(path):
@@ -304,20 +287,15 @@ else
 fi
 rm -f "$agg"
 echo "== premerge probe: shm transport rtt =="
-shmout="/tmp/premerge_shm_rtt_$$.json"
-if JAX_PLATFORMS=cpu PARSEC_BENCH_APP=rtt PARSEC_MCA_COMM_TRANSPORT=shm \
+shmout="${TMPDIR:-/tmp}/premerge_shm_rtt_$$.json"
+if ! JAX_PLATFORMS=cpu PARSEC_BENCH_APP=rtt PARSEC_MCA_COMM_TRANSPORT=shm \
      python "$repo/bench.py" > "$shmout" 2>/dev/null; then
-    if ! python "$repo/tools/bench_guard.py" "$shmout" --repo "$repo" \
-         --threshold "$threshold"; then
-        rc=1
-    fi
-else
     echo "premerge: shm rtt probe FAILED to run"
     rc=1
 fi
 rm -f "$shmout"
 echo "== premerge probe: telemetry overhead (metrics + flight recorder + liveattr armed) =="
-tel="/tmp/premerge_telemetry_$$.json"
+tel="${TMPDIR:-/tmp}/premerge_telemetry_$$.json"
 if JAX_PLATFORMS=cpu PARSEC_BENCH_APP=telemetry \
      python "$repo/bench.py" > "$tel" 2>/dev/null; then
     if ! python - "$tel" "$telemetry_bound" <<'EOF'
@@ -353,7 +331,7 @@ echo "== premerge probe: journal overhead (control-plane black box armed) =="
 # armed-vs-off must read ~0 us/task.  The absolute bound proves the C
 # run_quantum fast path never crosses the journal.
 journal_bound="${5:-0.3}"
-jnl="/tmp/premerge_journal_$$.json"
+jnl="${TMPDIR:-/tmp}/premerge_journal_$$.json"
 if JAX_PLATFORMS=cpu PARSEC_BENCH_APP=journal \
      python "$repo/bench.py" > "$jnl" 2>/dev/null; then
     if ! python - "$jnl" "$journal_bound" <<'EOF'
@@ -385,14 +363,9 @@ else
 fi
 rm -f "$jnl"
 echo "== premerge probe: fabric serving (jobs/s + latency, self-audited) =="
-fab="/tmp/premerge_fabric_$$.json"
-if JAX_PLATFORMS=cpu PARSEC_BENCH_APP=fabric \
+fab="${TMPDIR:-/tmp}/premerge_fabric_$$.json"
+if ! JAX_PLATFORMS=cpu PARSEC_BENCH_APP=fabric \
      python "$repo/bench.py" > "$fab" 2>/dev/null; then
-    if ! python "$repo/tools/bench_guard.py" "$fab" --repo "$repo" \
-         --threshold "$threshold"; then
-        rc=1
-    fi
-else
     echo "premerge: fabric probe FAILED to run"
     rc=1
 fi
