@@ -1045,13 +1045,23 @@ class CommEngine:
         return out
 
     # -- fault injection (utils/faultinject.py hook points) -------------
-    def _arm_kill(self) -> None:
-        """Schedule this rank's kill_rank directive, if any."""
+    def _arm_kill(self, hold: bool = False) -> None:
+        """Schedule this rank's kill_rank directive, if any, ``at_s``
+        from now.  Called when the transport comes up; whoever knows a
+        better zero for the plan's clock calls it again
+        (comm/launch._worker: ``hold`` stops the pending timer while the
+        rank starts up, the call after the start-up barrier starts it
+        anew)."""
         if self._fault is None or self._fault.kill is None:
             return
+        pending = getattr(self, "_kill_timer", None)
+        if pending is not None:
+            pending.cancel()
+        if hold:
+            return
         k = self._fault.kill
-        t = threading.Timer(max(0.0, k.at_s), self.fault_kill,
-                            args=(k.mode,))
+        t = self._kill_timer = threading.Timer(
+            max(0.0, k.at_s), self.fault_kill, args=(k.mode,))
         t.daemon = True
         t.start()
 

@@ -66,9 +66,20 @@ def _worker(rank: int, nranks: int, port_base: int, nb_cores: int,
         # the spawned children): evloop (default) or threads (the old
         # per-peer-thread path, kept for A/B attribution)
         ce = make_ce(rank, nranks, port_base)
+        # A fault plan's clock (kill_rank=<r>@t+<s>s) starts when user
+        # code does, at the start-up barrier below, not when the
+        # transport came up: bringing the Context up and meeting the
+        # barrier takes 0.2-0.95 s from run to run and more on a loaded
+        # host, and with that inside the clock a kill "at t+1.0s" fired
+        # before the victim had run one task in 11 of 20 runs (PR 25),
+        # or inside start-up, where it breaks the barrier.  (The
+        # transport still comes up first: its listener must not wait
+        # for the Context, peers dial it against a deadline.)
+        ce._arm_kill(hold=True)
         ctx = Context(nb_cores=nb_cores, rank=rank, nranks=nranks)
         rde = RemoteDepEngine(ce, ctx)
         ce.barrier()   # every rank's handlers are wired before user code
+        ce._arm_kill()
         try:
             result = fn(ctx, rank, nranks, *args)
             ce.barrier()

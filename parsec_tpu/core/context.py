@@ -183,6 +183,11 @@ class Context:
         #: _recompute_ready_stamp on (un)install
         self._ready_stamp = False
         self._device_spans = False
+        #: whether a thread-state span opened now would be recorded
+        #: (prof/pins.py open_span); the sink that records them installs
+        #: its own probe here
+        from parsec_tpu.prof.pins import no_span_sink
+        self._span_live = no_span_sink
         #: transient-task retry budget, cached off the worker hot path
         #: (core/scheduling.task_progress probes it per task)
         self._retry_max = int(params.get("task_retry_max", 0))
@@ -277,6 +282,16 @@ class Context:
         # pins_init + per-thread PINS THREAD_INIT, parsec.c bring-up)
         from parsec_tpu.prof.pins import install_selected
         self._pins_modules = install_selected(self)
+
+        # thread-state spans onto the profiler's clock (prof/pins.py
+        # TraceMePins; records only while a jax.profiler session runs).
+        # Where no XLA device attached, jax may not even be imported
+        # and there is no device timeline to hold the spans against
+        self._traceme = None
+        if len(self.devices) > 1:
+            from parsec_tpu.prof.pins import TraceMePins
+            self._traceme = TraceMePins()
+            self._traceme.install(self)
 
         # telemetry plane: the always-on metrics registry (PAPI-SDE
         # counterpart grown into a scrapeable registry) and the
@@ -722,6 +737,8 @@ class Context:
             unins = getattr(mod, "uninstall", None)
             if unins is not None:   # reference: pins_fini unregisters
                 unins(self)
+        if self._traceme is not None:
+            self._traceme.uninstall(self)
         if self.metrics is not None:
             self.metrics.uninstall(self)
         if self._flightrec is not None:

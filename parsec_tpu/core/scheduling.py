@@ -18,6 +18,7 @@ from parsec_tpu.core import engine
 from parsec_tpu.core.errors import FaultInjected, TaskRetryExhausted
 from parsec_tpu.core.task import HookReturn, Task, TaskStatus
 from parsec_tpu.data.data import ACCESS_WRITE
+from parsec_tpu.prof.pins import open_span
 from parsec_tpu.utils import faultinject as _fi
 from parsec_tpu.utils.output import debug_verbose, warning
 
@@ -450,6 +451,9 @@ def worker_loop(es) -> None:
     misses = 0
     done_since = 0
     n = 0
+    #: the open ``worker.idle`` span: one for a whole spin / doorbell
+    #: episode, from the first miss to the task that ends it
+    idle = None
     while not ctx.finished:
         sel_fired = False
         if quantum is not None:
@@ -457,6 +461,9 @@ def worker_loop(es) -> None:
             # the C quantum fires select before handing a task back
             sel_fired = task is not None
             if n:
+                if idle is not None:
+                    idle.end()
+                    idle = None
                 misses = 0
                 done_since += n
                 if done_since >= batch:
@@ -476,6 +483,8 @@ def worker_loop(es) -> None:
             if es._td_acc:
                 _td_flush(es)
                 done_since = 0
+            if idle is None:
+                idle = open_span(es, "worker.idle", th=es.th_id)
             misses += 1
             ctx.flush_ici()
             # re-read per idle moment, not cached at loop start: a comm
@@ -497,6 +506,9 @@ def worker_loop(es) -> None:
                     min(0.0002 * (1 << min(misses, 8)), 0.05), probe)
             if task is None:
                 continue
+        if idle is not None:
+            idle.end()
+            idle = None
         misses = 0
         # select fires exactly once per task: the C quantum already
         # fired it for tasks IT hands back; spin/doorbell tasks and
@@ -511,6 +523,8 @@ def worker_loop(es) -> None:
         if done_since >= batch:
             _td_flush(es)
             done_since = 0
+    if idle is not None:
+        idle.end()
     while es._td_acc:   # worker exit: drain re-entrant deposits too
         _td_flush(es)
     debug_verbose(9, "worker %d: %d tasks", es.th_id, es.nb_tasks_done)

@@ -26,7 +26,9 @@ class DeviceStats:
 
     __slots__ = ("executed_tasks", "bytes_in", "bytes_out", "faults",
                  "evictions", "fused_launches", "fused_tasks",
-                 "chained_launches", "chained_tasks")
+                 "chained_launches", "chained_tasks", "launches",
+                 "held_tasks", "defused_waves", "starved_waits",
+                 "inflight_waits", "compiles")
 
     def __init__(self):
         self.executed_tasks = 0
@@ -43,6 +45,20 @@ class DeviceStats:
         #: wave) rode them (devices/xla.py device_fuse_panel)
         self.chained_launches = 0
         self.chained_tasks = 0
+        #: counts at the boundaries of the device module's spans
+        #: (devices/xla.py, PERF.md section 3): jitted calls; chain heads
+        #: parked without a dispatch (executed_tasks + held_tasks is
+        #: every task); waves dispatched as singles because their fused
+        #: width was not ready; manager episodes with an empty queue;
+        #: launches that waited for room under device_inflight_depth;
+        #: first calls of a program (a trace and a compile or a cache
+        #: read hides in each) plus the background width compiles
+        self.launches = 0
+        self.held_tasks = 0
+        self.defused_waves = 0
+        self.starved_waits = 0
+        self.inflight_waits = 0
+        self.compiles = 0
 
     def as_dict(self) -> Dict[str, int]:
         return {k: getattr(self, k) for k in self.__slots__}

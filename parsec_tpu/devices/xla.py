@@ -26,6 +26,7 @@ cache means steady-state execution launches pre-compiled executables only.
 
 from __future__ import annotations
 
+import re
 import threading
 import weakref
 from collections import OrderedDict, deque
@@ -38,6 +39,7 @@ from parsec_tpu.data.data import (ACCESS_READ, ACCESS_WRITE, Coherency,
                                   DataCopy, FLAG_COW, FLAG_SCRATCH)
 from parsec_tpu.devices.device import Device
 from parsec_tpu.core.task import ToDesc
+from parsec_tpu.prof.pins import SPAN_OFF, open_span, spans_live
 from parsec_tpu.utils import faultinject as _fi
 from parsec_tpu.utils.mca import params
 from parsec_tpu.utils.output import debug_verbose, warning
@@ -127,8 +129,14 @@ class XlaKernel:
     _jit_lock = threading.Lock()
 
     def __init__(self, fn, arg_names: Sequence[str],
-                 flow_names: Sequence[str], writable_flows: Sequence[str]):
+                 flow_names: Sequence[str], writable_flows: Sequence[str],
+                 cls: Optional[str] = None):
         self.fn = fn
+        #: the task class this kernel is the body of (POTRF, GEMM...):
+        #: what its programs and their scopes are named after, so a
+        #: device trace reads ``jit_parsec_GEMM_x8`` and not ``jit_target``
+        self.cls = re.sub(r"\W", "_", cls or getattr(fn, "__name__", "")
+                          or "kernel")
         self.arg_names = list(arg_names)
         self.flow_names = set(flow_names)
         self.writable = list(writable_flows)   # flow declaration order
@@ -181,7 +189,9 @@ class XlaKernel:
             if donate else ()
         static = tuple(t * k + i for t in range(n) for i in static1)
         dn = tuple(t * k + i for t in range(n) for i in dn1)
-        key = (static, dn, n)
+        # the class is part of the key: two classes sharing one kernel
+        # function each get a program under their own name
+        key = (static, dn, n, self.cls)
         with XlaKernel._jit_lock:
             cache = getattr(self.fn, "__parsec_jit_cache__", None)
             if cache is None:
@@ -193,14 +203,22 @@ class XlaKernel:
             jf = cache.get(key)
             if jf is None:
                 import jax
+                fn, cls = self.fn, self.cls
                 if n == 1:
-                    target = self.fn
-                else:
-                    fn = self.fn
-
                     def target(*flat):
-                        return tuple(fn(*flat[t * k:(t + 1) * k])
-                                     for t in range(n))
+                        with jax.named_scope(cls):
+                            return fn(*flat)
+                else:
+                    def target(*flat):
+                        outs = []
+                        for t in range(n):
+                            with jax.named_scope(cls):
+                                outs.append(fn(*flat[t * k:(t + 1) * k]))
+                        return tuple(outs)
+                # the program's stable name: XLA calls the module
+                # jit_<__name__>, and that is what the trace prints
+                target.__name__ = target.__qualname__ = \
+                    f"parsec_{cls}" if n == 1 else f"parsec_{cls}_x{n}"
                 jf = jax.jit(target, static_argnums=static, donate_argnums=dn)
                 cache[key] = jf
             return jf
@@ -210,8 +228,7 @@ class XlaKernel:
         return normalize_body_outputs(result, self.writable, what="kernel")
 
     def fuse_ready(self, donate: bool, n: int, flat: Sequence[Any],
-                   failures: Optional[Dict[Tuple[str, int], str]] = None
-                   ) -> bool:
+                   device: Optional["XlaDevice"] = None) -> bool:
         """Whether the width-``n`` fused program may be dispatched NOW.
 
         First use of a fused width triggers a full XLA compile — tens of
@@ -226,9 +243,10 @@ class XlaKernel:
         back to the already-compiled width-1 program.
 
         A width whose compile failed answers False too; the compiler's
-        words stay in its stamp, and a caller that passes ``failures``
-        (the device's ``fuse_failures``) gets them recorded there, with
-        one warning at the first record."""
+        words stay in its stamp, and a caller that passes its ``device``
+        gets them recorded in the device's ``fuse_failures``, with one
+        warning at the first record (and the background compile shows
+        as that device's ``warm.compile`` span and in its counters)."""
         if n <= 1:
             return True
         if not int(params.get("device_fuse_bg", 1)):
@@ -237,6 +255,8 @@ class XlaKernel:
         key = ("w", donate, n, tuple(
             (tuple(a.shape), str(a.dtype)) if hasattr(a, "shape") else a
             for a in flat))
+
+        failures = device.fuse_failures if device is not None else None
 
         def failed(stamp) -> bool:
             if failures is not None and (self.name, n) not in failures:
@@ -265,7 +285,7 @@ class XlaKernel:
         specs = [jax.ShapeDtypeStruct(a.shape, a.dtype,
                                       sharding=getattr(a, "sharding", None))
                  if hasattr(a, "shape") else a for a in flat]
-        _fuse_warmer.submit(self, key, donate, n, specs)
+        _fuse_warmer.submit(self, key, donate, n, specs, device)
         # Bounded wait: a matmul-class width, or one the persistent
         # cache already holds, lands in ~1-3s and dispatching FUSED is
         # far cheaper than a de-fused singles rep (measured: potrf lost
@@ -298,9 +318,9 @@ class _FuseWarmer:
         self._thread = None
         self._busy = 0
 
-    def submit(self, spec, key, donate, n, arg_specs) -> None:
+    def submit(self, spec, key, donate, n, arg_specs, device=None) -> None:
         with self._cv:
-            self._q.append((spec, key, donate, n, arg_specs))
+            self._q.append((spec, key, donate, n, arg_specs, device))
             if self._thread is None or not self._thread.is_alive():
                 self._thread = threading.Thread(
                     target=self._run, daemon=True, name="xla-fuse-warm")
@@ -333,11 +353,16 @@ class _FuseWarmer:
                     if not self._q:
                         self._thread = None
                         return
-                spec, key, donate, n, arg_specs = self._q.popleft()
+                spec, key, donate, n, arg_specs, device = self._q.popleft()
                 self._busy += 1
             reason = None
+            if device is not None:
+                device.stats.compiles += 1
             try:
-                spec.jitted_fused(donate, n).lower(*arg_specs).compile()
+                jf = spec.jitted_fused(donate, n)
+                with open_span(device.es if device is not None else None,
+                               "warm.compile", program=_program_name(jf)):
+                    jf.lower(*arg_specs).compile()
             except Exception as exc:
                 reason = f"{type(exc).__name__}: {exc}"
             import time as _time
@@ -354,6 +379,28 @@ class _FuseWarmer:
 
 
 _fuse_warmer = _FuseWarmer()
+
+
+def _program_name(jf) -> str:
+    """What the device trace calls the jitted callable's program."""
+    return "jit_" + getattr(jf, "__name__", "?")
+
+
+def _chain_name(node_specs, wave_spec, n: int) -> str:
+    """``parsec_chain_<HEADS>__<CLS>_x<n>``: the held heads in launch
+    order (a run of one class as ``<CLS><count>``), then the consumer
+    wave; ``parsec_chain_<HEADS>`` where a chain is forced alone."""
+    heads = []
+    for sp in node_specs:
+        if heads and heads[-1][0] == sp.cls:
+            heads[-1][1] += 1
+        else:
+            heads.append([sp.cls, 1])
+    name = "parsec_chain_" + "_".join(
+        c if k == 1 else f"{c}{k}" for c, k in heads)[:80]
+    if wave_spec is not None and n:
+        name += f"__{wave_spec.cls}_x{n}"
+    return name
 
 
 def wait_fuse_warm(timeout: float = 600.0) -> bool:
@@ -468,19 +515,25 @@ def _chain_jitted(key, node_specs, node_descs, wave_spec, wave_descs,
             return node_outs[d[1]][d[2]]
         return d[1]
 
+    import jax
+
     def prog(*leaves):
         node_outs = []
         for sp, ds in zip(node_specs, node_descs):
             args = [resolve(d, leaves, node_outs) for d in ds]
-            node_outs.append(sp.bind_outputs(sp.fn(*args)))
+            with jax.named_scope(sp.cls):
+                node_outs.append(sp.bind_outputs(sp.fn(*args)))
         waves = []
         if wave_spec is not None:
             for ds in wave_descs:
                 args = [resolve(d, leaves, node_outs) for d in ds]
-                waves.append(wave_spec.bind_outputs(wave_spec.fn(*args)))
+                with jax.named_scope(wave_spec.cls):
+                    waves.append(wave_spec.bind_outputs(
+                        wave_spec.fn(*args)))
         return node_outs, waves
 
-    import jax
+    prog.__name__ = prog.__qualname__ = _chain_name(
+        node_specs, wave_spec, len(wave_descs))
     jf = jax.jit(prog, donate_argnums=tuple(donate))
     with _chain_jit_lock:
         return _chain_jit_cache.setdefault(key, jf)
@@ -562,10 +615,13 @@ _PLACEHOLDER = object()
 
 class _Inflight:
     __slots__ = ("es", "task", "spec", "outputs", "pinned", "load",
-                 "release_after", "prepublished")
+                 "release_after", "prepublished", "seq")
 
-    def __init__(self, es, task, spec, outputs, pinned, load, release_after):
+    def __init__(self, es, task, spec, outputs, pinned, load, release_after,
+                 seq=0):
         self.es = es
+        #: the launch (``mgr.launch`` span) this task rode
+        self.seq = seq
         self.task = task
         self.spec = spec
         self.outputs = outputs
@@ -645,6 +701,7 @@ class XlaDevice(Device):
         #: ready, oldest-first
         self._retire: deque = deque()
         self._launching = 0
+        self._launch_seq = 0    # launches popped so far (under _cond)
         self._completing = 0
         self._finalizing = 0
         self._cond = threading.Condition()
@@ -681,20 +738,41 @@ class XlaDevice(Device):
     # submit phases of the manager state machine)
     # ------------------------------------------------------------------
     def _manager_loop(self):
+        import time as _time
         while True:
             with self._cond:
-                while not self._pending and not self._stop:
-                    self._cond.wait(0.1)
+                if not self._pending and not self._stop:
+                    # one span an episode, however many wake-ups
+                    self.stats.starved_waits += 1
+                    with open_span(self.es, "mgr.starved", dev=self.name):
+                        while not self._pending and not self._stop:
+                            self._cond.wait(0.1)
                 if self._stop and not self._pending:
                     return
-                batch = self._pop_wave_locked()
+                seq = self._launch_seq = self._launch_seq + 1
+                launch = open_span(self.es, "mgr.launch", dev=self.name,
+                                   seq=seq)
+                with open_span(self.es, "mgr.pop_wave"):
+                    batch = self._pop_wave_locked()
                 self._launching += 1
             try:
+                if launch is not SPAN_OFF:
+                    task0 = batch[0][0]
+                    late = {"pool": task0.taskpool.taskpool_id,
+                            "cls": task0.task_class.name,
+                            "n": len(batch), "held": 0}
+                    ready = [t.ready_at for t, _s, _l in batch
+                             if t.ready_at is not None]
+                    if ready:
+                        late["wait_us"] = int(
+                            (_time.perf_counter() - min(ready)) * 1e6)
+                    launch.late = late
                 if _fi.ARMED:
                     # fault plan delay_dispatch: perturb the manager /
                     # completer interleaving deterministically
                     _fi.device_delay()
-                self._launch(batch)
+                if self._launch(batch, seq) and launch is not SPAN_OFF:
+                    late["held"] = 1
             except Exception as exc:   # stage-in/compile failure
                 from parsec_tpu.core import scheduling
                 self.stats.faults += 1
@@ -706,6 +784,7 @@ class XlaDevice(Device):
                         self.es.context.record_error(exc, t)
                         scheduling.complete_execution(self.es, t, failed=True)
             finally:
+                launch.end()
                 with self._cond:
                     self._launching -= 1
                     self._cond.notify_all()
@@ -846,11 +925,13 @@ class XlaDevice(Device):
         scheduling.schedule(self.es, rescued)
         return True
 
-    def _launch(self, batch) -> None:
+    def _launch(self, batch, seq: int = 0) -> bool:
         """Stage and dispatch one wave: a list of (task, spec, load) with
         a shared kernel spec (len 1 = the plain single-task launch).  The
         whole wave rides ONE jitted call (XlaKernel.jitted_fused), so a
-        k-wide TRSM/SYRK/GEMM wavefront costs one dispatch round trip."""
+        k-wide TRSM/SYRK/GEMM wavefront costs one dispatch round trip.
+        ``seq`` is the launch's number on this device; returns True
+        where the wave was a chain head that was held, not dispatched."""
         spec: XlaKernel = batch[0][1]
         n = len(batch)
         #: pins and deferred arena releases stay PER TASK: each inflight
@@ -862,6 +943,8 @@ class XlaDevice(Device):
         release_per: List[List[DataCopy]] = []
         flat: List[Any] = []
         try:
+            stage = open_span(self.es, "mgr.stage_in")
+            bytes0 = self.stats.bytes_in
             for task, _spec, _load in batch:
                 tc = task.task_class
                 staged: Dict[str, Any] = {}
@@ -901,15 +984,19 @@ class XlaDevice(Device):
             # already-resolved chain placeholders substitute transparently
             flat = [a.array if isinstance(a, Deferred)
                     and a.array is not None else a for a in flat]
+            # (with two managers the delta can hold the other's bytes)
+            stage.end(bytes_in=self.stats.bytes_in - bytes0)
+            stage = SPAN_OFF
             if n == 1 and spec.writable \
                     and self._chain_eligible(batch[0][0], spec):
                 # chain head (POTRF(k), TSQRT(m,k)...): hold instead of
                 # dispatching — deps release eagerly through the normal
                 # completer path with Deferred payloads, and the kernel
                 # is traced into the consumer wave's launch
+                self.stats.held_tasks += 1
                 self._hold_task(batch[0], flat, pinned_per[0],
-                                release_per[0])
-                return
+                                release_per[0], seq)
+                return True
             if any(isinstance(a, Deferred) for a in flat):
                 outs_per_task = self._dispatch_chained(spec, n, flat)
                 fused = False
@@ -921,6 +1008,7 @@ class XlaDevice(Device):
                 self.stats.fused_launches += 1
                 self.stats.fused_tasks += n
         except Exception:
+            stage.end()
             for pinned in pinned_per:
                 for d in pinned:
                     self._unpin(d)
@@ -944,18 +1032,43 @@ class XlaDevice(Device):
             for task, _spec2, _load2 in batch:
                 self.es.pins("device_dispatch", task)
         with self._cond:
-            # gate on the WHOLE wave fitting under the inflight depth:
-            # appending n entries after a <depth check would let the
-            # window exceed device_inflight_depth by fuse-width-1 and
-            # under-account HBM backpressure (ADVICE r3 low)
-            room = max(self._depth - n, 0)   # n>depth: drain fully first
-            while len(self._inflight) > room and not self._stop:
-                self._cond.wait(0.1)
+            self._wait_room_locked(n)
             for i, (task, _spec, load) in enumerate(batch):
                 self._inflight.append(
                     _Inflight(self.es, task, spec, outs_per_task[i],
-                              pinned_per[i], load, release_per[i]))
+                              pinned_per[i], load, release_per[i], seq))
             self._cond.notify_all()
+        return False
+
+    def _wait_room_locked(self, n: int) -> None:
+        """Wait until ``n`` more entries fit under the inflight depth.
+        The gate is on the WHOLE wave fitting: appending n entries after
+        a <depth check would let the window exceed device_inflight_depth
+        by fuse-width-1 and under-account HBM backpressure (ADVICE r3
+        low).  Caller holds ``_cond``."""
+        room = max(self._depth - n, 0)   # n>depth: drain fully first
+        if len(self._inflight) > room and not self._stop:
+            self.stats.inflight_waits += 1
+            with open_span(self.es, "mgr.inflight_wait"):
+                while len(self._inflight) > room and not self._stop:
+                    self._cond.wait(0.1)
+
+    def _call(self, jf, args):
+        """One jitted call: one program on the device's queue, one
+        ``mgr.dispatch`` span.  ``first`` marks the first call of this
+        callable on this chip, in which a trace and a compile (or a read
+        of the persistent cache) hides."""
+        ran = getattr(jf, "_parsec_ran", None)
+        if ran is None:
+            ran = jf._parsec_ran = set()
+        first = self.jdev.id not in ran
+        if first:
+            ran.add(self.jdev.id)
+            self.stats.compiles += 1
+        self.stats.launches += 1
+        with open_span(self.es, "mgr.dispatch", program=_program_name(jf),
+                       first=int(first)):
+            return jf(*args)
 
     def _dispatch_plain(self, spec: XlaKernel, n: int, flat: List[Any]):
         """The pre-existing dispatch path: one (possibly width-fused)
@@ -964,18 +1077,20 @@ class XlaDevice(Device):
         donate = self._donate and not self._donation_hazard(spec, flat)
 
         if n == 1:
-            fused, results = False, [spec.jitted(donate)(*flat)]
-        elif not spec.fuse_ready(donate, n, flat, self.fuse_failures):
+            fused, results = False, [self._call(spec.jitted(donate), flat)]
+        elif not spec.fuse_ready(donate, n, flat, self):
             # the fused width is still compiling in the background
             # (Cholesky-class programs take tens of seconds), or its
             # compile failed (fuse_failures says why): dispatch singles
             # now — the wave fuses once the width is warm
+            self.stats.defused_waves += 1
             k = len(spec.arg_names)
             jf = spec.jitted(donate)
-            fused, results = False, [jf(*flat[i * k:(i + 1) * k])
+            fused, results = False, [self._call(jf, flat[i * k:(i + 1) * k])
                                      for i in range(n)]
         else:
-            fused, results = True, list(spec.jitted_fused(donate, n)(*flat))
+            fused, results = True, list(
+                self._call(spec.jitted_fused(donate, n), flat))
         return fused, [spec.bind_outputs(r) for r in results]
 
     # ------------------------------------------------------------------
@@ -1014,7 +1129,7 @@ class XlaDevice(Device):
             return False
         return False
 
-    def _hold_task(self, item, flat, pinned, release_after) -> None:
+    def _hold_task(self, item, flat, pinned, release_after, seq=0) -> None:
         """Park a chain head: its outputs become Deferred payloads on
         the already-staged copies, and the task completes eagerly
         through the normal completer path (deps release, successors
@@ -1040,12 +1155,10 @@ class XlaDevice(Device):
             h.seq = self._hold_seq
             self._held[id(task)] = h
         inf = _Inflight(self.es, task, spec, h.outputs, pinned, load,
-                        release_after)
+                        release_after, seq)
         inf.prepublished = True
         with self._cond:
-            room = max(self._depth - 1, 0)
-            while len(self._inflight) > room and not self._stop:
-                self._cond.wait(0.1)
+            self._wait_room_locked(1)
             self._inflight.append(inf)
             self._cond.notify_all()
 
@@ -1139,14 +1252,14 @@ class XlaDevice(Device):
         donate = tuple(sorted(j for j in donatable
                               if leaf_uses.get(j) == 1)) \
             if self._chain_donate else ()
-        key = (tuple((hd.spec.fn, d)
+        key = (tuple((hd.spec.fn, hd.spec.cls, d)
                      for hd, d in zip(claimed, node_descs)),
-               wave_spec.fn if wave_spec is not None else None,
-               wave_descs, donate)
+               (wave_spec.fn, wave_spec.cls) if wave_spec is not None
+               else None, wave_descs, donate)
         hash(key)    # unhashable static -> the caller's failure path
         jf = _chain_jitted(key, [hd.spec for hd in claimed], node_descs,
                            wave_spec, wave_descs, donate)
-        node_outs, wave_outs = jf(*leaves)
+        node_outs, wave_outs = self._call(jf, leaves)
         self.stats.chained_launches += 1
         self.stats.chained_tasks += len(claimed) + \
             (n if wave_spec is not None else 0)
@@ -1409,10 +1522,12 @@ class XlaDevice(Device):
         from parsec_tpu.core import scheduling
         while True:
             with self._cond:
-                while not self._inflight and not self._stop:
-                    self._cond.wait(0.1)
-                    if self._retire and not self._inflight:
-                        break   # idle tick: see below
+                if not self._inflight and not self._stop:
+                    with open_span(self.es, "fin.idle", dev=self.name):
+                        while not self._inflight and not self._stop:
+                            self._cond.wait(0.1)
+                            if self._retire and not self._inflight:
+                                break   # idle tick: see below
                 if not self._inflight:
                     if self._stop:
                         break   # stopped and drained
@@ -1448,7 +1563,18 @@ class XlaDevice(Device):
                         dc = inf.task.data.get(fname)
                         if dc is not None:
                             dc.payload = arr
-                scheduling.complete_execution(inf.es, inf.task)
+                # dep release and the scheduling of successors: the one
+                # span that is per task by design (seq = the launch the
+                # task rode), so its arguments are built only when live
+                release = open_span(
+                    inf.es, "fin.release",
+                    pool=inf.task.taskpool.taskpool_id,
+                    cls=inf.task.task_class.name, seq=inf.seq) \
+                    if spans_live(inf.es) else SPAN_OFF
+                try:
+                    scheduling.complete_execution(inf.es, inf.task)
+                finally:
+                    release.end()
             except Exception as exc:
                 self.stats.faults += 1
                 inf.es.context.record_error(exc, inf.task)
@@ -1495,8 +1621,16 @@ class XlaDevice(Device):
                 self._finalizing += len(batch)
                 self._cond.notify_all()
             try:
-                for inf in batch:
-                    self._finalize(inf, block=block)
+                # (in a small-tile run nearly every task's drain finds
+                # the newest ready, so this one is per task in effect)
+                drain = open_span(self.es, "fin.drain", block=int(block),
+                                  n=len(batch)) \
+                    if spans_live(self.es) else SPAN_OFF
+                try:
+                    for inf in batch:
+                        self._finalize(inf, block=block)
+                finally:
+                    drain.end()
             finally:
                 with self._cond:
                     self._finalizing -= len(batch)

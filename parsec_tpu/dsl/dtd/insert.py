@@ -963,9 +963,11 @@ class DTDTaskpool(Taskpool):
             return None
         return hook
 
-    def _device_hook(self, fn: Callable, names: List[str], flows, writable):
+    def _device_hook(self, fn: Callable, names: List[str], flows, writable,
+                     cls: Optional[str] = None):
         from parsec_tpu.devices.xla import XlaKernel
-        spec = XlaKernel(fn, names, [f.name for f in flows], writable)
+        spec = XlaKernel(fn, names, [f.name for f in flows], writable,
+                         cls=cls)
 
         def hook(es, task):
             reg = getattr(es.context, "device_registry", None)
@@ -1769,7 +1771,8 @@ class DTDTaskClass:
         for device, fn in self.chores:
             if device in ("tpu", "xla", "gpu"):
                 incarnations.append(
-                    (device, pool._device_hook(fn, bound, flows, writable)))
+                    (device, pool._device_hook(fn, bound, flows, writable,
+                                               cls=self.name)))
             else:
                 incarnations.append(
                     ("cpu", pool._cpu_hook(fn, bound, writable)))

@@ -311,7 +311,7 @@ class TaskBuilder:
         names = [p.name for p in inspect.signature(fn).parameters.values()]
         flow_names = [f.name for f in self._flows]
         writable = [f.name for f in self._flows if f.access & ACCESS_WRITE]
-        spec = XlaKernel(fn, names, flow_names, writable)
+        spec = XlaKernel(fn, names, flow_names, writable, cls=self.name)
 
         def hook(es, task):
             reg = getattr(es.context, "device_registry", None)

@@ -772,7 +772,16 @@ class RuntimeMetrics:
                     ("evictions", "parsec_device_evictions_total"),
                     ("chained_launches",
                      "parsec_device_chained_launches_total"),
-                    ("chained_tasks", "parsec_device_chained_tasks_total")):
+                    ("chained_tasks", "parsec_device_chained_tasks_total"),
+                    # the counts at the boundaries of the device
+                    # module's thread-state spans (devices/device.py)
+                    ("launches", "parsec_device_launches_total"),
+                    ("held_tasks", "parsec_device_held_tasks_total"),
+                    ("defused_waves", "parsec_device_defused_waves_total"),
+                    ("starved_waits", "parsec_device_starved_waits_total"),
+                    ("inflight_waits",
+                     "parsec_device_inflight_waits_total"),
+                    ("compiles", "parsec_device_compiles_total")):
                 v = getattr(st, key, None)
                 if isinstance(v, (int, float)) and v:
                     out.append(counter_sample(metric, v, labels))

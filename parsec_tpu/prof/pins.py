@@ -22,10 +22,137 @@ from parsec_tpu.prof.profiling import EV_END, EV_POINT, EV_START, Profile
 #: ``device_dispatch``/``device_done`` bracket a device task's
 #: accelerator-pipeline residency (devices/xla.py, gated on the causal
 #: tracer being installed).
+#: ``span_begin``/``span_end`` bracket what a runtime THREAD is doing
+#: (manager, completer, worker, fuse warmer); the payload is the
+#: :class:`Span`, whose names are listed in ``SPAN_NAMES``.
 PINS_EVENTS = ("select", "exec_begin", "exec_end", "exec_async",
                "complete_exec", "task_discard",
                "device_dispatch", "device_done",
+               "span_begin", "span_end",
                "job_submit", "job_start", "job_done")
+
+#: thread-state spans (PERF.md section 3 says which metric reads each).
+#: ``mgr.*`` are emitted by a device's manager threads, ``fin.*`` by its
+#: completer, ``worker.idle`` by a worker, ``warm.compile`` by the
+#: background fused-width compiler (devices/xla.py, core/scheduling.py).
+SPAN_NAMES = ("mgr.starved", "mgr.launch", "mgr.pop_wave", "mgr.stage_in",
+              "mgr.dispatch", "mgr.inflight_wait", "fin.idle",
+              "fin.release", "fin.drain", "worker.idle", "warm.compile")
+#: what the spans are called in the profiler's trace: ``parsec:mgr.launch``
+SPAN_PREFIX = "parsec:"
+
+
+class Span:
+    """One begin/end pair on the emitting thread, opened by
+    :func:`open_span`.  ``args`` are known when the span opens; ``late``
+    (the keyword arguments of ``end``, or set before it) are those known
+    only when it closes.  Begin and end happen on ONE thread, properly
+    nested with the thread's other spans."""
+
+    __slots__ = ("es", "name", "args", "late", "sink")
+
+    def __init__(self, es, name: str, args: dict):
+        self.es = es
+        self.name = name
+        self.args = args
+        self.late = None
+        self.sink = None        # the sink's own handle for this pair
+
+    def end(self, **late) -> None:
+        if late:
+            self.late = late
+        self.es.pins("span_end", self)
+
+    def __enter__(self) -> "Span":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
+
+class _SpanOff:
+    """What :func:`open_span` hands out while nobody records: ends into
+    nothing, and forgets what is set on it."""
+
+    __slots__ = ()
+    late = property(lambda self: None, lambda self, late: None)
+
+    def end(self, **late) -> None:
+        pass
+
+    def __enter__(self) -> "_SpanOff":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+SPAN_OFF = _SpanOff()
+
+
+def spans_live(es) -> bool:
+    """Whether a span opened now on ``es`` would be recorded.  Per-task
+    emission sites ask this first (one C call) and skip building the
+    span's arguments; ``es`` None (a device no task has reached yet)
+    records nothing."""
+    return es is not None and es.context._span_live()
+
+
+def open_span(es, name: str, **args):
+    """Open the thread-state span ``name`` on the calling thread: emits
+    ``span_begin`` with the new :class:`Span` and returns it, to be
+    closed by ``end()`` (or as a context manager) on the same thread;
+    returns ``SPAN_OFF`` while no sink records (``Context._span_live``,
+    the gate the sink installed: no profiler session, no cost but the
+    probe)."""
+    if es is None or not es.context._span_live():
+        return SPAN_OFF
+    span = Span(es, name, args)
+    es.pins("span_begin", span)
+    return span
+
+
+def no_span_sink() -> bool:
+    return False
+
+
+class TraceMePins:
+    """The sink that puts the thread-state spans on the profiler's own
+    clock: each :class:`Span` becomes a ``jax.profiler.TraceAnnotation``
+    (TraceMe) named ``parsec:<span>`` on the emitting thread, its
+    arguments the annotation's, so a trace taken with ``jax.profiler``
+    shows what every runtime thread was doing beside the device's
+    timeline.  TraceMe is its own gate: the sink makes
+    ``TraceAnnotation.is_enabled`` the context's ``_span_live``, so with
+    no profiler session a span costs that one probe and nothing is
+    built or recorded.  Installed by every Context with an XLA device."""
+
+    def __init__(self):
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
+
+    def install(self, context) -> None:
+        context.pins_register("span_begin", self._begin)
+        context.pins_register("span_end", self._end)
+        context._span_live = self._annotation.is_enabled
+
+    def uninstall(self, context) -> None:
+        context._span_live = no_span_sink
+        context.pins_unregister("span_begin", self._begin)
+        context.pins_unregister("span_end", self._end)
+
+    def _begin(self, es, event, span) -> None:
+        ann = span.sink = self._annotation(SPAN_PREFIX + span.name,
+                                           **span.args)
+        ann.__enter__()
+
+    def _end(self, es, event, span) -> None:
+        ann = span.sink
+        if ann is not None:
+            span.sink = None
+            if span.late:
+                ann.set_metadata(**span.late)
+            ann.__exit__(None, None, None)
 
 
 class TaskProfilerPins:

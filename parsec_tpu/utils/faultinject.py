@@ -33,7 +33,9 @@ Directives (``;``-separated; fields ``,``-separated):
 ``trunc_frame``   replace a matching frame with an undecodable one (the
                   receiver severs the connection: wire-corruption path)
 ``kill_rank``     ``<rank>@t+<sec>s`` — at ``sec`` seconds after the
-                  engine came up, rank ``<rank>`` hard-closes every
+                  engine came up (under ``run_distributed``: after the
+                  start-up barrier, when user code begins on every
+                  rank), rank ``<rank>`` hard-closes every
                   socket (``mode=close``, default: EOF-detector path) or
                   goes silent with sockets open (``mode=hang``: only the
                   heartbeat timeout can see it)
