@@ -28,7 +28,7 @@ class DeviceStats:
                  "evictions", "fused_launches", "fused_tasks",
                  "chained_launches", "chained_tasks", "launches",
                  "held_tasks", "defused_waves", "starved_waits",
-                 "inflight_waits", "compiles")
+                 "inflight_waits", "compiles", "warm_waits")
 
     def __init__(self):
         self.executed_tasks = 0
@@ -52,13 +52,15 @@ class DeviceStats:
         #: width was not ready; manager episodes with an empty queue;
         #: launches that waited for room under device_inflight_depth;
         #: first calls of a program (a trace and a compile or a cache
-        #: read hides in each) plus the background width compiles
+        #: read hides in each) plus the background width compiles;
+        #: launches that blocked on a fused width's background compile
         self.launches = 0
         self.held_tasks = 0
         self.defused_waves = 0
         self.starved_waits = 0
         self.inflight_waits = 0
         self.compiles = 0
+        self.warm_waits = 0
 
     def as_dict(self) -> Dict[str, int]:
         return {k: getattr(self, k) for k in self.__slots__}

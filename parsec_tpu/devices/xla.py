@@ -48,15 +48,21 @@ from parsec_tpu.utils.output import debug_verbose, warning
 params.register("device_inflight_depth", 8,
                 "max in-flight device tasks per XLA device")
 params.register("device_fuse_bg", 1,
-                "compile fused-width programs in a background thread "
-                "and dispatch singles meanwhile (0 = compile "
+                "compile a fused-width program in a background thread "
+                "the first time this process asks for it on a device: "
+                "its waves wait for the compile's signal up to "
+                "device_fuse_warm_wait_ms and run as singles past that; "
+                "every later taskpool finds the width ready (0 = compile "
                 "synchronously on first use, stalling the wave)")
 params.register("device_fuse_warm_wait_ms", 3000.0,
-                "how long a wave waits for its fused-width program's "
-                "background compile before falling back to de-fused "
-                "singles: long enough to cover a matmul-class compile or "
-                "a persistent-cache read (~1-3s), far below a cold "
-                "Cholesky/tri_inv-class compile (tens of seconds)")
+                "how long, from the moment a fused-width program's "
+                "background compile was asked for, its waves wait for "
+                "the compile before they run as de-fused singles (every "
+                "manager that meets the warming width waits to the same "
+                "deadline and wakes on the warmer's signal): long enough "
+                "to cover a matmul-class compile or a persistent-cache "
+                "read (~1-3s), far below a cold Cholesky/tri_inv-class "
+                "compile (tens of seconds)")
 params.register("device_fuse_window_ms", 0.0,
                 "how long a manager waits for same-class siblings before "
                 "launching a narrower-than-device_fuse wave (ms).  "
@@ -126,7 +132,10 @@ class XlaKernel:
     jdf2c.c:6556 GPU hook generation.)
     """
 
-    _jit_lock = threading.Lock()
+    #: guards the caches on the kernel functions (jitted callables and
+    #: fused-width states) and is what a wave waits on for a warming
+    #: width: the warmer notifies it when it posts a width's state
+    _jit_cv = threading.Condition()
 
     def __init__(self, fn, arg_names: Sequence[str],
                  flow_names: Sequence[str], writable_flows: Sequence[str],
@@ -143,6 +152,23 @@ class XlaKernel:
         #: per-instance fast path: donate-flag -> jitted callable, dodging
         #: the lock + tuple rebuild on every launch (hot path)
         self._fast: Dict[bool, Any] = {}
+        # The cache lives ON the kernel function object, so its lifetime
+        # is the function's: module-level kernels (apps memoize theirs,
+        # e.g. gemm._kernels) share traced executables, and which fused
+        # widths are ready, across taskpool rebuilds, while per-build
+        # lambdas die with their pools instead of pinning entries in a
+        # global table forever.
+        with XlaKernel._jit_cv:
+            cache = getattr(fn, "__parsec_jit_cache__", None)
+            if cache is None:
+                cache = {}
+                try:
+                    fn.__parsec_jit_cache__ = cache
+                except AttributeError:   # unsettable callable: no sharing,
+                    pass                 # this instance keeps its own
+        #: jit key -> jitted callable, and fused-width key -> its state
+        #: (see fuse_ready), for every XlaKernel over this function
+        self._cache: Dict[Any, Any] = cache
 
     @property
     def name(self) -> str:
@@ -176,11 +202,6 @@ class XlaKernel:
         return jf
 
     def _jitted_slow(self, donate: bool, n: int = 1):
-        # The jit cache lives ON the kernel function object, so its
-        # lifetime is the function's: module-level kernels (apps memoize
-        # theirs, e.g. gemm._kernels) share traced executables across
-        # taskpool rebuilds, while per-build lambdas die with their pools
-        # instead of pinning entries in a global table forever.
         k = len(self.arg_names)
         static1 = tuple(i for i, a in enumerate(self.arg_names)
                         if a not in self.flow_names)
@@ -192,15 +213,8 @@ class XlaKernel:
         # the class is part of the key: two classes sharing one kernel
         # function each get a program under their own name
         key = (static, dn, n, self.cls)
-        with XlaKernel._jit_lock:
-            cache = getattr(self.fn, "__parsec_jit_cache__", None)
-            if cache is None:
-                cache = {}
-                try:
-                    self.fn.__parsec_jit_cache__ = cache
-                except AttributeError:   # unsettable callable: no sharing
-                    pass
-            jf = cache.get(key)
+        with XlaKernel._jit_cv:
+            jf = self._cache.get(key)
             if jf is None:
                 import jax
                 fn, cls = self.fn, self.cls
@@ -220,7 +234,7 @@ class XlaKernel:
                 target.__name__ = target.__qualname__ = \
                     f"parsec_{cls}" if n == 1 else f"parsec_{cls}_x{n}"
                 jf = jax.jit(target, static_argnums=static, donate_argnums=dn)
-                cache[key] = jf
+                self._cache[key] = jf
             return jf
 
     def bind_outputs(self, result: Any) -> Dict[str, Any]:
@@ -235,74 +249,100 @@ class XlaKernel:
         seconds for Cholesky/tri_inv-class programs — and which widths a
         run needs depends on nondeterministic wave scheduling, so a cold
         width mid-measurement stalls the whole pipeline (the r4 geqrf
-        variance).  Instead of blocking, the first request WARMS the
-        width in a background thread (shape-only lower+compile: the
-        result lands in the persistent compile cache that
+        variance).  Instead the first request WARMS the width in a
+        background thread (shape-only lower+compile: the result lands
+        in the persistent compile cache that
         devices.configure_compile_cache placed, so the eventual jit call
-        reads it back instead of compiling again) and the caller falls
-        back to the already-compiled width-1 program.
+        reads it back instead of compiling again).
 
-        A width whose compile failed answers False too; the compiler's
-        words stay in its stamp, and a caller that passes its ``device``
-        gets them recorded in the device's ``fuse_failures``, with one
-        warning at the first record (and the background compile shows
-        as that device's ``warm.compile`` span and in its counters)."""
+        A width's state belongs to the PROGRAM, not to the taskpool: it
+        sits beside the jitted callables on the kernel function, under a
+        key that names the program the jit call will ask for — class,
+        donation, width, the static values and (shape, dtype) of the
+        arguments, and the device — so every later taskpool over the
+        same kernel function finds the width ready with one lookup: no
+        compile submitted, nothing waited for.  The states: absent
+        (never asked for), ``("warming", deadline)``, ``True``,
+        ``("failed", when, reason)``.
+
+        Whoever meets a warming width — the manager that asked for it
+        or any other — waits on the condition the warmer notifies when
+        it posts the state, up to the width's deadline
+        (``device_fuse_warm_wait_ms`` from the request): a matmul-class
+        compile or a persistent-cache read lands inside it and the wave
+        goes out FUSED, far cheaper than a rep of singles.  Past the
+        deadline (a cold Cholesky-class program) nobody waits: waves
+        run as singles until the compile lands.
+
+        A width whose compile failed answers False too, without a wait,
+        and is asked for again after 60 s; the compiler's words stay in
+        its stamp, and a caller that passes its ``device`` gets them
+        recorded in the device's ``fuse_failures``, with one warning at
+        the first record.  On that device the background compile shows
+        as a ``warm.compile`` span and in ``compiles``, a wait as a
+        ``mgr.warm_wait`` span and in ``warm_waits``."""
         if n <= 1:
             return True
         if not int(params.get("device_fuse_bg", 1)):
             return True    # kill-switch: compile widths synchronously
-        import time as _time
-        key = ("w", donate, n, tuple(
+        key = ("w", self.cls, donate, n, tuple(
             (tuple(a.shape), str(a.dtype)) if hasattr(a, "shape") else a
-            for a in flat))
-
-        failures = device.fuse_failures if device is not None else None
-
-        def failed(stamp) -> bool:
-            if failures is not None and (self.name, n) not in failures:
-                failures[(self.name, n)] = stamp[2]
-                warning("fused width %d of kernel %s failed to compile; "
-                        "its waves run as singles: %s", n, self.name,
-                        stamp[2][:2000])
-            return False
-
-        with XlaKernel._jit_lock:
-            st = self._fast.get(key)
+            for a in flat), device.name if device is not None else None)
+        state = self._cache
+        if state.get(key) is True:
+            return True
+        import time as _time
+        specs = None
+        with XlaKernel._jit_cv:
+            st = state.get(key)
             if st is True:
                 return True
-            if st == "warming":
-                return False
-            if isinstance(st, tuple):      # ("failed", when, reason)
-                failed(st)
-                if _time.monotonic() - st[1] < 60.0:
+            now = _time.monotonic()
+            if st is not None and st[0] == "failed":
+                self._fuse_failed(device, n, st[2])
+                if now - st[1] < 60.0:
                     return False    # backoff: singles, no wait
-            self._fast[key] = "warming"
+                st = None
+            if st is None:
+                import jax
+                # the sharding rides along so the warm compile is THE
+                # program the jit call will ask for (same device
+                # assignment: the persistent-cache key covers it)
+                specs = [jax.ShapeDtypeStruct(
+                    a.shape, a.dtype, sharding=getattr(a, "sharding", None))
+                    if hasattr(a, "shape") else a for a in flat]
+                st = state[key] = ("warming", now + 1e-3 * float(
+                    params.get("device_fuse_warm_wait_ms", 3000.0)))
+            left = st[1] - now      # of the width's bound
+            if left > 0 and device is not None:
+                device.stats.warm_waits += 1
+        if specs is not None:
+            _fuse_warmer.submit(self, key, donate, n, specs, device)
+        if left <= 0:
+            return False    # a slow compile: singles until it lands
+        with open_span(device.es if device is not None else None,
+                       "mgr.warm_wait",
+                       program=_program_name(self.jitted_fused(donate, n))):
+            with XlaKernel._jit_cv:
+                XlaKernel._jit_cv.wait_for(
+                    lambda: state[key] is True or state[key][0] != "warming",
+                    left)
+                st = state[key]
+        if st is True:
+            return True
+        if st[0] == "failed":       # singles this time
+            return self._fuse_failed(device, n, st[2])
+        return False
 
-        import jax
-        # the sharding rides along so the warm compile is THE program
-        # the jit call will ask for (same device assignment: the
-        # persistent-cache key covers it)
-        specs = [jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                      sharding=getattr(a, "sharding", None))
-                 if hasattr(a, "shape") else a for a in flat]
-        _fuse_warmer.submit(self, key, donate, n, specs, device)
-        # Bounded wait: a matmul-class width, or one the persistent
-        # cache already holds, lands in ~1-3s and dispatching FUSED is
-        # far cheaper than a de-fused singles rep (measured: potrf lost
-        # 30% to eager singles).  A genuinely cold Cholesky-class
-        # program blows past the bound and the wave takes the singles
-        # path while the compile finishes in background.
-        wait_s = float(params.get("device_fuse_warm_wait_ms", 3000.0)) \
-            * 1e-3
-        deadline = _time.monotonic() + wait_s
-        while _time.monotonic() < deadline:
-            with XlaKernel._jit_lock:
-                st = self._fast.get(key)
-            if st is True:
-                return True
-            if st != "warming":
-                return failed(st)     # warm failed; singles this time
-            _time.sleep(0.05)
+    def _fuse_failed(self, device: Optional["XlaDevice"], n: int,
+                     reason: str) -> bool:
+        """Record a failed width with the device that asked, warning at
+        its first record there; False, what ``fuse_ready`` answers."""
+        if device is not None and (self.name, n) not in device.fuse_failures:
+            device.fuse_failures[(self.name, n)] = reason
+            warning("fused width %d of kernel %s failed to compile; "
+                    "its waves run as singles: %s", n, self.name,
+                    reason[:2000])
         return False
 
 
@@ -310,7 +350,10 @@ class _FuseWarmer:
     """ONE background thread compiling fused-width programs serially:
     the compiles share the host's cores with the workers and the
     dispatching managers, and a single queue still warms every width
-    well before steady state."""
+    well before steady state.  It posts each width's outcome where
+    ``XlaKernel.fuse_ready`` reads it — the cache on the kernel
+    function, shared by every taskpool — and notifies the waves that
+    wait for it."""
 
     def __init__(self):
         self._q: deque = deque()
@@ -366,13 +409,14 @@ class _FuseWarmer:
             except Exception as exc:
                 reason = f"{type(exc).__name__}: {exc}"
             import time as _time
-            with XlaKernel._jit_lock:
+            with XlaKernel._jit_cv:
                 # failure memoization with backoff: a persistently
                 # failing width must not make every wave re-pay the
                 # bounded wait.  The compiler's words stay in the stamp;
                 # fuse_ready hands them to the device that asks next
-                spec._fast[key] = True if reason is None else \
+                spec._cache[key] = True if reason is None else \
                     ("failed", _time.monotonic(), reason)
+                XlaKernel._jit_cv.notify_all()
             with self._cv:
                 self._busy -= 1
                 self._cv.notify_all()

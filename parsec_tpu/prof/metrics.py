@@ -781,7 +781,8 @@ class RuntimeMetrics:
                     ("starved_waits", "parsec_device_starved_waits_total"),
                     ("inflight_waits",
                      "parsec_device_inflight_waits_total"),
-                    ("compiles", "parsec_device_compiles_total")):
+                    ("compiles", "parsec_device_compiles_total"),
+                    ("warm_waits", "parsec_device_warm_waits_total")):
                 v = getattr(st, key, None)
                 if isinstance(v, (int, float)) and v:
                     out.append(counter_sample(metric, v, labels))

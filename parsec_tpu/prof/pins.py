@@ -36,8 +36,9 @@ PINS_EVENTS = ("select", "exec_begin", "exec_end", "exec_async",
 #: completer, ``worker.idle`` by a worker, ``warm.compile`` by the
 #: background fused-width compiler (devices/xla.py, core/scheduling.py).
 SPAN_NAMES = ("mgr.starved", "mgr.launch", "mgr.pop_wave", "mgr.stage_in",
-              "mgr.dispatch", "mgr.inflight_wait", "fin.idle",
-              "fin.release", "fin.drain", "worker.idle", "warm.compile")
+              "mgr.dispatch", "mgr.inflight_wait", "mgr.warm_wait",
+              "fin.idle", "fin.release", "fin.drain", "worker.idle",
+              "warm.compile")
 #: what the spans are called in the profiler's trace: ``parsec:mgr.launch``
 SPAN_PREFIX = "parsec:"
 

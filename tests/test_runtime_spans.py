@@ -27,7 +27,7 @@ TASKS = NT + NT * (NT - 1) + NT * (NT - 1) * (NT - 2) // 6
 MCA = {"device_max": 1, "device_inflight_depth": 1,
        "device_fuse_window_ms": 2.0}
 MGR_CHILDREN = ("mgr.pop_wave", "mgr.stage_in", "mgr.dispatch",
-                "mgr.inflight_wait")
+                "mgr.inflight_wait", "mgr.warm_wait")
 
 
 def _run_jobs(jobs=JOBS):
@@ -66,6 +66,11 @@ def traced(tmp_path_factory):
     client's events carry."""
     import jax
     from jax.profiler import ProfileData
+    from parsec_tpu.apps import potrf
+    # kernel functions of this run's own: which fused widths are ready
+    # is kept on them, and another test of this process may have warmed
+    # the app's memoized ones
+    potrf._kernels.clear()
     out = str(tmp_path_factory.mktemp("trace"))
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -160,6 +165,23 @@ def test_dispatch_spans_equal_the_launch_counter(traced):
     assert len(_spans(traced, "mgr.inflight_wait")) == st["inflight_waits"]
     held = [ev for ev in _spans(traced, "mgr.launch") if ev[3]["held"]]
     assert len(held) == st["held_tasks"]
+
+
+def test_warm_wait_spans_equal_the_counter(traced):
+    """Every launch that blocked on a warming width is one
+    ``mgr.warm_wait``; a width is compiled once a program, not once a
+    taskpool: the later jobs find it ready."""
+    waits = _spans(traced, "mgr.warm_wait")
+    assert len(waits) == traced["stats"]["warm_waits"] > 0
+    compiled = [ev[3]["program"] for ev in _spans(traced, "warm.compile")]
+    assert len(compiled) == len(set(compiled))
+    assert {ev[3]["program"] for ev in waits} <= set(compiled)
+    # only a fused wave has a width to wait for
+    for ln in traced["lines"]:
+        launches = [ev for ev in ln if ev[0] == "mgr.launch"]
+        for _n, s, e, _a in (ev for ev in ln if ev[0] == "mgr.warm_wait"):
+            (la,) = [la for la in launches if la[1] <= s and e <= la[2]]
+            assert la[3]["n"] > 1
 
 
 def test_every_task_is_counted_and_released(traced):
