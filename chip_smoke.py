@@ -522,9 +522,12 @@ def run_multichip(potrf_size: dict, gemm_size: dict, seed: int = 0,
                     + moved["permutes"] <= 0:
                 raise SmokeFailure(f"potrf over several devices moved "
                                    f"nothing over ICI: {moved}")
-            if not g["ici"] or g["ici"]["bcasts"] <= 0:
+            # on a 2 x 2 grid a row or column panel has ONE other chip
+            # to reach (a put); broadcasts start at three
+            if not g["ici"] or g["ici"]["bcasts"] + g["ici"]["puts"] \
+                    + g["ici"]["permutes"] <= 0:
                 raise SmokeFailure(f"panel_bcast GEMM over several devices "
-                                   f"ran no ICI broadcast: {g['ici']}")
+                                   f"moved no panel over ICI: {g['ici']}")
         runs[label] = (p, g)
         for r in (p, g):
             emit({**{k: v for k, v in r.items() if k != "_samples"},

@@ -27,6 +27,14 @@ host:
 
 Programs are shard_map computations over a 1D mesh of every attached XLA
 device, cached per (shape, dtype, permutation).
+
+What a transfer leaves on a consumer's chip is a REPLICA: a SHARED copy
+of another chip's tile.  ``fan_out`` counts, from the flow's deliveries,
+how many consumers each chip will see (``Data.replica_readers``); each
+consumer counts itself down where its inputs are unpinned
+(``consumed``), and at the last one the replica leaves its chip
+(``XlaDevice.release_replica``) — so several chips hold a larger
+problem than one, not a copy of it each.
 """
 
 from __future__ import annotations
@@ -36,7 +44,8 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from parsec_tpu.data.data import Coherency, DataCopy
+from parsec_tpu.data.data import Coherency, DataCopy, FLAG_REPLICA
+from parsec_tpu.prof.pins import open_span
 from parsec_tpu.utils.mca import params
 from parsec_tpu.utils.output import debug_verbose
 
@@ -102,8 +111,11 @@ class IciEngine:
         self._space_to_pos: Dict[int, int] = {
             d.space: i for i, d in enumerate(self.xla_devices)}
         self._jdev = {d.space: d.jdev for d in self.xla_devices}
+        self._by_space = {d.space: d for d in self.xla_devices}
         self.stats = IciStats()
         self._mesh = None
+        #: sorted tuple of spaces -> the sharding that replicates over them
+        self._rep_shardings: Dict[Any, Any] = {}
         self._prog_cache: Dict[Tuple, Any] = {}
         self._lock = threading.Lock()
         #: serializes COLLECTIVE program launches: two multi-device
@@ -117,11 +129,12 @@ class IciEngine:
         #: program order.
         self._launch_lock = threading.Lock()
         #: deferred single-consumer placements awaiting same-wavefront
-        #: siblings: (produced copy, destination space, enqueue time).
+        #: siblings: (produced copy, destination space, enqueue time,
+        #: whether the destination's consumers are counted).
         #: Flushed as batched CollectivePermute rounds (SURVEY §5.8's
         #: "batched per DAG wavefront" schedule) when a full round
         #: accumulates or an idle worker drains the window.
-        self._pending_edges: List[Tuple[DataCopy, int, float]] = []
+        self._pending_edges: List[Tuple[DataCopy, int, float, bool]] = []
         self._pending_lock = threading.Lock()
         #: when the last single-consumer edge was seen: a fresh edge after
         #: a quiet spell is treated as a chain hop (placed immediately),
@@ -152,38 +165,55 @@ class IciEngine:
         buffer, which a later donation would corrupt (the r8 wrong-R
         root cause; see devices/xla.device_put_private)."""
         from parsec_tpu.devices.xla import device_put_private
-        out = device_put_private(payload, self._jdev[dst_space])
+        nbytes = getattr(payload, "nbytes", 0)
+        with open_span(self._es(), "ici.put", bytes=nbytes, ndst=1):
+            out = device_put_private(payload, self._jdev[dst_space])
         self.stats.puts += 1
-        self.stats.put_bytes += getattr(payload, "nbytes", 0)
+        self.stats.put_bytes += nbytes
         return out
+
+    def _es(self):
+        """The stream the transport's spans go out on: whichever device
+        stream exists (spans are per thread; the stream only names the
+        context whose sink is asked)."""
+        for d in self.xla_devices:
+            if d.es is not None:
+                return d.es
+        return None
 
     # ------------------------------------------------------------------
     # broadcast: one producer tile -> many devices, one XLA replication
     # ------------------------------------------------------------------
     def bcast(self, payload, dst_spaces: Sequence[int]) -> Dict[int, Any]:
-        """Replicate ``payload`` onto every device of the mesh in one XLA
-        data movement; return {space: on-device array} for the requested
-        targets (reference: the dataflow bcast trees, remote_dep.c:334-357
-        — here the tree is the interconnect's native replication)."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        """Replicate ``payload`` onto the devices of ``dst_spaces``, and
+        no others, in one XLA data movement; return {space: on-device
+        array} (reference: the dataflow bcast trees, remote_dep.c:334-357
+        — here the tree is the interconnect's native replication).
+        ``bcast_bytes`` counts what moved: one payload a destination."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
         from parsec_tpu.devices.xla import device_put_replicated_private
-        want = set(dst_spaces)
-        sharding = NamedSharding(self.mesh(), P())   # fully replicated
+        want = tuple(sorted({s for s in dst_spaces if s in self._jdev}))
+        if not want:
+            return {}
+        sharding = self._rep_shardings.get(want)
+        if sharding is None:
+            sharding = self._rep_shardings[want] = NamedSharding(
+                Mesh(np.array([self._jdev[s] for s in want]), ("d",)), P())
+        nbytes = getattr(payload, "nbytes", 0)
         # the replicated "copies" must be PRIVATE: on the CPU client the
         # shard co-located with the host buffer can alias it (the same
         # r8 wrong-R hazard device_put_private closes for put/stage-in)
         # — a later in-place mutation or donation of the source would
         # corrupt every consumer's tile
-        rep = device_put_replicated_private(payload, sharding)
-        out: Dict[int, Any] = {}
-        by_jdev = {jd: sp for sp, jd in self._jdev.items()}
-        for shard in rep.addressable_shards:
-            sp = by_jdev.get(shard.device)
-            if sp in want:
-                out[sp] = shard.data
+        with open_span(self._es(), "ici.bcast", bytes=nbytes * len(want),
+                       ndst=len(want)):
+            rep = device_put_replicated_private(payload, sharding)
+        by_jdev = {self._jdev[s]: s for s in want}
+        out = {by_jdev[shard.device]: shard.data
+               for shard in rep.addressable_shards}
         self.stats.bcasts += 1
-        self.stats.bcast_bytes += getattr(payload, "nbytes", 0) * len(out)
+        self.stats.bcast_bytes += nbytes * len(out)
         return out
 
     # ------------------------------------------------------------------
@@ -263,7 +293,10 @@ class IciEngine:
             prog = self._prog_cache.get(key)
             if prog is None:
                 prog = self._prog_cache[key] = permute_program(mesh, perm)
-        with self._launch_lock:
+        nbytes = int(np.prod(shape)) * np.dtype(dtype).itemsize \
+            if shape else 0
+        with open_span(self._es(), "ici.permute", bytes=nbytes * len(perm),
+                       ndst=len(perm)), self._launch_lock:
             # dispatch AND completion inside the lock: async dispatch
             # alone could still leave per-device enqueues of two
             # collectives interleaved (see _launch_lock)
@@ -280,18 +313,134 @@ class IciEngine:
             if pos not in recv:
                 continue
             out[(pos_to_space[recv[pos]], sp)] = shard.data[0]
-        nbytes = int(np.prod(shape)) * np.dtype(dtype).itemsize \
-            if shape else 0
         self.stats.permutes += 1
         self.stats.permute_edges += len(perm)
         self.stats.permute_bytes += nbytes * len(perm)
         return out
 
     # ------------------------------------------------------------------
-    # runtime hook: collective panel broadcast on dataflow fan-out
+    # runtime hook: a flow fans out onto other chips (release_deps)
     # ------------------------------------------------------------------
-    def prebroadcast(self, copy: DataCopy, target_spaces: Sequence[int]
-                     ) -> int:
+    def fan_out(self, taskpool, copy: DataCopy, deliveries) -> None:
+        """A produced copy goes to ``deliveries`` ((successor class,
+        locals, ...) tuples): count the consumers each OTHER chip will
+        see, so the replica there can leave at its last one
+        (:meth:`expect`), and move the tile now.  Onto DISTINCT consumer
+        devices: one collective replication; onto a single one (one
+        consumer, or several sharing a device): one proactive d2d put
+        that overlaps with scheduling, deferred so that the whole DAG
+        wavefront (stencil halos, ring neighbor hops, panel sends) rides
+        ONE batched CollectivePermute instead of N puts (reference:
+        dataflow bcast trees remote_dep.c:334-357 and the CE put; SURVEY
+        §5.8 ICI lowering).  A host-resident copy with one target falls
+        through to lazy stage-in."""
+        readers: Dict[int, int] = {}
+        for d in deliveries:
+            sp = self.predicted_space(d[0], d[1])
+            if sp is not None and sp != copy.device:
+                readers[sp] = readers.get(sp, 0) + 1
+        if not readers:
+            return
+        self.expect(taskpool, copy, readers)
+        if len(readers) > 1:
+            self.prebroadcast(copy, sorted(readers), counted=True)
+        else:
+            sp = next(iter(readers))
+            if not self.defer_place(copy, sp, counted=True):
+                self.preplace(copy, sp, counted=True)
+
+    def expect(self, taskpool, copy: DataCopy, readers: Dict[int, int]
+               ) -> None:
+        """``readers[space]`` consumers will read ``copy``'s datum on
+        ``space``: whatever SHARED copy serves them there — pushed by
+        this engine or pulled by the first consumer's stage-in — is that
+        chip's replica until the last of them has been counted down
+        (:meth:`consumed`), or the taskpool ends (:meth:`release_pool`)."""
+        datum = copy.data
+        if datum is None:
+            return
+        with datum._lock:
+            rr = datum.replica_readers
+            if rr is None:
+                rr = datum.replica_readers = {}
+            for sp, n in readers.items():
+                rr[sp] = rr.get(sp, 0) + n
+        taskpool.replica_data.add(datum)
+
+    def consumed(self, task, datums) -> None:
+        """``task``'s inputs are unpinned: count it down on the replica
+        of each datum it read, on the chip where it was EXPECTED — the
+        count is by consumer, so one that ran elsewhere still frees the
+        replica that waited for it.  The last one releases it."""
+        sp = -1
+        for datum in datums:
+            rr = datum.replica_readers
+            if not rr:
+                continue
+            if sp == -1:
+                sp = self.predicted_space(task.task_class, task.locals)
+            with datum._lock:
+                left = rr.get(sp)
+                if left is None:
+                    continue
+                if left > 1:
+                    rr[sp] = left - 1
+                    continue
+                del rr[sp]
+            self._by_space[sp].release_replica(datum)
+
+    def release_pool(self, taskpool) -> None:
+        """The taskpool has ended: whatever its consumers left counted
+        (they ran on the host, were cancelled, or were never expected
+        where they ran) is released now."""
+        data, taskpool.replica_data = taskpool.replica_data, set()
+        for datum in data:
+            with datum._lock:
+                datum.replica_readers = None
+                spaces = [sp for sp, c in datum._copies.items()
+                          if c.flags & FLAG_REPLICA]
+            for sp in spaces:
+                self._by_space[sp].release_replica(datum)
+
+    def _attach(self, copy: DataCopy, arrays: Dict[int, Any],
+                counted: bool) -> int:
+        """Attach freshly-moved replicas of ``copy`` to its datum as
+        SHARED copies (version-guarded: a consumer that already wrote a
+        newer version wins) and register them with their devices' HBM
+        ledgers.  A ``counted`` replica whose consumers have all been
+        counted down meanwhile is dropped, not attached."""
+        datum = copy.data
+        adopt = []
+        with datum._lock:
+            rr = datum.replica_readers
+            for sp, arr in arrays.items():
+                if counted and not (rr and rr.get(sp)):
+                    continue      # every reader there has come and gone
+                dc = datum.copy_on(sp)
+                if dc is None:
+                    dc = DataCopy(datum, sp, payload=arr,
+                                  coherency=Coherency.SHARED,
+                                  version=copy.version)
+                    datum.attach_copy(dc)
+                elif dc.coherency == Coherency.INVALID or \
+                        dc.version < copy.version:
+                    dc.payload = arr
+                    dc.coherency = Coherency.SHARED
+                    dc.version = copy.version
+                else:
+                    continue
+                adopt.append((sp, dc))
+        for sp, dc in adopt:
+            self._by_space[sp].adopt(datum, dc)
+        return len(adopt)
+
+    def _resident(self, datum, space: int, version: int) -> bool:
+        c = datum.copy_on(space)
+        return c is not None and c.coherency != Coherency.INVALID \
+            and c.version >= version
+
+    def prebroadcast(self, copy: DataCopy, target_spaces: Sequence[int],
+                     counted: bool = False) -> int:
         """Replicate a produced copy onto the consumer devices in one
         collective, attaching SHARED device copies to its datum so each
         consumer's stage-in finds the tile resident (zero further
@@ -302,41 +451,26 @@ class IciEngine:
             # chain-held placeholder (devices/xla.py Deferred): the value
             # does not exist yet — consumers lazily stage (and force) it
             return 0
-        spaces = sorted({s for s in target_spaces
-                         if s in self._jdev})
         with datum._lock:
-            missing = [s for s in spaces
-                       if (c := datum.copy_on(s)) is None or
-                       c.coherency == Coherency.INVALID or
-                       c.version < copy.version]
+            missing = [s for s in sorted(set(target_spaces))
+                       if s in self._jdev
+                       and not self._resident(datum, s, copy.version)]
         if len(missing) < int(params.get("comm_ici_bcast_min", 2)):
-            return 0
-        replicas = self.bcast(copy.payload, missing)
-        attached = 0
-        adopt = []
-        with datum._lock:
-            for sp, arr in replicas.items():
-                existing = datum.copy_on(sp)
-                if existing is None:
-                    dc = DataCopy(datum, sp, payload=arr,
-                                  coherency=Coherency.SHARED,
-                                  version=copy.version)
-                    datum.attach_copy(dc)
-                    adopt.append((sp, dc))
-                    attached += 1
-                elif existing.coherency == Coherency.INVALID or \
-                        existing.version < copy.version:
-                    existing.payload = arr
-                    existing.coherency = Coherency.SHARED
-                    existing.version = copy.version
-                    adopt.append((sp, existing))
-                    attached += 1
-        self._adopt(datum, adopt)
+            # too few for a collective (on a 2 x 2 grid a row panel has
+            # one other chip to reach): a put each, from the chip where
+            # the tile is resident
+            src = copy if self.device_resident(copy) \
+                else datum.copy_on(self._resident_on(datum))
+            return sum(self.preplace(src, s, counted) for s in missing) \
+                if src is not None else 0
+        attached = self._attach(copy, self.bcast(copy.payload, missing),
+                                counted)
         debug_verbose(7, "ici prebroadcast: %d replicas of %s", attached,
                       datum)
         return attached
 
-    def preplace(self, copy: DataCopy, space: int) -> bool:
+    def preplace(self, copy: DataCopy, space: int,
+                 counted: bool = False) -> bool:
         """Single-consumer counterpart of :meth:`prebroadcast`: move one
         produced device-resident tile onto the consumer's device NOW —
         overlapping the transfer with scheduling — instead of lazily
@@ -350,34 +484,9 @@ class IciEngine:
         if copy.device == space or copy.device not in self._jdev:
             return False      # host-resident payloads stage in normally
         with datum._lock:
-            existing = datum.copy_on(space)
-            if existing is not None and \
-                    existing.coherency != Coherency.INVALID and \
-                    existing.version >= copy.version:
-                return False  # already resident
-        arr = self.put(copy.payload, space)
-        return self._attach_placed(copy, space, arr)
-
-    def _attach_placed(self, copy: DataCopy, space: int, arr) -> bool:
-        """Attach a freshly-moved replica to the datum as a SHARED copy on
-        ``space`` (version-guarded: a consumer that already wrote a newer
-        version wins) and register it with the device's HBM ledger."""
-        datum = copy.data
-        placed = None
-        with datum._lock:
-            existing = datum.copy_on(space)
-            if existing is None:
-                placed = DataCopy(datum, space, payload=arr,
-                                  coherency=Coherency.SHARED,
-                                  version=copy.version)
-                datum.attach_copy(placed)
-            elif existing.version <= copy.version:
-                existing.payload = arr
-                existing.coherency = Coherency.SHARED
-                existing.version = copy.version
-                placed = existing
-        if placed is not None:
-            self._adopt(datum, [(space, placed)])
+            if self._resident(datum, space, copy.version):
+                return False
+        self._attach(copy, {space: self.put(copy.payload, space)}, counted)
         return True
 
     # ------------------------------------------------------------------
@@ -386,7 +495,8 @@ class IciEngine:
     # the per-peer aggregation of the comm thread, remote_dep_mpi.c —
     # here aggregation happens across DEVICE edges of one wavefront)
     # ------------------------------------------------------------------
-    def defer_place(self, copy: DataCopy, space: int) -> bool:
+    def defer_place(self, copy: DataCopy, space: int,
+                    counted: bool = False) -> bool:
         """Queue a device-resident single-consumer placement; when the
         batch completes a permutation round (every device sends/receives
         at most once) — or an idle worker drains the window
@@ -400,11 +510,8 @@ class IciEngine:
                 or self.ndev < 2:
             return False
         with datum._lock:
-            existing = datum.copy_on(space)
-            if existing is not None and \
-                    existing.coherency != Coherency.INVALID and \
-                    existing.version >= copy.version:
-                return False  # already resident
+            if self._resident(datum, space, copy.version):
+                return False
         import time
         now = time.monotonic()
         window = float(params.get("comm_ici_permute_window_ms", 2.0)) / 1e3
@@ -423,7 +530,7 @@ class IciEngine:
                 # launches" contract.
                 immediate = True
             else:
-                self._pending_edges.append((copy, space, now))
+                self._pending_edges.append((copy, space, now, counted))
                 # flush when the batch completes a permutation round —
                 # OR when the oldest deferred edge has already outlived
                 # the window (under load the gaps between wavefront
@@ -440,7 +547,7 @@ class IciEngine:
                     flush_now, self._pending_edges = self._pending_edges, []
             self._last_edge = now
         if immediate:
-            return self.preplace(copy, space)
+            return self.preplace(copy, space, counted)
         if flush_now:
             self._flush_edges(flush_now)
         return True
@@ -471,30 +578,30 @@ class IciEngine:
 
     def _flush_edges(self, edges) -> None:
         live = []
-        for copy, space, _t in edges:
+        for copy, space, _t, counted in edges:
             p = copy.payload
             if p is None or (hasattr(p, "is_deleted") and p.is_deleted()):
                 continue     # evicted/donated since: consumer stages lazily
             datum = copy.data
             with datum._lock:
-                existing = datum.copy_on(space)
-                if existing is not None and \
-                        existing.coherency != Coherency.INVALID and \
-                        existing.version >= copy.version:
-                    # the consumer staged in (or wrote) while the edge sat
-                    # in the window: a collective for it would move bytes
+                rr = datum.replica_readers
+                if self._resident(datum, space, copy.version) or \
+                        (counted and not (rr and rr.get(space))):
+                    # the consumer staged in (or wrote), or every counted
+                    # one has come and gone, while the edge sat in the
+                    # window: a collective for it would move bytes
                     # nobody reads
                     continue
-            live.append((copy, space))
+            live.append((copy, space, counted))
         if not live:
             return
         if len(live) < int(params.get("comm_ici_permute_min", 2)):
-            for copy, space in live:
-                self.preplace(copy, space)
+            for copy, space, counted in live:
+                self.preplace(copy, space, counted)
             return
         # unique (src, dst) keys per permute() call: duplicate pairs would
         # collide in its result map, so they go in follow-up calls
-        calls: List[List[Tuple[DataCopy, int]]] = []
+        calls: List[List[Tuple[DataCopy, int, bool]]] = []
         for item in live:
             key = (item[0].device, item[1])
             for c in calls:
@@ -507,20 +614,20 @@ class IciEngine:
             try:
                 results = self.permute(
                     [(copy.device, space, copy.payload)
-                     for copy, space in c])
+                     for copy, space, _c in c])
             except Exception as exc:
                 debug_verbose(3, "ici permute batch failed (%s); "
                               "falling back to puts", exc)
-                for copy, space in c:
+                for copy, space, counted in c:
                     try:
-                        self.preplace(copy, space)
+                        self.preplace(copy, space, counted)
                     except Exception:
                         pass      # best-effort prefetch
                 continue
-            for copy, space in c:
+            for copy, space, counted in c:
                 arr = results.get((copy.device, space))
                 if arr is not None:
-                    self._attach_placed(copy, space, arr)
+                    self._attach(copy, {space: arr}, counted)
 
     def device_resident(self, copy: DataCopy) -> bool:
         """Cheap hot-path gate: only device-resident produced copies are
@@ -529,37 +636,38 @@ class IciEngine:
         return copy.device in self._jdev and copy.payload is not None \
             and not getattr(copy.payload, "parsec_deferred", False)
 
-    def _adopt(self, datum, placed) -> None:
-        """Register externally-attached copies with their device's HBM
-        ledger so eviction/budget accounting can see them."""
-        by_space = {d.space: d for d in self.xla_devices}
-        for sp, dc in placed:
-            dev = by_space.get(sp)
-            if dev is not None and hasattr(dev, "adopt"):
-                dev.adopt(datum, dc)
+    def predicted_space(self, tc, locals_) -> Optional[int]:
+        """Best-effort device target of one task, by the rules of
+        ``DeviceRegistry.best_device`` in their order: the chip its
+        affinity datum is pinned to, else the chip of the datum a
+        ``coaffinity`` hint names (a panel's diagonal tile), else the
+        chip where the affinity datum is resident (reference:
+        parsec_get_best_device's data-affinity rule, device.c:79-140)."""
+        if tc.affinity is None:
+            return None
+        try:
+            datum = tc.affinity(locals_).resolve()
+            if datum.preferred_device in self._jdev:
+                return datum.preferred_device
+            coaff = tc.properties.get("coaffinity")
+            if coaff is not None and int(params.get("device_fuse_panel", 1)):
+                sp = self._home(coaff(locals_).resolve())
+                if sp is not None:
+                    return sp
+        except Exception:
+            return None
+        return self._resident_on(datum)
 
-    def consumer_spaces(self, taskpool, deliveries) -> List[int]:
-        """Best-effort device targets for a list of local deliveries:
-        each successor's affinity datum names its preferred/resident
-        accelerator (reference: parsec_get_best_device's data-affinity
-        rule, device.c:79-140)."""
-        spaces: List[int] = []
-        for succ_tc, succ_locals, _dflow in deliveries:
-            if succ_tc.affinity is None:
-                continue
-            try:
-                ref = succ_tc.affinity(succ_locals)
-                datum = ref.resolve()
-            except Exception:
-                continue
-            pref = datum.preferred_device
-            if pref is not None and pref in self._jdev:
-                spaces.append(pref)
-                continue
-            v = datum.newest_version()
-            for sp, c in datum.copies().items():
-                if sp in self._jdev and c.version == v \
-                        and c.coherency != Coherency.INVALID:
-                    spaces.append(sp)
-                    break
-        return spaces
+    def _resident_on(self, datum) -> Optional[int]:
+        v = datum.newest_version()
+        for sp, c in datum.copies().items():
+            if sp in self._jdev and c.version == v \
+                    and c.coherency != Coherency.INVALID \
+                    and c.payload is not None:
+                return sp
+        return None
+
+    def _home(self, datum) -> Optional[int]:
+        if datum.preferred_device in self._jdev:
+            return datum.preferred_device
+        return self._resident_on(datum)

@@ -432,29 +432,13 @@ def release_deps(es, task: Task) -> List[Task]:
         if copy is not None and ici is not None and local_deliveries \
                 and (len(local_deliveries) > 1
                      or ici.device_resident(copy)):
-            # Fan-out onto DISTINCT consumer devices: one collective
-            # replication; a single distinct target (one consumer, or
-            # several sharing a device): one proactive d2d put that
-            # overlaps with scheduling (reference: dataflow bcast trees
-            # remote_dep.c:334-357 and the CE put; SURVEY §5.8 ICI
-            # lowering).  Host-resident single-consumer edges — the
-            # dominant same-device case — skip the affinity resolution
-            # entirely; multi-consumer fan-outs qualify even from host
-            # (one replication beats N separate stage-ins).
-            uniq = set(ici.consumer_spaces(
-                tp, [d[:3] for d in local_deliveries]))
-            uniq.discard(copy.device)
-            if len(uniq) > 1:
-                ici.prebroadcast(copy, sorted(uniq))
-            elif len(uniq) == 1:
-                # single-consumer edge: defer so the whole DAG wavefront
-                # (stencil halos, ring neighbor hops, panel sends) rides
-                # ONE batched CollectivePermute instead of N puts
-                # (SURVEY §5.8); host-resident copies fall through to
-                # lazy stage-in as before
-                sp = uniq.pop()
-                if not ici.defer_place(copy, sp):
-                    ici.preplace(copy, sp)
+            # the flow may leave this chip: ici counts the consumers of
+            # each other chip and moves the tile (comm/ici.py fan_out).
+            # Host-resident single-consumer edges — the dominant
+            # same-device case — skip the affinity resolution entirely;
+            # multi-consumer fan-outs qualify even from host (one
+            # replication beats N separate stage-ins).
+            ici.fan_out(tp, copy, local_deliveries)
         for succ_tc, succ_locals, dflow, odep in local_deliveries:
             dcopy = copy
             if copy is not None:

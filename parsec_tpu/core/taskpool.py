@@ -90,6 +90,9 @@ class Taskpool:
         #: collection datums whose host copy a writeback replaced; their
         #: user-visible backing re-links at termination (engine._writeback)
         self.dirty_data: set = set()
+        #: datums whose fan-out onto other chips is counted (comm/ici.py
+        #: expect): what their consumers leave is released at the end
+        self.replica_data: set = set()
         #: reshape promises: one shared conversion per (copy, dtt) edge
         #: (reference: parsec_reshape.c promise table)
         from parsec_tpu.data.reshape import ReshapeCache
@@ -206,6 +209,8 @@ class Taskpool:
             if datum.collection is not None:
                 datum.collection.refresh_backing(datum)
         self.dirty_data.clear()
+        if self.replica_data:
+            self.context.ici.release_pool(self)
         self.reshape.clear()
         cbs = list(self._complete_cbs)
         for cb in cbs:

@@ -34,6 +34,10 @@ FLAG_COW = 0x1   # payload is shared with readers: duplicate before writing
 FLAG_SCRATCH = 0x2   # NEW-flow arena buffer: content undefined until the
                      # first writer runs (device stage-in may materialize
                      # it on device instead of shipping host bytes)
+FLAG_REPLICA = 0x4   # a SHARED copy on a chip other than the producer's,
+                     # there for counted consumers (Data.replica_readers):
+                     # it is on that chip's replica ledger and leaves at
+                     # the last of them (devices/xla.py release_replica)
 
 
 class Coherency(IntEnum):
@@ -103,6 +107,11 @@ class Data:
         self._lock = threading.RLock()
         self._copies: Dict[int, DataCopy] = {}
         self._version_clock = 0   # monotonic; never regresses on invalidation
+        #: space -> consumers still to read the SHARED replica there: set
+        #: where a flow fans out onto other chips (comm/ici.py expect),
+        #: counted down as each consumer's inputs are unpinned; at zero
+        #: the replica leaves its chip.  None: no fan-out is counted
+        self.replica_readers: Optional[Dict[int, int]] = None
 
     def __repr__(self):
         return f"<Data key={self.key}>"

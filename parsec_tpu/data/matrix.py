@@ -158,12 +158,20 @@ class TiledMatrix(DataCollection):
                 if self.tile_exists(m, n)
                 and self.owner_of(m, n) == self.myrank]
 
-    def distribute_devices(self, context_or_spaces) -> "TiledMatrix":
-        """Pin local tiles block-cyclically over the process's accelerator
-        memory spaces (the intra-rank analog of rank_of: owner-computes
+    def distribute_devices(self, context_or_spaces, P: Optional[int] = None,
+                           Q: Optional[int] = None) -> "TiledMatrix":
+        """Pin local tiles block-cyclically over a P x Q grid of the
+        process's accelerator memory spaces: tile (m, n) on space
+        ``(m % P) * Q + n % Q`` of the list, as :class:`Grid2DCyclic`
+        lays ranks (the intra-rank analog of rank_of: owner-computes
         over the device mesh; reference: data-affinity device selection,
         device.c:79-140).  Accepts a Context or an explicit list of
-        memory-space indices."""
+        memory-space indices.  Without P and Q the grid is the
+        near-square factorisation of their number (4 -> 2 x 2, 8 -> 2 x
+        4, 2 -> 1 x 2), DPLASMA's usual choice: a tile of a panel is
+        then wanted on the chips of one grid row and one grid column,
+        not on all of them.  A single row or column of tiles is laid
+        over all the spaces in turn."""
         spaces = context_or_spaces
         if hasattr(spaces, "device_registry"):
             spaces = [d.space
@@ -171,12 +179,33 @@ class TiledMatrix(DataCollection):
         spaces = list(spaces)
         if not spaces:
             return self
+        P, Q = device_grid(len(spaces), P, Q,
+                           rows=self.mt, cols=self.nt)
         for (m, n) in [(m, n) for m in range(self.mt)
                        for n in range(self.nt) if self.tile_exists(m, n)
                        and self.rank_of(m, n) == self.myrank]:
             self.data_of(m, n).preferred_device = \
-                spaces[(m * self.nt + n) % len(spaces)]
+                spaces[(m % P) * Q + n % Q]
         return self
+
+
+def device_grid(n: int, P: Optional[int] = None, Q: Optional[int] = None,
+                rows: int = 0, cols: int = 0) -> Tuple[int, int]:
+    """The P x Q grid ``n`` chips are laid as: the caller's P and/or Q,
+    else one line of chips along a single row or column of tiles, else
+    P the largest divisor of n up to its square root."""
+    if P is None and Q is None:
+        if cols == 1 or rows == 1:
+            P = n if cols == 1 else 1
+        else:
+            P = max(p for p in range(1, int(n ** 0.5) + 1) if n % p == 0)
+    if P is None:
+        P = n // Q
+    if Q is None:
+        Q = n // P
+    if P < 1 or Q < 1 or P * Q != n:
+        raise ValueError(f"grid {P}x{Q} does not cover {n} devices")
+    return P, Q
 
 
 class Grid2DCyclic:

@@ -28,7 +28,9 @@ class DeviceStats:
                  "evictions", "fused_launches", "fused_tasks",
                  "chained_launches", "chained_tasks", "launches",
                  "held_tasks", "defused_waves", "starved_waits",
-                 "inflight_waits", "compiles", "warm_waits")
+                 "inflight_waits", "compiles", "warm_waits",
+                 "replicas_adopted", "replicas_released",
+                 "replica_bytes_peak")
 
     def __init__(self):
         self.executed_tasks = 0
@@ -61,6 +63,14 @@ class DeviceStats:
         self.inflight_waits = 0
         self.compiles = 0
         self.warm_waits = 0
+        #: SHARED copies this chip held for counted consumers of another
+        #: chip's tile (comm/ici.py expect; pushed over ICI or pulled by
+        #: a stage-in), how many of them left again at their last
+        #: consumer or their taskpool's end, and the high-water mark of
+        #: the bytes they held at once
+        self.replicas_adopted = 0
+        self.replicas_released = 0
+        self.replica_bytes_peak = 0
 
     def as_dict(self) -> Dict[str, int]:
         return {k: getattr(self, k) for k in self.__slots__}
@@ -162,6 +172,19 @@ class DeviceRegistry:
             accs = [d for d in accs if d.space in allowed]
         if not accs:
             return None
+        # owner computes: a written tile that was pinned to a chip
+        # (distribute_devices) keeps its task there, before any hint
+        # that would gather a panel onto one chip
+        for flow in task.task_class.flows:
+            copy = task.data.get(flow.name) \
+                if flow.access & ACCESS_WRITE else None
+            if copy is None or copy.data is None:
+                continue
+            pref = copy.data.preferred_device
+            if pref is not None and 1 <= pref < len(self.devices) \
+                    and self.devices[pref].enabled \
+                    and (allowed is None or pref in allowed):
+                return self.devices[pref]
         dev = self._coaffinity_device(task)
         if dev is not None and (allowed is None or dev.space in allowed):
             return dev
@@ -172,11 +195,6 @@ class DeviceRegistry:
             if copy is None or copy.data is None:
                 continue
             datum = copy.data
-            pref = datum.preferred_device
-            if pref is not None and 1 <= pref < len(self.devices) \
-                    and self.devices[pref].enabled \
-                    and (allowed is None or pref in allowed):
-                return self.devices[pref]
             # residency affinity: the accelerator already holding the
             # newest valid copy of the written datum wins, avoiding a
             # cross-device migration per write

@@ -36,7 +36,8 @@ import numpy as np
 
 from parsec_tpu.core.task import HookReturn, Task
 from parsec_tpu.data.data import (ACCESS_READ, ACCESS_WRITE, Coherency,
-                                  DataCopy, FLAG_COW, FLAG_SCRATCH)
+                                  DataCopy, FLAG_COW, FLAG_REPLICA,
+                                  FLAG_SCRATCH)
 from parsec_tpu.devices.device import Device
 from parsec_tpu.core.task import ToDesc
 from parsec_tpu.prof.pins import SPAN_OFF, open_span, spans_live
@@ -360,15 +361,76 @@ class _FuseWarmer:
         self._cv = threading.Condition()
         self._thread = None
         self._busy = 0
+        #: programs compiled for a chip that has not called them yet
+        #: (cover): (spec, donate, width, argument specs, device)
+        self._unprimed: List[Tuple] = []
+        self._at_exit = False     # _drain_at_exit is registered
 
-    def submit(self, spec, key, donate, n, arg_specs, device=None) -> None:
+    def _drain_at_exit(self) -> None:
+        """Drop what is queued and let the compile in hand finish: the
+        interpreter tears a daemon thread down inside XLA otherwise, and
+        the process aborts on its way out."""
         with self._cv:
-            self._q.append((spec, key, donate, n, arg_specs, device))
+            self._q.clear()
+            self._cv.wait_for(lambda: not self._busy, 120.0)
+
+    def submit(self, spec, key, donate, n, arg_specs, device=None,
+               prime: bool = False) -> None:
+        with self._cv:
+            self._q.append((spec, key, donate, n, arg_specs, device, prime))
             if self._thread is None or not self._thread.is_alive():
+                if not self._at_exit:
+                    import atexit
+                    atexit.register(self._drain_at_exit)
+                    self._at_exit = True
                 self._thread = threading.Thread(
                     target=self._run, daemon=True, name="xla-fuse-warm")
                 self._thread.start()
             self._cv.notify_all()
+
+    def cover(self, spec, donate, n, flat, device, chips) -> None:
+        """A context that drives several chips met a wave of a kernel on
+        one of them: which power-of-two wave widths meet on which chip
+        is timing, so what one chip asks for every chip will — queue
+        every width up to ``device_fuse``, the single included, for
+        every chip, once a (class, donation, signature) a process; the
+        asking chip's own width first.  (A class that never meets in a
+        wave — a panel's diagonal kernel — runs where its tile is pinned,
+        the same in every job, and is left alone.)  Each lands in the
+        persistent cache and is marked ready where
+        ``XlaKernel.fuse_ready`` looks; ``prime`` then makes the jitted
+        call itself on the chips that have not."""
+        k = len(spec.arg_names)
+        args = list(flat[:k])
+        sig = tuple((tuple(a.shape), str(a.dtype)) if hasattr(a, "shape")
+                    else a for a in args)
+        seen = ("cover", spec.cls, donate, sig)
+        state = spec._cache
+        if seen in state:
+            return
+        import jax
+        from jax.sharding import SingleDeviceSharding
+        import time as _time
+        limit = max(1, int(params.get("device_fuse", 8)))
+        widths = [1 << i for i in range(limit.bit_length())]
+        todo = []
+        with XlaKernel._jit_cv:
+            if seen in state:
+                return
+            state[seen] = True
+            deadline = _time.monotonic() + 1e-3 * float(
+                params.get("device_fuse_warm_wait_ms", 3000.0))
+            for dev in sorted(chips, key=lambda d: d is not device):
+                sh = SingleDeviceSharding(dev.jdev)
+                one = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
+                       if hasattr(a, "shape") else a for a in args]
+                for w in sorted(widths, key=lambda w: w != n):
+                    key = ("w", spec.cls, donate, w, sig * w, dev.name)
+                    if key not in state:
+                        state[key] = ("warming", deadline)
+                        todo.append((key, w, one * w, dev))
+        for key, w, specs, dev in todo:
+            self.submit(spec, key, donate, w, specs, dev, prime=True)
 
     def wait_idle(self, timeout: float = 600.0) -> bool:
         """Block until every queued width compile has finished — the
@@ -396,7 +458,8 @@ class _FuseWarmer:
                     if not self._q:
                         self._thread = None
                         return
-                spec, key, donate, n, arg_specs, device = self._q.popleft()
+                spec, key, donate, n, arg_specs, device, prime = \
+                    self._q.popleft()
                 self._busy += 1
             reason = None
             if device is not None:
@@ -418,8 +481,54 @@ class _FuseWarmer:
                     ("failed", _time.monotonic(), reason)
                 XlaKernel._jit_cv.notify_all()
             with self._cv:
+                if prime and reason is None:
+                    self._unprimed.append((spec, donate, n, arg_specs,
+                                           device))
                 self._busy -= 1
                 self._cv.notify_all()
+
+    def prime(self) -> int:
+        """Call, once, every program that ``cover`` compiled for a chip
+        and that chip has not called since: the first call of a jitted
+        function on a chip traces it and reads its executable back from
+        the persistent cache, which is a compile to whoever counts them,
+        and belongs to the warm-up.  The arguments are zeros made on the
+        chip; the caller is at a quiet point (``wait_fuse_warm``)."""
+        import jax
+        import jax.numpy as jnp
+        with self._cv:
+            todo, self._unprimed = self._unprimed, []
+        shared: Dict[Tuple, Any] = {}
+        called = 0
+        for spec, donate, n, arg_specs, device in todo:
+            jf = spec.jitted_fused(donate, n)
+            if device.jdev.id in getattr(jf, "_parsec_ran", ()) \
+                    or not device.enabled:
+                continue
+            k = len(spec.arg_names)
+            args = []
+            for i, a in enumerate(arg_specs):
+                if not hasattr(a, "shape"):
+                    args.append(a)
+                    continue
+                name = spec.arg_names[i % k]
+                zkey = (device.jdev.id, tuple(a.shape), str(a.dtype))
+                if donate and name in spec.writable:
+                    # a donated position needs a buffer of its own
+                    args.append(jnp.zeros(a.shape, a.dtype,
+                                          device=device.jdev))
+                else:
+                    if zkey not in shared:
+                        shared[zkey] = jnp.zeros(a.shape, a.dtype,
+                                                 device=device.jdev)
+                    args.append(shared[zkey])
+            try:
+                jax.block_until_ready(device._call(jf, args))
+                called += 1
+            except Exception as exc:
+                warning("priming %s on %s failed: %s", _program_name(jf),
+                        device.name, exc)
+        return called
 
 
 _fuse_warmer = _FuseWarmer()
@@ -451,8 +560,13 @@ def wait_fuse_warm(timeout: float = 600.0) -> bool:
     """Wait for all in-flight fused-width background compiles (benches
     call this between warmup and timed reps, then run ONE more warm
     pass so the newly-ready widths' client-side jit calls also land in
-    cache — otherwise reps run de-fused singles while widths warm)."""
-    return _fuse_warmer.wait_idle(timeout)
+    cache — otherwise reps run de-fused singles while widths warm).
+    Where the context drives several chips, every width compiled for a
+    chip that has not met it is then called there once
+    (``_FuseWarmer.prime``): the caller is between jobs."""
+    ok = _fuse_warmer.wait_idle(timeout)
+    _fuse_warmer.prime()
+    return ok
 
 
 class Deferred:
@@ -700,6 +814,9 @@ class XlaDevice(Device):
         cap_mb = int(params.get("device_mem_mb", 0))
         self._capacity = cap_mb * (1 << 20) if cap_mb > 0 else None
         self._bytes_used = 0
+        #: bytes of the replicas this chip holds now (stats.replica_bytes_peak
+        #: is its high-water mark); under _mem_lock
+        self._replica_bytes = 0
         #: (kernel name, width) -> the compiler's words, for every fused
         #: width this device asked for whose background compile failed
         #: (its waves ran as singles; XlaKernel.fuse_ready records it).
@@ -1041,11 +1158,15 @@ class XlaDevice(Device):
                 self._hold_task(batch[0], flat, pinned_per[0],
                                 release_per[0], seq)
                 return True
+            # a panel-chain link (POTRF, GEQRT, TSQRT) is the class whose
+            # every width is a Cholesky-class compile: not for cover()
+            cover = not batch[0][0].task_class.properties.get("fuse_chain")
             if any(isinstance(a, Deferred) for a in flat):
-                outs_per_task = self._dispatch_chained(spec, n, flat)
+                outs_per_task = self._dispatch_chained(spec, n, flat, cover)
                 fused = False
             else:
-                fused, outs_per_task = self._dispatch_plain(spec, n, flat)
+                fused, outs_per_task = self._dispatch_plain(spec, n, flat,
+                                                            cover)
             if fused:
                 # count only waves the fused program actually executed —
                 # a de-fused n>1 wave (fuse_ready False) ran singles
@@ -1114,11 +1235,19 @@ class XlaDevice(Device):
                        first=int(first)):
             return jf(*args)
 
-    def _dispatch_plain(self, spec: XlaKernel, n: int, flat: List[Any]):
+    def _dispatch_plain(self, spec: XlaKernel, n: int, flat: List[Any],
+                        cover: bool = False):
         """The pre-existing dispatch path: one (possibly width-fused)
         jitted call over real arrays.  Returns (fused, bound outputs per
-        task)."""
+        task).  ``cover``: the class is one whose widths may be warmed
+        on every chip (``_FuseWarmer.cover``)."""
         donate = self._donate and not self._donation_hazard(spec, flat)
+        ici = self.es.context.ici if cover and n > 1 else None
+        if ici is not None and int(params.get("device_fuse_bg", 1)):
+            # several chips, and a class that meets in waves: what this
+            # chip runs of it, every chip will
+            _fuse_warmer.cover(spec, donate, n, flat, self,
+                               ici.xla_devices)
 
         if n == 1:
             fused, results = False, [self._call(spec.jitted(donate), flat)]
@@ -1146,7 +1275,8 @@ class XlaDevice(Device):
         class names a 'fuse_chain' (flow, successor class), the run is
         single-rank (remote activations must never see a Deferred
         payload), and the chain flow has at least one task successor to
-        force the eventual launch."""
+        force the eventual launch — all of them, where the context
+        drives several chips, expected on this one."""
         try:
             if not int(params.get("device_fuse_panel", 1)):
                 return False
@@ -1164,14 +1294,33 @@ class XlaDevice(Device):
         if flow is None:
             return False
         from parsec_tpu.core.task import ToTask
+        ici = ctx.ici
+        found = False
         try:
             for dep in flow.active_outputs(task.locals):
                 if isinstance(dep.end, ToTask):
-                    for _ in dep.end.instances(task.locals):
-                        return True
+                    if ici is None:
+                        for _ in dep.end.instances(task.locals):
+                            return True
+                        continue
+                    # several chips: a head is held only where the whole
+                    # chain stays on its chip.  A consumer elsewhere
+                    # would force the chain alone, so which programs a
+                    # panel runs (head alone, head + wave of 1, 2, 4, 8)
+                    # would be a matter of timing between the chips, each
+                    # a Cholesky-class compile; dispatched plainly, the
+                    # head is one program and its output a real tile
+                    # that ICI moves at once
+                    succ_tc = tp.task_classes[dep.end.task_class]
+                    for loc in dep.end.instances(task.locals):
+                        if ici.predicted_space(
+                                succ_tc, succ_tc.complete_locals(loc)) \
+                                != self.space:
+                            return False
+                        found = True
         except Exception:
             return False
-        return False
+        return found
 
     def _hold_task(self, item, flat, pinned, release_after, seq=0) -> None:
         """Park a chain head: its outputs become Deferred payloads on
@@ -1338,8 +1487,8 @@ class XlaDevice(Device):
                     hd.state = "held"
             self._chain_cv.notify_all()
 
-    def _dispatch_chained(self, spec: XlaKernel, n: int,
-                          flat: List[Any]) -> List[Dict[str, Any]]:
+    def _dispatch_chained(self, spec: XlaKernel, n: int, flat: List[Any],
+                          cover: bool = False) -> List[Dict[str, Any]]:
         """Launch a wave whose inputs include unresolved chain
         placeholders: claim the chain, trace it in front of the wave in
         one program, resolve the held tasks' outputs from the same
@@ -1354,7 +1503,7 @@ class XlaDevice(Device):
             if not claimed:
                 if any(isinstance(a, Deferred) for a in flat):
                     continue          # raced a fresh hold: re-claim
-                _f, outs = self._dispatch_plain(spec, n, flat)
+                _f, outs = self._dispatch_plain(spec, n, flat, cover)
                 return outs
             try:
                 node_outs, wave_outs = self._run_chain(claimed, spec, n,
@@ -1526,6 +1675,8 @@ class XlaDevice(Device):
             self.stats.bytes_in += nbytes
             if fresh:
                 self._account(datum, dc, nbytes, off)
+            if not access & ACCESS_WRITE:
+                self._note_replica(datum, dc)
         if copy.flags & FLAG_COW and copy is not dc:
             # The COW alias's payload aliases the producer's buffer (for
             # DATA-fed fan-outs: the collection's backing array).  The
@@ -1723,6 +1874,11 @@ class XlaDevice(Device):
             self.load_sub(inf.load)
             for d in inf.pinned:
                 self._unpin(d)
+            ici = inf.es.context.ici
+            if ici is not None:
+                # deps were released at dispatch and the chip's queue is
+                # in order: a replica this task read may leave now
+                ici.consumed(inf.task, inf.pinned)
             for copy in inf.release_after:
                 # a predecessor's repo entry may still hold this
                 # superseded host buffer for its OTHER consumers
@@ -1783,6 +1939,61 @@ class XlaDevice(Device):
             self._mem_lock.notify_all()
         weakref.finalize(dc, self._forget, key, nbytes)
         self.stats.bytes_in += nbytes
+        self._note_replica(datum, dc)
+
+    def _note_replica(self, datum, dc: DataCopy) -> None:
+        """``dc`` serves consumers that are counted on this chip
+        (``datum.replica_readers``): enter it in the replica ledger,
+        once, whoever brought it — the ICI engine's push or a consumer's
+        own stage-in."""
+        rr = datum.replica_readers
+        if not rr:
+            return
+        with datum._lock:
+            if not rr.get(self.space) or dc.flags & FLAG_REPLICA \
+                    or datum.copy_on(self.space) is not dc:
+                return
+            dc.flags |= FLAG_REPLICA
+            nbytes = getattr(dc.payload, "nbytes", 0)
+        with self._mem_lock:
+            self.stats.replicas_adopted += 1
+            self._replica_bytes += nbytes
+            if self._replica_bytes > self.stats.replica_bytes_peak:
+                self.stats.replica_bytes_peak = self._replica_bytes
+
+    def release_replica(self, datum) -> None:
+        """The last counted consumer of ``datum``'s replica on this chip
+        is through (comm/ici.py consumed / release_pool): detach the
+        SHARED copy, drop its payload and give its bytes back to the
+        ledger.  Never a write-back and never the owner's copy: a copy
+        that was written here since, or that is the only valid one, only
+        stops being a replica."""
+        with datum._lock:
+            dc = datum.copy_on(self.space)
+            if dc is None or not dc.flags & FLAG_REPLICA:
+                return
+            dc.flags &= ~FLAG_REPLICA
+            nbytes = getattr(dc.payload, "nbytes", 0)
+            drop = dc.coherency == Coherency.INVALID or (
+                dc.coherency == Coherency.SHARED and any(
+                    c is not dc and c.coherency != Coherency.INVALID
+                    and c.version >= dc.version
+                    for c in datum._copies.values()))
+            if drop:
+                datum.detach_copy(self.space)
+                dc.payload = None
+                dc.coherency = Coherency.INVALID
+        with self._mem_lock:
+            self._replica_left_locked(nbytes)
+            ent = self._lru.get(id(datum))
+            if drop and ent is not None and ent[0]() is dc:
+                del self._lru[id(datum)]
+                self._bytes_used -= ent[1]
+                self._zone_free(ent[2])
+
+    def _replica_left_locked(self, nbytes: int) -> None:
+        self.stats.replicas_released += 1
+        self._replica_bytes -= nbytes
 
     def sync(self, timeout: Optional[float] = None) -> None:
         """Drain the device: block until every dispatched kernel has
@@ -1919,6 +2130,9 @@ class XlaDevice(Device):
         if dc.coherency in (Coherency.OWNED, Coherency.EXCLUSIVE) and \
                 dc.version >= datum.newest_version():
             self._writeback_host(datum, dc)
+        if dc.flags & FLAG_REPLICA:
+            dc.flags &= ~FLAG_REPLICA
+            self._replica_left_locked(getattr(dc.payload, "nbytes", 0))
         datum.detach_copy(self.space)
         dc.payload = None
         dc.coherency = Coherency.INVALID
@@ -1955,6 +2169,9 @@ class XlaDevice(Device):
                 if datum is None or datum.collection is not None:
                     continue   # user-visible data keeps flush semantics
                 del self._lru[key]
+                if dc.flags & FLAG_REPLICA:
+                    dc.flags &= ~FLAG_REPLICA
+                    self._replica_left_locked(sz)
                 # _mem_lock -> datum._lock is the established order
                 # (_reserve's eviction path writes back under it), so
                 # taking the per-datum lock here is deadlock-free and

@@ -39,6 +39,11 @@ SPAN_NAMES = ("mgr.starved", "mgr.launch", "mgr.pop_wave", "mgr.stage_in",
               "mgr.dispatch", "mgr.inflight_wait", "mgr.warm_wait",
               "fin.idle", "fin.release", "fin.drain", "worker.idle",
               "warm.compile")
+#: the ICI transport's spans (comm/ici.py), around each data movement
+#: between chips, on whichever thread releases the deps (mostly a
+#: completer, inside its ``fin.release``); arguments ``bytes``, ``ndst``.
+#: Only a context that drives several chips emits them
+ICI_SPAN_NAMES = ("ici.put", "ici.bcast", "ici.permute")
 #: what the spans are called in the profiler's trace: ``parsec:mgr.launch``
 SPAN_PREFIX = "parsec:"
 

@@ -100,7 +100,9 @@ def test_multichip_phase_four_devices(devices):
         assert moved["bcasts"] + moved["puts"] + moved["permutes"] > 0
         assert one["ici"] == {}
         json.dumps(many)
-    assert by[("gemm", "many")]["ici"]["bcasts"] > 0
+    # on the 2 x 2 grid a GEMM panel has one other chip to reach: a put
+    moved = by[("gemm", "many")]["ici"]
+    assert moved["bcasts"] + moved["puts"] + moved["permutes"] > 0
 
 
 def test_idle_device_fails_the_distributed_check():
