@@ -1762,7 +1762,8 @@ def _potrf_flop_accounting(mb: int, nt: int, peak_gflops: float,
                            achieved_gflops: float):
     """Executed-flop accounting of the tiled Cholesky (apps/potrf.py):
     every class is DEFAULT-precision matmul-class work; the interesting
-    ratio is executed/useful (the TRSM-by-inverse + full-SYRK tax)."""
+    ratio is executed/useful (the inverse, and what TRSM and SYRK still
+    execute above the triangle's mb^3 at ``tri_blocks`` blocks an edge)."""
     counts = {
         "POTRF": max(nt - 1, 0) if nt > 1 else 0,
         "POTRFL": 1,
@@ -1771,10 +1772,9 @@ def _potrf_flop_accounting(mb: int, nt: int, peak_gflops: float,
         "GEMM": sum((nt - 1 - k) * (nt - 2 - k) // 2
                     for k in range(nt - 1)),
     }
-    per = {"POTRF": (0.0, mb ** 3), "POTRFL": (0.0, mb ** 3 / 3.0),
-           "TRSM": (0.0, 2.0 * mb ** 3), "SYRK": (0.0, 2.0 * mb ** 3),
-           "GEMM": (0.0, 2.0 * mb ** 3)}
-    from parsec_tpu.apps.potrf import potrf_flops as _pf
+    from parsec_tpu.apps.potrf import (potrf_executed_flops,
+                                       potrf_flops as _pf)
+    per = {cls: (0.0, potrf_executed_flops(cls, mb)) for cls in counts}
     return _emit_accounting("potrf", counts, per, _pf(nt * mb),
                             peak_gflops, achieved_gflops)
 

@@ -22,11 +22,18 @@ L^-1 — computed by recursive block inversion whose leaves use the Newton
 iteration X <- X(2I - LX).  For triangular L with X0 = diag(L)^-1 the
 residual I - LX0 is strictly lower triangular, i.e. NILPOTENT, and the
 iteration SQUARES it, so ceil(log2(n)) iterations reach the exact
-inverse — everything is matmuls on the MXU.  Each TRSM then becomes a
-single matmul A[m,k] @ W^T at full systolic-array rate instead of a
+inverse — everything is matmuls on the MXU.  Each TRSM then becomes
+matmuls A[m,k] @ W^T at full systolic-array rate instead of a
 triangular solve.  The extra mb^3/3 inverse flops per panel are ~1% of
 the factorization and buy back a >4x faster panel wave (measured on
 v5e: jsl trsm ~18 TF/s vs matmul ~150 TF/s).
+
+The panel kernels use the triangle (``tri_blocks``): with the tile cut
+into b x b blocks, TRSM leaves out the products with the zero blocks
+above W's diagonal and SYRK the blocks above the tile's own, which
+nobody reads — POTRF factors the lower triangle alone, as DPLASMA's
+dpotrf_L does.  Both then execute (b+1)/(2b) of the full product's
+flop; ``selected`` says which b every traced tile order got.
 
 The priority schedule drives the critical path (POTRF > TRSM > SYRK >
 GEMM at equal k) exactly like DPLASMA's priority hints, and same-class
@@ -51,6 +58,38 @@ _kernels = {}
 #: recursive-inversion leaf: below this order the Newton iteration runs
 #: directly (log2(leaf) matmuls of leaf x leaf — MXU noise)
 _INV_LEAF = 512
+
+#: the number of blocks b an edge of the tile was cut into for every
+#: traced (class, tile order), 1 = the full product: what says the
+#: block-triangular form engaged (apps/pallas_kernels.py keeps such a
+#: map on its factories)
+selected = {}
+
+#: blocks an edge, and the smallest block edge at which the blocked form
+#: of each class ran faster a task than the full product on the v5e
+#: (PERF.md §6, PR 28: SYRK at mb = 2048 and 6144 alike; TRSM at 6144,
+#: while at 2048 its 8-wide wave ran no faster at any b)
+_TRI_BLOCKS = 8
+_TRI_EDGE_MIN = {"SYRK": 256, "TRSM": 768}
+
+
+def tri_blocks(cls: str, mb: int) -> int:
+    """Blocks an edge of an mb x mb tile is cut into by the SYRK or TRSM
+    kernel: ``_TRI_BLOCKS`` where they divide mb into edges of the
+    class's ``_TRI_EDGE_MIN`` or more, else 1 (the full product)."""
+    edge, rest = divmod(mb, _TRI_BLOCKS)
+    return _TRI_BLOCKS if not rest and edge >= _TRI_EDGE_MIN[cls] else 1
+
+
+def potrf_executed_flops(cls: str, mb: int) -> float:
+    """Flop one task of class ``cls`` executes on an mb x mb tile (the
+    device load-balancing weights and bench.py's accounting): SYRK and
+    TRSM run (b+1)/(2b) of the full 2 mb^3 product."""
+    if cls in _TRI_EDGE_MIN:
+        b = tri_blocks(cls, mb)
+        return mb ** 3 * (b + 1.0) / b
+    return {"POTRF": mb ** 3, "POTRFL": mb ** 3 / 3.0,
+            "GEMM": 2.0 * mb ** 3}[cls]
 
 
 def tri_inv(L, precision=None):
@@ -92,7 +131,10 @@ def _k_potrf(precision):
             import jax.numpy as jnp
             # factor in f32 even under bf16 tile storage (the mp mode):
             # the inverse W always stays f32 — it multiplies every panel
-            L = jnp.linalg.cholesky(T.astype(jnp.float32))
+            # the lower triangle alone (dpotrf_L): SYRK leaves the blocks
+            # above the diagonal behind
+            L = jnp.linalg.cholesky(T.astype(jnp.float32),
+                                    symmetrize_input=False)
             return {"T": L.astype(T.dtype), "W": tri_inv(L, precision)}
         _kernels[("potrf", precision)] = fn
     return fn
@@ -105,7 +147,9 @@ def _k_potrf_last(precision):
     if fn is None:
         def fn(T):
             import jax.numpy as jnp
-            return jnp.linalg.cholesky(T.astype(jnp.float32)).astype(T.dtype)
+            return jnp.linalg.cholesky(
+                T.astype(jnp.float32),
+                symmetrize_input=False).astype(T.dtype)
         _kernels[("potrf_last", precision)] = fn
     return fn
 
@@ -122,10 +166,25 @@ def _k_trsm(precision):
     if fn is None:
         def fn(W, C):
             import jax.numpy as jnp
-            # C <- C @ L^-T  ==  C @ W^T  (W = L^-1 from POTRF)
-            acc = jnp.matmul(C, W.T, precision=precision,
-                             preferred_element_type=jnp.float32)
-            return acc.astype(C.dtype)
+            from jax import lax
+            # C <- C @ L^-T  ==  C @ W^T  (W = L^-1 from POTRF).  W is
+            # lower triangular, so column block j needs the first j+1
+            # block columns of C alone: the products left out are with
+            # tri_inv's exact zeros.  Last block first, each read from
+            # the tile as written so far: block j reads nothing a later
+            # block has written, so the donated tile is updated in place.
+            mb = C.shape[1]
+            b = selected[("TRSM", mb)] = tri_blocks("TRSM", mb)
+            s = mb // b
+            out = C
+            for j in reversed(range(b)):
+                lo, hi = j * s, (j + 1) * s
+                acc = jnp.matmul(out[:, :hi], W[lo:hi, :hi].T,
+                                 precision=precision,
+                                 preferred_element_type=jnp.float32)
+                out = lax.dynamic_update_slice(out, acc.astype(C.dtype),
+                                               (0, lo))
+            return out
         _kernels[("trsm", precision)] = fn
     return fn
 
@@ -135,9 +194,22 @@ def _k_syrk(precision):
     if fn is None:
         def fn(T, R):
             import jax.numpy as jnp
-            acc = jnp.matmul(R, R.T, precision=precision,
-                             preferred_element_type=jnp.float32)
-            return (T.astype(jnp.float32) - acc).astype(T.dtype)
+            from jax import lax
+            # block row i of the update, up to the block diagonal; the
+            # blocks above it are not computed, keep what they held and
+            # are never read (POTRF reads the lower triangle alone)
+            mb = T.shape[0]
+            b = selected[("SYRK", mb)] = tri_blocks("SYRK", mb)
+            s = mb // b
+            out = T
+            for i in range(b):
+                lo, hi = i * s, (i + 1) * s
+                acc = jnp.matmul(R[lo:hi], R[:hi].T, precision=precision,
+                                 preferred_element_type=jnp.float32)
+                blk = out[lo:hi, :hi].astype(jnp.float32) - acc
+                out = lax.dynamic_update_slice(out, blk.astype(T.dtype),
+                                               (lo, 0))
+            return out
         _kernels[("syrk", precision)] = fn
     return fn
 
@@ -285,14 +357,8 @@ def potrf_taskpool(A: TiledMatrix, device: str = "tpu",
 
     tp = p.build()
     for name, tc in tp.task_classes.items():
-        # executed-flop weights for device load balancing (TRSM runs as a
-        # full matmul against W, so it carries 2mb^3, not the mb^3 of a
-        # true triangular solve)
-        tc.properties["flops"] = {"POTRF": mb ** 3,
-                                  "POTRFL": mb ** 3 / 3.0,
-                                  "TRSM": 2.0 * mb ** 3,
-                                  "SYRK": 2.0 * mb ** 3,
-                                  "GEMM": 2.0 * mb ** 3}[name]
+        # executed-flop weights for device load balancing
+        tc.properties["flops"] = potrf_executed_flops(name, mb)
     # cross-panel fused dispatch (devices/xla.py chain fusion): the
     # POTRF(k) -> TRSM(*,k) panel is the dispatch-latency-bound spine of
     # the DAG (each TRSM's only missing input is W) — the device layer

@@ -8,13 +8,22 @@ time; nothing runs, so they say nothing about results or speed.
 Kept to about a minute on one worker.  Three panel kernels are too slow
 to compile at the full mb=6144 in a test and are compiled here at the
 largest mb that fits; the builder compiled them by hand at full width
-before the first chip run of PR 21 (seconds on this sandbox's CPU,
-compiles only):
+before the first chip run of PR 21, and the two Cholesky-class ones
+again for PR 28 (no symmetrization, TRSM in eight blocks an edge);
+seconds on this sandbox's CPU, compiles only:
 
     POTRF diagonal (cholesky + tri_inv), 6144 bf16      31 s   (here 2048)
     GEQRT ib=512, 6144 bf16                              96 s   (here 1024)
     TSQRT ib=512, 6144 bf16                             159 s   (here 1024)
-    chained POTRF + 8-wide TRSM wave, 6144 bf16          70 s   (not here)
+    chained POTRF + 8-wide TRSM wave, 6144 bf16          29 s   (not here)
+
+PR 28's compiles of the last read 29 / 39 / 60 s and the parent's
+beside them 35 / 39 s (PR 21: 70 s; the diagonal alone 31-63 and
+37-48 s): the sandbox's seconds wander by a factor of two with its
+load, so they say which kernels are slow to compile, not what a change
+costs.  The compiler's own count of that program is 10.2 mb^3 flop and
+156 MB of temporaries, the parent's 17.2 and 456 MB, the nine donated
+tiles aliased on both.
 
 Rules this file keeps (a worker that breaks them takes the whole suite
 down under pytest-xdist): the topology is described inside a
@@ -88,12 +97,25 @@ def test_potrf_update_kernels_6144_bf16(spec, kernel):
     from parsec_tpu.apps import potrf
     t = (MB, MB)
     bf = spec(t, jnp.bfloat16)
-    fn, args = {
-        "trsm": (potrf._k_trsm(None), (spec(t, jnp.float32), bf)),
-        "syrk": (potrf._k_syrk(None), (bf, bf)),
-        "gemm": (potrf._k_gemm(None), (bf, bf, bf)),
+    fn, args, written = {
+        "trsm": (potrf._k_trsm(None), (spec(t, jnp.float32), bf), 1),
+        "syrk": (potrf._k_syrk(None), (bf, bf), 0),
+        "gemm": (potrf._k_gemm(None), (bf, bf, bf), 0),
     }[kernel]
-    _compile(fn, *args)
+    c = _compile(fn, *args, donate_argnums=(written,))
+    # the compiler's own count: SYRK and TRSM leave out the blocks the
+    # triangle makes zero or unread, GEMM has none to leave out
+    flops = c.cost_analysis()["flops"] / (2.0 * MB ** 3)
+    if kernel == "gemm":
+        assert flops == pytest.approx(1.0, rel=0.01)
+    else:
+        cls = kernel.upper()
+        assert potrf.selected[(cls, MB)] == potrf.tri_blocks(cls, MB) > 1
+        assert flops <= 0.70
+    # the written tile is updated in place, with less than a tile beside
+    ma = c.memory_analysis()
+    assert ma.alias_size_in_bytes == MB * MB * 2
+    assert ma.temp_size_in_bytes < MB * MB * 2
 
 
 def test_potrf_fused_gemm_wave_width8(spec):
