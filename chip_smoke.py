@@ -3,13 +3,16 @@
 
 Drives ``Context`` -> PTG taskpool -> dep engine -> ``XlaDevice`` once
 per app, at the sizes the repo calls its headline, in ONE process on ONE
-TPU chip, and checks every result by the repo's own means:
+TPU chip, and checks every result.  The GEMM and Cholesky phases are the
+benchmark's own jobs (``benchmark/apps/``: operands, MCA settings and
+limits from ``benchmark/configs/``, the comparison ``Job.check()``), at
+the sizes of its cells 2 and 1; nothing under ``benchmark/`` imports
+this file:
 
-    gemm    gemm_taskpool, mb=12288, 3x3 tiles, kt=4, bf16 A/B, f32 C,
-            sampled C tiles against a plain jnp product of the same tiles
-    potrf   potrf_taskpool, mb=6144, nt=16, bf16 storage (n = 98 304,
-            ~10 GB resident): one warm pass, two runs,
-            apps/potrf_check.backward_error <= 1e-2
+    gemm    dplasma_gemm_bf16 at mb=12288, 3x3 tiles, kt=4: every C tile
+            against the plain product, c_rel_err <= 1e-4
+    potrf   dplasma_potrf_bf16 at mb=6144, nt=16 (n = 98 304, ~10 GB
+            resident): one warm pass, two runs, offdiag_resid <= 0.02
     geqrf   qr_taskpool as it runs by default (ib=512 panel engine,
             cross-panel chain fusion), mb=6144, bf16 storage, nt cut
             from 8 to 2 (see GEQRF_NT),
@@ -49,20 +52,21 @@ import time
 
 import numpy as np
 
-import bench
-from parsec_tpu.apps.potrf_check import _tile as _newest
+from benchmark import tiles
+from benchmark.apps import gemm as gemm_app, potrf as potrf_app
 from parsec_tpu.utils.mca import params
 
-#: accuracy bounds (BENCH.md: the bf16-storage class measured in r5 was
-#: 3.0e-3 for potrf and 1.0e-2 for geqrf)
-GEMM_TOL = 1e-4
-POTRF_TOL = 1e-2
-GEQRF_TOL = 2e-2
+ROOT = os.path.dirname(os.path.abspath(__file__))
 
-#: run settings bench.py's potrf/geqrf modes use (bench._potrf_headline,
-#: bench.main): the defaults overflow a 16 GB chip at these sizes
-POTRF_MCA = {"device_fuse": 8, "device_runahead": 48,
-             "device_inflight_depth": 32}
+#: geqrf has no job under benchmark/ yet: its bound is this file's (the
+#: bf16-storage class read 1.0e-2 on the chip the repo was written for)
+GEQRF_TOL = 2e-2
+#: largest relative difference allowed between a sampled potrf tile of
+#: the four-chip run and of the one-chip run: two bfloat16 spacings
+POTRF_AGREE_TOL = 1e-2
+
+#: the defaults overflow a 16 GB chip at nt=8 (HIGHEST-precision TSQRT
+#: programs carry large workspaces: depth 32 ran out of memory)
 GEQRF_MCA = {"device_fuse": 8, "device_runahead": 20,
              "device_inflight_depth": 12, "device_fuse_window_ms": 4.0}
 #: nt of the default-path geqrf phase, cut from the headline 8; mb is
@@ -107,21 +111,14 @@ def native_extensions() -> dict:
             "commext": native.load_commext() is not None}
 
 
-def _bf16():
-    import ml_dtypes
-    return ml_dtypes.bfloat16
-
-
-def _sync_tiles(*Ms) -> None:
-    """Block until every tile's newest payload has materialized (tile by
-    tile: the tiles of a distributed matrix sit on different devices, so
-    no single program may take them all)."""
-    import jax
-    for M in Ms:
-        for m, n in M.local_tiles():
-            p = _newest(M, m, n)
-            if not isinstance(p, np.ndarray):
-                jax.block_until_ready(p)
+def _config(name: str, storage=None) -> dict:
+    """The benchmark's configuration ``name`` as committed; ``storage``
+    replaces its storage dtype (the CPU rehearsal: XLA's CPU backend has
+    no bf16 x bf16 -> f32 dot)."""
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           name + ".json")) as f:
+        config = json.load(f)
+    return {**config, "storage": storage} if storage else config
 
 
 def _run_passes(ctx, passes: int, t0: float, stage, pool, *Ms):
@@ -138,20 +135,13 @@ def _run_passes(ctx, passes: int, t0: float, stage, pool, *Ms):
         t1 = time.perf_counter()
         ctx.add_taskpool(pool())
         ctx.wait()
-        _sync_tiles(*Ms)
+        tiles.fence(*Ms)
         if p == 0:
             wait_fuse_warm()
             setup_s = time.perf_counter() - t0
         else:
             run_s.append(time.perf_counter() - t1)
     return setup_s, run_s
-
-
-def _drop(ctx, *Ms) -> None:
-    """Free the phase's device memory: tiles and arena scratch go
-    without writeback (they are synthetic and checked already)."""
-    bench._discard_device_tiles(*Ms)
-    bench._discard_device_scratch(ctx)
 
 
 def _device_report(ctx, *Ms) -> list:
@@ -199,197 +189,142 @@ def _ici_stats(ctx) -> dict:
     return ctx.ici.stats.as_dict() if ctx.ici is not None else {}
 
 
-def _sample(rng, tiles, k):
-    idx = rng.choice(len(tiles), size=min(k, len(tiles)), replace=False)
-    return [tiles[i] for i in sorted(idx)]
-
-
 def _to_host(t) -> np.ndarray:
     import jax.numpy as jnp
     return np.asarray(jnp.asarray(t).astype(jnp.float32))
 
 
 # ---------------------------------------------------------------------------
-# gemm
+# gemm and potrf: the benchmark's jobs
 # ---------------------------------------------------------------------------
+
+def _run_job(phase: str, Job, config: dict, traffic: dict, seed: int,
+             passes: int, samples: int, every_device: bool,
+             prepare=None) -> dict:
+    """One phase on a job of the benchmark (``benchmark/apps/``): the
+    configuration's MCA settings, ``passes`` runs (the first is the warm
+    one), then ``Job.check()`` on what the last left, held to the
+    configuration's limits as the benchmark's harness holds it.
+    ``prepare(job, ctx)`` may lay the matrices out and return another
+    taskpool builder; ``samples`` lower tiles of the output, drawn from
+    the seed, come back on the host under ``_samples``."""
+    from parsec_tpu.core.context import Context
+
+    t0 = time.perf_counter()
+    with _mca(**config["mca"]), Context(nb_cores=4) as ctx:
+        job = Job(config, traffic, ctx, seed)
+        pool = prepare(job, ctx) if prepare else job.pool
+        result = job.outputs[0]
+        lower = [t for t in result.local_tiles() if t[0] >= t[1]]
+        picked = np.random.default_rng(seed).choice(
+            len(lower), size=min(samples, len(lower)), replace=False)
+        try:
+            job.setup()
+            setup_s, run_s = _run_passes(ctx, passes, t0, job.stage, pool,
+                                         *job.outputs)
+            t1 = time.perf_counter()
+            checked = job.check()
+            out = {"phase": phase, **traffic, "storage": config["storage"],
+                   "distributed": every_device, "mca": config["mca"],
+                   "setup_s": round(setup_s, 3),
+                   "run_s": [round(t, 3) for t in run_s],
+                   "check_s": round(time.perf_counter() - t1, 3),
+                   "compared": {k: {"value": v, "limit": job.limits[k]}
+                                for k, v in checked["numbers"].items()},
+                   "notes": checked["notes"],
+                   "devices": _device_report(ctx, *job.outputs),
+                   "ici": _ici_stats(ctx)}
+            kept = {lower[i]: _to_host(tiles.newest(result, *lower[i]))
+                    for i in sorted(picked)}
+        finally:
+            job.drop()
+    del job, result
+    gc.collect()
+    over = {k: c for k, c in out["compared"].items()
+            if not c["value"] <= c["limit"]}
+    if over or not out["compared"]:
+        raise SmokeFailure(f"{phase}: over the configuration's limits: "
+                           f"{over}: {out}")
+    _require_healthy(phase, out["devices"], every_device)
+    if samples:
+        out["_samples"] = kept
+    return out
+
 
 def run_gemm(mb: int, mt: int, nt: int, kt: int, seed: int = 0,
-             ab_dtype=None, passes: int = 2, distribute: bool = False,
-             panel_bcast=None, samples: int = 2,
-             keep_samples: bool = False) -> dict:
-    """C += A @ B through gemm_taskpool, tiles born on the device;
-    ``samples`` C tiles are checked against a plain jnp product of the
-    same A/B/C tiles.  ``distribute`` spreads all three matrices over
-    every attached device."""
-    import jax
-    import jax.numpy as jnp
-    from parsec_tpu.apps.gemm import gemm_taskpool
-    from parsec_tpu.core.context import Context
-    from parsec_tpu.data.matrix import TwoDimBlockCyclic
-
-    ab_dtype = ab_dtype or _bf16()
-    A = TwoDimBlockCyclic(mb=mb, nb=mb, lm=mt * mb, ln=kt * mb, name="A",
-                          dtype=ab_dtype)
-    B = TwoDimBlockCyclic(mb=mb, nb=mb, lm=kt * mb, ln=nt * mb, name="B",
-                          dtype=ab_dtype)
-    C = TwoDimBlockCyclic(mb=mb, nb=mb, lm=mt * mb, ln=nt * mb, name="C")
-    s0 = 100003 * seed
-    seeds = {"A": s0, "B": s0 + 10007, "C": s0 + 20011}
-    rng = np.random.default_rng(seed)
-    picked = _sample(rng, list(C.local_tiles()), samples)
-
-    t0 = time.perf_counter()
-    with Context(nb_cores=4) as ctx:
+             storage=None, passes: int = 2, distribute: bool = False,
+             panel_bcast=None, samples: int = 0) -> dict:
+    """C += A B: the ``dplasma_gemm_bf16`` job at this size, every C
+    tile against the plain product.  ``distribute`` spreads all three
+    matrices over every attached device and ``panel_bcast`` broadcasts
+    the panels (no configuration of the benchmark does either yet)."""
+    def prepare(job, ctx):
+        Ms = (job.A, job.B, job.C)
         if distribute:
-            for M in (A, B, C):
+            for M in Ms:
                 M.distribute_devices(ctx)
-        for M in (A, B):
-            bench.prestage(M, ctx, rand_scale=1.0, seed0=seeds[M.name])
-        # C accumulates: give every pass the same C0, so the last pass
-        # leaves C0 + A @ B whatever the number of passes
-        setup_s, run_s = _run_passes(
-            ctx, passes, t0,
-            lambda: bench.prestage(C, ctx, rand_scale=1.0,
-                                   seed0=seeds["C"]),
-            lambda: gemm_taskpool(A, B, C, panel_bcast=panel_bcast), C)
+        if not panel_bcast:
+            return job.pool
+        from parsec_tpu.apps.gemm import gemm_taskpool
+        return lambda: gemm_taskpool(*Ms, panel_bcast=True)
 
-        gen_c = bench._tile_generator(C, 1.0)
-        lin = {t: i for i, t in enumerate(C.local_tiles())}
-
-        @jax.jit
-        def ref_err(got, c0, a_row, b_col):
-            ref = c0
-            for a, b in zip(a_row, b_col):
-                ref = ref + jnp.matmul(a, b, preferred_element_type=c0.dtype)
-            return jnp.max(jnp.abs(got - ref)), jnp.max(jnp.abs(ref))
-
-        err = 0.0
-        kept = {}
-        for (m, n) in picked:
-            got = _newest(C, m, n)
-            here = next(iter(got.devices()))
-
-            def put(t):
-                return jax.device_put(jnp.asarray(t), here)
-            num, den = ref_err(
-                got, put(gen_c(float(seeds["C"] + lin[(m, n)]), 0.0)),
-                [put(_newest(A, m, k)) for k in range(kt)],
-                [put(_newest(B, k, n)) for k in range(kt)])
-            err = max(err, float(num) / max(float(den), 1e-30))
-            if keep_samples:
-                kept[(m, n)] = _to_host(got)
-        devices = _device_report(ctx, A, B, C)
-        ici = _ici_stats(ctx)
-        _drop(ctx, A, B, C)
-    del A, B, C
-    gc.collect()
-    out = {"phase": "gemm", "mb": mb, "tiles": [mt, nt, kt],
-           "ab_dtype": np.dtype(ab_dtype).name, "c_dtype": "float32",
-           "distributed": distribute, "panel_bcast": bool(panel_bcast),
-           "setup_s": round(setup_s, 3),
-           "run_s": [round(t, 3) for t in run_s],
-           "rel_err_vs_jnp": err, "checked_tiles": [list(t) for t in picked],
-           "devices": devices, "ici": ici}
-    if err > GEMM_TOL or not np.isfinite(err):
-        raise SmokeFailure(f"gemm: sampled C tiles differ from the jnp "
-                           f"product by {err:.3e} (> {GEMM_TOL}): {out}")
-    _require_healthy("gemm", devices, every_device=distribute)
-    if keep_samples:
-        out["_samples"] = kept
-    return out
+    return _run_job("gemm", gemm_app.Job,
+                    _config("dplasma_gemm_bf16", storage),
+                    {"m": mt * mb, "n": nt * mb, "k": kt * mb, "mb": mb},
+                    seed, passes, samples, distribute, prepare)
 
 
-# ---------------------------------------------------------------------------
-# potrf
-# ---------------------------------------------------------------------------
-
-def run_potrf(mb: int, nt: int, seed: int = 0, mp: bool = True,
+def run_potrf(mb: int, nt: int, seed: int = 0, storage=None,
               passes: int = 3, distribute: bool = False,
-              samples: int = 3, keep_samples: bool = False) -> dict:
-    """Tiled Cholesky of an n = nt*mb SPD matrix born on the device (the
-    bench's matrix: iota tiles, dominant diagonal): ``passes`` full
-    factorizations (the first is the warm one), then the exact backward
-    error of the last."""
-    from parsec_tpu.apps.potrf import potrf_taskpool
-    from parsec_tpu.apps.potrf_check import backward_error
-    from parsec_tpu.core.context import Context
-    from parsec_tpu.data.matrix import TwoDimBlockCyclic
-
-    dtype = _bf16() if mp else np.float32
-    n = nt * mb
-    A = TwoDimBlockCyclic(mb=mb, nb=mb, lm=n, ln=n, name="A", dtype=dtype)
-    s0 = 1009 * seed
-    lower = [t for t in A.local_tiles() if t[0] >= t[1]]
-    picked = _sample(np.random.default_rng(seed), lower, samples)
-
-    t0 = time.perf_counter()
-    with _mca(**POTRF_MCA), Context(nb_cores=4) as ctx:
-        if distribute:
-            A.distribute_devices(ctx)
-
-        def stage():
-            bench._discard_device_scratch(ctx)   # last pass's W inverses
-            # dpotrf_L touches only the lower triangle
-            bench.prestage(A, ctx, spd_diag=True, seed0=s0,
-                           keep=lambda m, k: m >= k)
-        setup_s, run_s = _run_passes(
-            ctx, passes, t0, stage,
-            lambda: potrf_taskpool(A, device="tpu"), A)
-
-        gen = bench._tile_generator(A)
-        lin = {t: i for i, t in enumerate(A.local_tiles())}
-
-        def orig(m, k):
-            return gen(float(s0 + lin[(m, k)]),
-                       float(A.lm) if m == k else 0.0)
-
-        accs = ctx.device_registry.accelerators
-        t1 = time.perf_counter()
-        bwd = backward_error(A, orig,
-                             device=accs[0].jdev if len(accs) > 1 else None)
-        check_s = time.perf_counter() - t1
-        kept = {t: _to_host(_newest(A, *t)) for t in picked} \
-            if keep_samples else {}
-        devices = _device_report(ctx, A)
-        ici = _ici_stats(ctx)
-        _drop(ctx, A)
-    del A
-    gc.collect()
-    out = {"phase": "potrf", "mb": mb, "nt": nt, "n": n,
-           "storage": np.dtype(dtype).name, "distributed": distribute,
-           "mca": POTRF_MCA, "setup_s": round(setup_s, 3),
-           "run_s": [round(t, 3) for t in run_s],
-           "check_s": round(check_s, 3), "backward_error": bwd,
-           "devices": devices, "ici": ici}
-    if not bwd <= POTRF_TOL:
-        raise SmokeFailure(f"potrf: backward error {bwd:.3e} > "
-                           f"{POTRF_TOL}: {out}")
-    _require_healthy("potrf", devices, every_device=distribute)
-    if keep_samples:
-        out["_samples"] = kept
-    return out
+              samples: int = 0) -> dict:
+    """Tiled Cholesky of n = nt*mb: the ``dplasma_potrf_bf16`` job, its
+    four-chip configuration (2x2 block-cyclic) under ``distribute``."""
+    return _run_job("potrf", potrf_app.Job,
+                    _config("dplasma_potrf_bf16_4chip" if distribute
+                            else "dplasma_potrf_bf16", storage),
+                    {"n": nt * mb, "mb": mb}, seed, passes, samples,
+                    distribute)
 
 
 # ---------------------------------------------------------------------------
 # geqrf
 # ---------------------------------------------------------------------------
 
-def run_geqrf(mb: int, nt: int, seed: int = 0, mp: bool = True,
+def _qr_tile(M):
+    """Jitted ``gen(key) -> (mb, nb) tile`` of the QR operand, in M's
+    storage dtype: Gaussian entries of deviation 0.05 plus the identity
+    on EVERY tile — full rank, and stacked panels well-conditioned for
+    Cholesky-QR.  ``benchmark.tiles`` has no such operand (its entries
+    have variance 1 and only diagonal tiles take a bump)."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def gen(key):
+        out = 0.05 * jax.random.normal(jax.random.PRNGKey(key),
+                                       (M.mb, M.nb), jnp.float32)
+        return (out + jnp.eye(M.mb, M.nb, dtype=jnp.float32)).astype(M.dtype)
+    return gen
+
+
+def run_geqrf(mb: int, nt: int, seed: int = 0, storage: str = "bfloat16",
               ib: int = 512, passes: int = 2, mca=None) -> dict:
     """Tiled QR with the inner-blocked (ib) panel engine on a Gaussian +
     identity matrix born on the device; the factor is held to
     R^T R = A^T A on a random probe (apps/qr_check).  ``mca`` goes on
     top of GEQRF_MCA; the phase is named after the panel path it took."""
+    import jax
     import jax.numpy as jnp
     from parsec_tpu.apps.qr import effective_ib, qr_taskpool
     from parsec_tpu.apps.qr_check import factorization_residual
     from parsec_tpu.core.context import Context
     from parsec_tpu.data.matrix import TwoDimBlockCyclic
 
-    dtype = _bf16() if mp else np.float32
     n = nt * mb
-    A = TwoDimBlockCyclic(mb=mb, nb=mb, lm=n, ln=n, name="A", dtype=dtype)
-    s0 = 2003 * seed
+    A = TwoDimBlockCyclic(mb=mb, nb=mb, lm=n, ln=n, name="A",
+                          dtype=tiles.storage_dtype(storage))
+    gen = _qr_tile(A)
+    key = {t: 2003 * seed + i for i, t in enumerate(A.local_tiles())}
     mca = {**GEQRF_MCA, **(mca or {}), "qr_ib": ib}
 
     t0 = time.perf_counter()
@@ -397,28 +332,28 @@ def run_geqrf(mb: int, nt: int, seed: int = 0, mp: bool = True,
         ib_used = effective_ib(mb)
         chain = bool(int(params.get("device_fuse_panel", 1)))
 
+        dev = ctx.device_registry.accelerators[0]
+
         def stage():
-            bench._discard_device_scratch(ctx)   # last pass's Q panels
-            # Gaussian tiles + identity bump: full rank, and stacked
-            # panels well-conditioned for Cholesky-QR (bench.py geqrf)
-            bench.prestage(A, ctx, bump_all=1.0, rand_scale=0.05, seed0=s0)
+            tiles.discard_scratch(ctx)           # last pass's Q panels
+            for t in A.local_tiles():
+                A.data_of(*t).overwrite_on(
+                    dev.space, jax.device_put(gen(key[t]), dev.jdev))
         setup_s, run_s = _run_passes(
             ctx, passes, t0, stage, lambda: qr_taskpool(A, device="tpu"), A)
 
-        gen = bench._tile_generator(A, 0.05)
-        lin = {t: i for i, t in enumerate(A.local_tiles())}
         t1 = time.perf_counter()
         res = factorization_residual(
-            A, lambda m, k: gen(float(s0 + lin[(m, k)]),
-                                1.0).astype(jnp.float32))
+            A, lambda m, k: gen(key[(m, k)]).astype(jnp.float32))
         check_s = time.perf_counter() - t1
         devices = _device_report(ctx, A)
-        _drop(ctx, A)
+        tiles.discard_tiles(A)
+        tiles.discard_scratch(ctx)
     del A
     gc.collect()
     out = {"phase": "geqrf" if chain else "geqrf_per_kernel",
            "mb": mb, "nt": nt, "n": n, "ib": ib_used,
-           "storage": np.dtype(dtype).name, "mca": mca,
+           "storage": storage, "mca": mca,
            "setup_s": round(setup_s, 3),
            "run_s": [round(t, 3) for t in run_s],
            "check_s": round(check_s, 3), "factorization_residual": res,
@@ -455,9 +390,9 @@ def run_ici_ring(mb: int, seed: int = 0) -> dict:
             raise SmokeFailure("ici: one device attached, no ICI engine")
         devs = ici.xla_devices
         nd = len(devs)
-        T = TwoDimBlockCyclic(mb=mb, nb=mb, lm=mb, ln=mb, dtype=_bf16())
-        gen = bench._tile_generator(T, 1.0)
-        sent = [jax.device_put(gen(float(31 * seed + i), 0.0), d.jdev)
+        T = TwoDimBlockCyclic(mb=mb, nb=mb, lm=mb, ln=mb, name="ring",
+                              dtype=tiles.storage_dtype("bfloat16"))
+        sent = [tiles.make_tile(T, seed, i, 0, device=d.jdev)
                 for i, d in enumerate(devs)]
         got = ici.permute([(devs[i].space, devs[(i + 1) % nd].space, sent[i])
                            for i in range(nd)])
@@ -513,9 +448,9 @@ def run_multichip(potrf_size: dict, gemm_size: dict, seed: int = 0,
         with _mca(**scope):
             dist = label == "many"
             p = run_potrf(**potrf_size, seed=seed, passes=1,
-                          distribute=dist, keep_samples=True)
+                          distribute=dist, samples=3)
             g = run_gemm(**gemm_size, seed=seed, passes=1, distribute=dist,
-                         panel_bcast=True, keep_samples=True)
+                         panel_bcast=True, samples=2)
         if dist:
             moved = p["ici"]
             if not moved or moved["bcasts"] + moved["puts"] \
@@ -533,13 +468,19 @@ def run_multichip(potrf_size: dict, gemm_size: dict, seed: int = 0,
             emit({**{k: v for k, v in r.items() if k != "_samples"},
                   "scope": label})
     (p4, g4), (p1, g1) = runs["many"], runs["one"]
+
+    def value(r, number):
+        return r["compared"][number]["value"]
     return {"phase": "multichip",
-            "potrf_backward_error": {"many": p4["backward_error"],
-                                     "one": p1["backward_error"]},
-            "potrf_tiles_rel_diff": _agree("potrf", p4, p1, POTRF_TOL),
-            "gemm_rel_err_vs_jnp": {"many": g4["rel_err_vs_jnp"],
-                                    "one": g1["rel_err_vs_jnp"]},
-            "gemm_tiles_rel_diff": _agree("gemm", g4, g1, GEMM_TOL),
+            "potrf_offdiag_resid": {"many": value(p4, "offdiag_resid"),
+                                    "one": value(p1, "offdiag_resid")},
+            "potrf_tiles_rel_diff": _agree("potrf", p4, p1,
+                                           POTRF_AGREE_TOL),
+            "gemm_c_rel_err": {"many": value(g4, "c_rel_err"),
+                               "one": value(g1, "c_rel_err")},
+            # the same quantity as c_rel_err, so the same limit
+            "gemm_tiles_rel_diff": _agree(
+                "gemm", g4, g1, g1["compared"]["c_rel_err"]["limit"]),
             "ici_ring": ring["ici"]}
 
 

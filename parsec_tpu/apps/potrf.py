@@ -83,8 +83,8 @@ def tri_blocks(cls: str, mb: int) -> int:
 
 def potrf_executed_flops(cls: str, mb: int) -> float:
     """Flop one task of class ``cls`` executes on an mb x mb tile (the
-    device load-balancing weights and bench.py's accounting): SYRK and
-    TRSM run (b+1)/(2b) of the full 2 mb^3 product."""
+    device load-balancing weights; benchmark/work.py counts the USEFUL
+    flop): SYRK and TRSM run (b+1)/(2b) of the full 2 mb^3 product."""
     if cls in _TRI_EDGE_MIN:
         b = tri_blocks(cls, mb)
         return mb ** 3 * (b + 1.0) / b
@@ -161,7 +161,7 @@ def _k_trsm(precision):
     # so the same code path serves full-f32 tiles and the bf16-storage
     # mixed-precision mode (HPL-AI-style: all tiles stored bf16, halving
     # HBM footprint+traffic, results rounded to bf16 between steps; the
-    # panel inverse W alone stays f32; bench.py PARSEC_BENCH_POTRF_MP).
+    # panel inverse W alone stays f32; benchmark/configs/ "storage").
     fn = _kernels.get(("trsm", precision))
     if fn is None:
         def fn(W, C):

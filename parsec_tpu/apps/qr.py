@@ -41,7 +41,7 @@ upper triangle; tiles below are zeroed.
 INNER BLOCKING (ib; the DPLASMA dgeqrf panel discipline, r6): the
 panel CONSTRUCTION is cond^2-sensitive and must run at HIGHEST matmul
 precision (true f32 — DEFAULT's bf16 passes destroy the factorization,
-measured residual 1.19; BENCH.md geqrf note), but HIGHEST is ~3x
+measured residual 1.19 on the r5 remote chip), but HIGHEST is ~3x
 DEFAULT on the MXU.  Factoring the panel in ib-wide column blocks
 confines the HIGHEST-precision math (per-block Gram, Cholesky,
 triangular inverses, WY assembly) to O(mb^2*ib) per panel instead of

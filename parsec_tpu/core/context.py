@@ -330,8 +330,8 @@ class Context:
         CURRENT core affinity.  The spin needs a spare core: on a
         1-core host a polling worker steals the GIL/CPU from the very
         comm loop whose delivery it is waiting for (measured: shm rtt
-        694 -> 1000 us/hop with the spin forced on 1 core — BENCH.md
-        r14); auto mode (1) arms it only with a spare core, 2 forces.
+        694 -> 1000 us/hop with the spin forced on 1 core, r14 CPU
+        container); auto mode (1) arms it only with a spare core, 2 forces.
 
         Called from ``__init__`` AND whenever a comm engine attaches
         (comm/remote_dep.py): a fabric-carved worker is re-pinned

@@ -820,7 +820,7 @@ class XlaDevice(Device):
         #: (kernel name, width) -> the compiler's words, for every fused
         #: width this device asked for whose background compile failed
         #: (its waves ran as singles; XlaKernel.fuse_ready records it).
-        #: Benches and chip_smoke.py fail on a non-empty map
+        #: benchmark/harness.py reports it; chip_smoke.py fails on it
         self.fuse_failures: Dict[Tuple[str, int], str] = {}
         #: segment ledger over the HBM budget (reference: the GPU slab
         #: zone_malloc, utils/zone_malloc.c — XLA owns physical HBM, so

@@ -23,8 +23,8 @@ What is simulated faithfully:
   bcast ships one payload per destination device.
 
 What is abstracted: link contention (alpha-beta per edge, no shared-link
-queueing) and memory capacity.  Durations and overheads are inputs —
-the bench calibrates them on the real chip (bench.py eff mode).
+queueing) and memory capacity.  Durations and overheads are inputs: the
+caller measures them (prof/liveattr.py takes them from the live run).
 """
 
 from __future__ import annotations

@@ -33,8 +33,8 @@ def test_potrf_matches_numpy(device, nt):
 
 @pytest.mark.parametrize("device", ["tpu", "cpu"])
 def test_potrf_bf16_panels_mixed_precision(device):
-    """bf16-panel mixed precision (HPL-AI-style; bench.py potrf mp mode):
-    the kernels are dtype-following, so storing off-diagonal tiles bf16
+    """bf16-panel mixed precision (HPL-AI-style; the storage of the
+    benchmark's potrf configurations): the kernels are dtype-following, so storing off-diagonal tiles bf16
     must still produce a valid factorization of a (slightly perturbed)
     matrix — loose tolerance reflects bf16 storage rounding."""
     from ml_dtypes import bfloat16

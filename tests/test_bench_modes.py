@@ -1,96 +1,61 @@
-"""bench.py's split between the accelerator modes and the host-only
-canaries: on the CPU the first fail (they never shrink to a toy size),
-the second run and name the device in their line; a device without a
-recorded peak is an error, not a default."""
+"""bench.py is the host canaries and nothing else: each runs on the CPU
+and names the device in its line; any other mode name — a typo, the
+accelerator modes this file used to carry, or none — is an error that
+points at the benchmark; and the pre-merge script asks for no mode the
+file does not have."""
 
 import json
+import os
+import re
 
 import pytest
 
 import bench
 
 
-@pytest.mark.parametrize("app", ["gemm", "potrf", "geqrf", "stencil", "eff"])
-def test_accelerator_modes_fail_without_a_tpu(app, monkeypatch, capsys):
-    monkeypatch.setenv("PARSEC_BENCH_APP", app)
-    monkeypatch.delenv("PARSEC_EFF_CHILD", raising=False)
+@pytest.mark.parametrize("app", ["gemn", "gemm", "potrf", "geqrf",
+                                 "stencil", "eff", None])
+def test_unknown_mode_is_an_error(app, monkeypatch, capsys):
+    if app is None:
+        monkeypatch.delenv("PARSEC_BENCH_APP", raising=False)
+    else:
+        monkeypatch.setenv("PARSEC_BENCH_APP", app)
     with pytest.raises(SystemExit) as exc:
         bench.main()
     assert exc.value.code not in (0, None)
-    assert "found no TPU" in str(exc.value.code)
-    assert f"'{app}'" in str(exc.value.code)
+    said = str(exc.value.code)
+    assert "benchmark.run --workload" in said
+    assert "benchmark/README.md" in said and "\n" not in said
     assert capsys.readouterr().out == ""          # no result line
 
 
-def test_unknown_mode_is_an_error(monkeypatch):
-    monkeypatch.setenv("PARSEC_BENCH_APP", "gemn")
-    with pytest.raises(SystemExit, match="unknown PARSEC_BENCH_APP"):
-        bench.main()
-
-
-def test_host_canary_runs_on_cpu_and_names_it(monkeypatch, capsys):
-    monkeypatch.setenv("PARSEC_BENCH_APP", "tracer")
+#: the other modes spawn ranks or run four off/on pairs:
+#: tools/premerge_bench.sh runs those
+@pytest.mark.parametrize("app, metric", [
+    ("tracer", "tracer_overhead"), ("tasks", "task_throughput"),
+    ("ntasks", "task_throughput_nontrivial"),
+    ("journal", "journal_overhead")])
+def test_host_canary_runs_on_cpu_and_names_it(app, metric, monkeypatch,
+                                              capsys):
+    monkeypatch.setenv("PARSEC_BENCH_APP", app)
     bench.main()
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert line["metric"] == "tracer_overhead"
+    out = capsys.readouterr().out.strip().splitlines()
+    assert len(out) == 1                          # exactly one line
+    line = json.loads(out[0])
+    assert line["metric"] == metric and line["value"] >= 0
     dev = line["device"]
     assert dev["platform"] == "cpu" and dev["kind"] and dev["count"] >= 1
 
 
-def test_peak_table_is_keyed_by_device_kind():
-    assert bench._peak_gflops({"kind": "TPU v5 lite"}) == 197_000.0
-    with pytest.raises(SystemExit, match="no peak rate on record"):
-        bench._peak_gflops({"kind": "cpu"})
-    with pytest.raises(SystemExit, match="TPU v9"):
-        bench._peak_gflops({"kind": "TPU v9"})
-
-
-def test_eff_measured_names_missing_points(monkeypatch):
-    """A virtual-mesh child that fails or prints nothing is reported by
-    name with its reason, never skipped in silence."""
-    import subprocess
-
-    def fake_run(cmd, env=None, **kw):
-        nd = int(env["PARSEC_EFF_CHILD"])
-        assert env["JAX_PLATFORMS"] == "cpu"      # never reaches for the chip
-        assert f"device_count={nd}" in env["XLA_FLAGS"]
-        if nd == 2:
-            return subprocess.CompletedProcess(cmd, 3, "", "boom")
-        if nd == 4:
-            return subprocess.CompletedProcess(cmd, 0, "no json here\n", "")
-        if nd == 8:
-            raise subprocess.TimeoutExpired(cmd, 900)
-        return subprocess.CompletedProcess(
-            cmd, 0, json.dumps({"t": 1.5, "ndev": nd}) + "\n", "")
-    monkeypatch.setattr(subprocess, "run", fake_run)
-    times, payloads, missing = bench._eff_measured()
-    assert times == {1: 1.5} and list(payloads) == [1]
-    assert set(missing) == {2, 4, 8}
-    assert "exit 3" in missing[2] and "boom" in missing[2]
-    assert "no result line" in missing[4]
-    assert "timed out" in missing[8]
-
-
-def test_require_clean_devices_refuses_failed_widths_and_faults():
-    class Stats:
-        faults = 0
-
-    class Dev:
-        name = "tpu:0"
-        fuse_failures = {}
-        stats = Stats()
-
-    class Reg:
-        accelerators = [Dev()]
-
-    class Ctx:
-        device_registry = Reg()
-
-    bench._require_clean_devices(Ctx())
-    Dev.fuse_failures = {("potrf.fn", 8): "XlaRuntimeError: RESOURCE_EXHAUSTED"}
-    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
-        bench._require_clean_devices(Ctx())
-    Dev.fuse_failures = {}
-    Stats.faults = 2
-    with pytest.raises(RuntimeError, match="2 device faults"):
-        bench._require_clean_devices(Ctx())
+def test_premerge_names_only_modes_that_exist():
+    script = os.path.join(os.path.dirname(os.path.abspath(bench.__file__)),
+                          "tools", "premerge_bench.sh")
+    with open(script) as f:
+        text = f.read()
+    asked = set(re.findall(r"PARSEC_BENCH_APP=(\$?\w+)", text))
+    # the first loop names its modes through $mode
+    loop = re.search(r"for mode in ([\w ]+); do", text)
+    assert "$mode" in asked and loop
+    asked = (asked - {"$mode"}) | set(loop.group(1).split())
+    assert asked and asked <= set(bench._AUX_MODES), \
+        asked - set(bench._AUX_MODES)

@@ -2,10 +2,11 @@
 
 The script itself passes only on a TPU; its phases are plain functions
 of their sizes, run here tiny on one and on four virtual CPU devices
-(f32 storage: XLA's CPU backend has no bf16 x bf16 -> f32 dot).  Beside
-them: where the compile cache is placed, a broken backend raising out
-of init_devices, a failed fused width keeping its reason, and tiles
-born on the device that owns them.
+(f32 storage: XLA's CPU backend has no bf16 x bf16 -> f32 dot), sound
+and with a fault planted under them.  Beside them: where the compile
+cache is placed, a broken backend raising out of init_devices, a failed
+fused width keeping its reason, and tiles born on the device that owns
+them.
 """
 
 import json
@@ -36,22 +37,104 @@ def _one(out):
     return d
 
 
+def _limits(config):
+    return cs._config(config)["limits"]
+
+
 def test_gemm_phase_one_device(devices):
     devices(1)
-    out = cs.run_gemm(mb=64, mt=3, nt=3, kt=4, seed=3, ab_dtype=np.float32)
+    out = cs.run_gemm(mb=64, mt=3, nt=3, kt=4, seed=3, storage="float32")
     d = _one(out)
     assert d["stats"]["executed_tasks"] == 2 * 3 * 3 * 4   # two passes
-    assert out["rel_err_vs_jnp"] <= cs.GEMM_TOL
-    assert len(out["checked_tiles"]) == 2 and len(out["run_s"]) == 1
+    # the benchmark's number, held to the benchmark's limit
+    assert set(out["compared"]) == {"c_rel_err"}
+    err = out["compared"]["c_rel_err"]
+    assert err["limit"] == _limits("dplasma_gemm_bf16")["c_rel_err"]
+    assert err["value"] <= 1e-6 and len(out["run_s"]) == 1
 
 
 def test_potrf_phase_one_device(devices):
     devices(1)
-    out = cs.run_potrf(mb=32, nt=6, seed=3, mp=False)
+    out = cs.run_potrf(mb=32, nt=6, seed=3, storage="float32")
     _one(out)
-    assert out["backward_error"] < 1e-5          # f32 storage
+    resid = out["compared"]["offdiag_resid"]
+    assert resid["limit"] == _limits("dplasma_potrf_bf16")["offdiag_resid"]
+    assert resid["value"] < 1e-5                 # f32 storage
     assert len(out["run_s"]) == 2                # one warm pass, two runs
-    assert out["mca"] == cs.POTRF_MCA
+    assert out["mca"] == cs._config("dplasma_potrf_bf16")["mca"]
+
+
+# -- faults planted under the phases: each must come out a SmokeFailure ------
+
+def _diagonal_alone(monkeypatch):
+    """Every kernel keeps the diagonal's square root and nothing else:
+    L = sqrt(diag A) I (sqrt(n) I on the operand the phase used to
+    stage, which its backward error let through: PERF.md section 7)."""
+    import jax.numpy as jnp
+    from parsec_tpu.apps import potrf
+
+    def root(T):
+        return jnp.diag(jnp.sqrt(jnp.diag(T.astype(jnp.float32))))
+    monkeypatch.setitem(
+        potrf._kernels, ("potrf", None),
+        lambda T, W: {"T": root(T).astype(T.dtype),
+                      "W": jnp.diag(1.0 / jnp.diag(root(T)))})
+    monkeypatch.setitem(potrf._kernels, ("potrf_last", None),
+                        lambda T: root(T).astype(T.dtype))
+    monkeypatch.setitem(potrf._kernels, ("trsm", None),
+                        lambda W, C: jnp.zeros_like(C))
+    monkeypatch.setitem(potrf._kernels, ("syrk", None), lambda T, R: T)
+    monkeypatch.setitem(potrf._kernels, ("gemm", None), lambda C, L, R: C)
+
+
+def _tile_zeroed(monkeypatch):
+    """One off-diagonal tile of the factor is lost after the last pass."""
+    import jax.numpy as jnp
+    from benchmark import tiles
+    real = cs._run_passes
+
+    def run_passes(ctx, passes, t0, stage, pool, A):
+        timed = real(ctx, passes, t0, stage, pool, A)
+        datum = A.data_of(3, 1)
+        space = next(sp for sp, c in datum.copies().items()
+                     if c.version == datum.newest_version()
+                     and c.payload is not None)
+        datum.overwrite_on(space, jnp.zeros_like(tiles.newest(A, 3, 1)))
+        return timed
+    monkeypatch.setattr(cs, "_run_passes", run_passes)
+
+
+def _trsm_altered(monkeypatch):
+    import jax.numpy as jnp
+    from parsec_tpu.apps import potrf
+    monkeypatch.setitem(
+        potrf._kernels, ("trsm", None),
+        lambda W, C: (1.1 * jnp.matmul(C, W.T)).astype(C.dtype))
+
+
+def _updates_left_out(monkeypatch):
+    from parsec_tpu.apps import potrf
+    monkeypatch.setitem(potrf._kernels, ("gemm", None), lambda C, L, R: C)
+
+
+@pytest.mark.parametrize("fault", [_diagonal_alone, _tile_zeroed,
+                                   _trsm_altered, _updates_left_out])
+def test_potrf_phase_sees_a_wrong_factor(devices, monkeypatch, fault):
+    devices(1)
+    fault(monkeypatch)
+    with pytest.raises(cs.SmokeFailure, match="offdiag_resid"):
+        cs.run_potrf(mb=32, nt=6, seed=3, storage="float32", passes=1)
+
+
+def test_gemm_phase_sees_half_a_product(devices, monkeypatch):
+    import jax.numpy as jnp
+    from parsec_tpu.apps import gemm
+    devices(1)
+    monkeypatch.setitem(gemm._kernels, (1.0, None),
+                        lambda Ai, Bi, Ci: Ci + 0.5 * jnp.matmul(Ai, Bi))
+    with pytest.raises(cs.SmokeFailure, match="c_rel_err"):
+        cs.run_gemm(mb=64, mt=3, nt=3, kt=4, seed=3, storage="float32",
+                    passes=1)
 
 
 @pytest.mark.parametrize("nt, mca, phase", [
@@ -61,7 +144,8 @@ def test_geqrf_phases_one_device(devices, nt, mca, phase):
     """Both geqrf phases as main() runs them: the default path (chain
     fusion on) at its cut nt, and the per-kernel panel path at nt=8."""
     devices(1)
-    out = cs.run_geqrf(mb=64, nt=nt, ib=16, seed=3, mp=False, mca=mca)
+    out = cs.run_geqrf(mb=64, nt=nt, ib=16, seed=3, storage="float32",
+                       mca=mca)
     d = _one(out)
     assert out["phase"] == phase and out["nt"] == nt
     assert out["ib"] == 16 and out["factorization_residual"] < 1e-5
@@ -73,18 +157,21 @@ def test_geqrf_phase_refuses_a_clamped_ib(devices):
     the phase must say so, not report an unblocked run as ib=24."""
     devices(1)
     with pytest.raises(cs.SmokeFailure, match="ib=24"):
-        cs.run_geqrf(mb=64, nt=2, ib=24, seed=3, mp=False, passes=1)
+        cs.run_geqrf(mb=64, nt=2, ib=24, seed=3, storage="float32",
+                     passes=1)
 
 
 def test_multichip_phase_four_devices(devices):
     devices(4)
     lines = []
-    out = cs.run_multichip({"mb": 32, "nt": 8, "mp": False},
+    out = cs.run_multichip({"mb": 32, "nt": 8, "storage": "float32"},
                            {"mb": 64, "mt": 3, "nt": 3, "kt": 4,
-                            "ab_dtype": np.float32},
+                            "storage": "float32"},
                            seed=3, emit=lines.append)
-    assert out["potrf_tiles_rel_diff"] <= cs.POTRF_TOL
-    assert out["gemm_tiles_rel_diff"] <= cs.GEMM_TOL
+    assert out["potrf_tiles_rel_diff"] <= cs.POTRF_AGREE_TOL
+    assert out["gemm_tiles_rel_diff"] <= 1e-4
+    # the four-chip run took the four-chip configuration
+    assert out["potrf_offdiag_resid"]["many"] < 1e-5
     assert out["ici_ring"]["permutes"] == 1 and out["ici_ring"]["bcasts"] == 1
     by = {(ln["phase"], ln.get("scope")): ln for ln in lines}
     assert set(by) == {("ici_ring", None), ("potrf", "many"),
@@ -246,14 +333,14 @@ def test_failed_fused_width_keeps_its_reason(monkeypatch, devices, capfd):
 def test_prestage_births_tiles_on_their_owning_device(devices):
     """Tiles of a matrix spread with distribute_devices are generated on
     the device that owns them, not all on the first."""
-    import bench
+    from benchmark import tiles
     from parsec_tpu.core.context import Context
     from parsec_tpu.data.matrix import TwoDimBlockCyclic
     devices(4)
-    A = TwoDimBlockCyclic(mb=8, nb=8, lm=32, ln=32)
+    A = TwoDimBlockCyclic(mb=8, nb=8, lm=32, ln=32, name="A")
     with Context(nb_cores=1) as ctx:
         A.distribute_devices(ctx)
-        bench.prestage(A, ctx, rand_scale=1.0, seed0=7)
+        tiles.stage(A, ctx, seed=7)
         by_space = {d.space: d.jdev for d in ctx.device_registry.accelerators}
         seen = set()
         for m, n in A.local_tiles():
@@ -263,10 +350,9 @@ def test_prestage_births_tiles_on_their_owning_device(devices):
             assert copy.payload.devices() == {by_space[datum.preferred_device]}
             seen.add(datum.preferred_device)
         assert len(seen) == 4
-        # seed0 offsets the generator: tile i of seed0=7 is tile i+7 of 0
-        gen = bench._tile_generator(A, 1.0)
+        # the seed and the tile alone define the value
         np.testing.assert_array_equal(
             np.asarray(A.data_of(0, 0).copies()[
                 A.data_of(0, 0).preferred_device].payload),
-            np.asarray(gen(7.0, 0.0)))
-        bench._discard_device_tiles(A)
+            np.asarray(tiles.make_tile(A, 7, 0, 0)))
+        tiles.discard_tiles(A)

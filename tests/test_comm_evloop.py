@@ -526,8 +526,8 @@ def test_undelivered_backlog_replayed_on_register():
 
 
 # -- multi-core-host validation of the evloop freed-core claim ---------------
-# (BENCH.md r6 residual: the r6 threads-vs-evloop parity was measured on
-# a 1-core host, where the freed progress-thread core cannot show up.)
+# (the r6 threads-vs-evloop parity was measured on a 1-core host, where
+# the freed progress-thread core cannot show up.)
 
 def _mc_pingpong_worker(ctx, rank, nranks, nbytes, hops):
     from parsec_tpu.apps.pingpong import run_pingpong
@@ -541,7 +541,7 @@ def test_evloop_threads_parity_multicore():
     """Paired A/B on a host with >= 2 cores: the evloop transport must
     hold parity with the threads transport (generous band — CI hosts
     are noisy), and the datapoint is archived to a JSON file + the
-    test log so the BENCH.md r6 freed-core claim accumulates real
+    test log so the r6 freed-core claim accumulates real
     multi-core evidence (bw/rtt bench lines now record the host core
     inventory for the same reason)."""
     import json
@@ -553,8 +553,8 @@ def test_evloop_threads_parity_multicore():
         cores = os.cpu_count() or 1
     if cores < 2:
         pytest.skip("multi-core validation needs >= 2 available cores "
-                    f"(have {cores}); the 1-core parity leg is BENCH.md "
-                    "r6")
+                    f"(have {cores}); the 1-core parity leg is README's "
+                    "transport table")
     results = {}
     for transport in ("threads", "evloop"):
         prior = os.environ.get("PARSEC_MCA_COMM_TRANSPORT")

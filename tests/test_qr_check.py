@@ -61,8 +61,8 @@ def test_ls_refine_recovers_from_bf16_storage():
 @pytest.mark.parametrize("storage,lo,hi", [("float32", 0.0, 1e-5),
                                            ("bfloat16", 1e-5, 2e-2)])
 def test_factorization_residual_tracks_the_factor(storage, lo, hi):
-    """R^T R = A^T A on a random probe (what chip_smoke.py and bench.py
-    hold the geqrf factor to): f32 storage sits at f32 class, bf16
+    """R^T R = A^T A on a random probe (what chip_smoke.py holds the
+    geqrf factor to): f32 storage sits at f32 class, bf16
     storage at bf16 class, and a damaged R tile is seen."""
     import ml_dtypes
     import jax.numpy as jnp

@@ -1511,8 +1511,7 @@ def test_three_rank_potrf_survives_midrun_kill():
     partition re-maps onto one adopter, both re-enumerate, cross-rank
     activations of the new generation flow, numerics validate, and the
     killed run stays within ~2x the no-fault makespan (the ISSUE
-    bound; the loose assert guards the invariant under host noise —
-    the measured ratio is recorded in BENCH.md)."""
+    bound; the loose assert guards the invariant under host noise)."""
     import chaos
     env = {"PARSEC_MCA_RECOVERY_ENABLE": "1",
            "PARSEC_CHAOS_WAIT_S": "60"}

@@ -1,102 +1,61 @@
 #!/bin/sh
-# Pre-merge bench smoke: run the CPU-only host-side probes and hold each
-# to its ABSOLUTE gate.  (Until PR 21 every probe line was also diffed
-# with tools/bench_guard.py against the newest BENCH_r*.json in the
-# repo; those artifacts held one GEMM metric that no probe shares, so
-# the diff compared nothing, and they were deleted with the rest of the
-# remote-chip records.  bench_guard.py itself stays: --prev FILE diffs
-# any two bench lines.)
+# Pre-merge gate: the static analyzer, a from-source native build, the
+# CPU canaries of bench.py, and the chaos smokes, each held to an
+# ABSOLUTE bound or to a paired A/B taken in the same session.  Nothing
+# here needs or measures a chip (that is BENCHMARK.json + benchmark/,
+# recorded in PERF.md); the probes time Python, the native scheduler and
+# the transports of whatever container runs them (~3 minutes), so no
+# reading is compared with a stored one: run-to-run host noise is ~10%,
+# a faster task core turns any constant cost into a larger ratio, and a
+# regression in behaviour shows as a gate below tripping, not as a rate.
+# Counts and canaries, not statements about speed.  Run one at a time:
+# concurrent runs corrupt each other's clocks on a small host.
 #
-# These probes time the Python+TCP runtime layers (no accelerator), so
-# they run anywhere in ~3 minutes and catch scheduler/transport
-# regressions — including the r6 protocol-mix guards (frames_sent,
-# syscalls_per_mb, and act_eager coverage under the bw/rtt "protocol"
-# key; wakeups/partial_writes are recorded but not gated — they track
-# OS scheduling timing, not the code under test) — before a change
-# merges.  Documented in BENCH.md ("Pre-merge guard").
+# What each gate bounds:
+#   parseclint     tools/parseclint clean against its baseline (lock
+#                  discipline, event-loop blocking calls, device_put
+#                  aliasing, MCA knob drift, exception hygiene)
+#   native build   every native source compiles from a clean tree (the
+#                  .so files build on demand: a source that stopped
+#                  compiling would silently degrade every fresh host to
+#                  the Python twins)
+#   tasks rtt bw   each probe runs to a result (bw/rtt lines carry the
+#                  protocol mix: frames, syscalls per MB, eager/rdv)
+#   tracer         tasks with the full tracing stack on
+#                  (PARSEC_BENCH_TRACE=1) costs <= $trace_bound us/task
+#                  more than tasks without
+#   native A/B     tasks and ntasks with the native scheduler against
+#                  PARSEC_MCA_SCHED_NATIVE=0: the native path is active
+#                  (sched_native=1), beats the fallback by
+#                  >= $native_margin / $ntasks_margin, and (ntasks) no
+#                  task bailed out of the C chain
+#   aggregate      N ranks over shm: no comm_buffered bailout (local
+#                  tasks stay on the C chain with a comm engine attached)
+#   shm rtt        the shm transport runs to a result
+#   telemetry      metrics + flight recorder + liveattr armed cost
+#                  <= $telemetry_bound us/task (min of four off/on pairs)
+#   journal        the control-plane journal armed costs
+#                  <= $journal_bound us/task: no per-task emit site
+#   fabric         the serving probe runs and its journal audits clean;
+#                  3 exclusive tenants + 1 shared on an 8-device CPU mesh
+#                  hold disjoint subsets concurrently (F1/F2/F3)
+#   chaos          seeded fault plans end correct or in a structured
+#                  error (no hang), journals audit clean; minimal replay
+#                  re-executes fewer tasks than full replay; 4 random
+#                  recover schedules; drain-before-death
 #
-# r7 added the TRACER-OVERHEAD gate: the tasks probe runs a second
-# time with the full tracing stack installed (PARSEC_BENCH_TRACE=1:
-# binary task profiler + causal tracer's queue-wait spans and dep
-# edges).  Since r14 the gate bounds the ABSOLUTE per-task tracing
-# cost ($trace_bound_us, default 8 us/task; measured ~2.3 on the
-# 1-core container, down from r7's ~5.4) instead of a ratio — see the
-# usage note.
-#
-# r8 adds the CHAOS smoke: a seeded subset of tools/chaos.py fault
-# plans (delayed v0 DTD payload, hard rank kill, transient task faults
-# with retry) asserting the no-hang invariant — every run completes
-# correctly or fails with a structured error within its deadline.  The
-# full catalog is `python tools/chaos.py --seeds 12`.
-#
-# r10 added the TELEMETRY-OVERHEAD gate: the always-on metrics
-# registry plus an ARMED flight recorder and the live attribution
-# engine with straggler detection (prof/liveattr.py).  Since r14 the
-# gate bounds the ABSOLUTE armed-plane cost ($telemetry_bound_us,
-# default 0.5 us/task — the same magnitude the old 5%-of-7us contract
-# allowed, but stable under base speedups).  The measurement is
-# bench.py's telemetry mode (four back-to-back off/on pairs in one
-# process, gating on the MINIMUM pair reading — host-load noise
-# contaminates single pairs in either direction but a real regression
-# shows in all of them; the JSON records both the ratio and
-# overhead_us).
-#
-# r16 adds the JOURNAL-OVERHEAD gate (control-plane black box,
-# prof/journal.py): the tasks probe armed vs off through bench.py's
-# journal mode, bounded ABSOLUTE at $journal_bound (default 0.3
-# us/task).  The journal has no per-task emit sites by construction —
-# this leg proves the C run_quantum fast path never crosses it.  The
-# chaos smoke below additionally runs --audit-journal (per-case
-# journal bundles through tools/journal_audit.py's invariant auditor).
-#
-# Usage:  sh tools/premerge_bench.sh [threshold] [trace_bound_us] \
-#             [telemetry_bound_us] [native_margin] [journal_bound_us]
-#         threshold:   unused since PR 21 (was bench_guard's relative
-#             regression bound); kept so the positions of the other
-#             arguments do not move
-#         trace_bound_us: max ABSOLUTE tracing cost in us/task
-#             (default 8.0).  r14 changed this gate from a ratio to an
-#             absolute bound: at the 482k+/s headline (~2 us/task) the
-#             old 50% ratio tripped on a tracing cost that had in fact
-#             DROPPED from r7's ~5.4 to ~2.3 us/task — a faster base
-#             must not turn a constant overhead into a regression.
-#         telemetry_bound_us: max ABSOLUTE armed-plane cost in us/task
-#             (default 0.5; same rationale — the old <=5% ratio bound
-#             was 5% of a 7 us base = 0.35 us, so the absolute bound
-#             preserves the old contract's magnitude while surviving
-#             base speedups; bench telemetry mode reports both)
-#         native_margin: min native/fallback tasks ratio (default 1.05)
-#         ntasks_margin (arg 6): min native/fallback ratio on the
-#             NON-trivial (data-carrying chain) probe (default 1.3) —
-#             the r17 extended-chain gate; the same leg fails on ANY
-#             native-path bailout (coverage, not just speed)
-# r11 adds the NATIVE-vs-PYTHON pairing: the tasks probe (which runs
-# with the native scheduler hot path by default) is re-run with
-# PARSEC_MCA_SCHED_NATIVE=0 — the native line must (a) actually have
-# the native path active in its JSON (sched_native=1 — a
-# silently-degraded build is a no-op native path) and (b) beat the
-# fallback by >= $native_margin (default 5%).  The shm transport gets
-# its own rtt probe (it must run to a result).
-#
-# r17 adds the FABRIC smoke (multi-tenant serving fabric,
-# service/fabric.py): the bench fabric probe (many small jobs/s with
-# p50/p99 admission->completion latency, self-auditing its journal)
-# must run clean, and a carved-subset smoke
-# runs 3 concurrent tenants on disjoint exclusive device subsets of an
-# 8-device CPU mesh plus one temporal-sharing job, then replays the
-# journal through tools/journal_audit.py's F1/F2/F3 fabric invariants
-# (disjoint subsets always, one placement outcome per admission,
-# preemptions resolve).
-#
-# r9 prepends the PARSECLINT gate: the project static analyzer
-# (tools/parseclint — lock discipline, event-loop blocking calls,
-# device_put aliasing, MCA knob drift, containment exception hygiene,
-# -O assert hazards) must be clean against its baseline BEFORE any
-# bench cycle is spent; a violation fails the premerge outright.
+# Usage:  sh tools/premerge_bench.sh [trace_bound_us] [telemetry_bound_us] \
+#             [native_margin] [journal_bound_us] [ntasks_margin]
+#         trace_bound_us      default 8.0 (the 1-core container reads ~2.3)
+#         telemetry_bound_us  default 0.5
+#         native_margin       min native/fallback tasks ratio, default 1.05
+#         journal_bound_us    default 0.3
+#         ntasks_margin       min native/fallback ratio on the
+#                             data-carrying chains, default 1.3
 set -e
 repo="$(cd "$(dirname "$0")/.." && pwd)"
-trace_bound="${2:-8.0}"
-telemetry_bound="${3:-0.5}"
+trace_bound="${1:-8.0}"
+telemetry_bound="${2:-0.5}"
 rc=0
 tasks_off=""
 echo "== premerge gate: parseclint (static analysis) =="
@@ -106,14 +65,10 @@ if ! (cd "$repo" && python -m tools.parseclint parsec_tpu); then
     exit 1
 fi
 echo "== premerge gate: native build-from-source =="
-# r14: every native source (core.cpp + the pinsext/schedext/commext
-# CPython extensions) must compile from a clean tree into a scratch
-# directory — the .so artifacts are built on demand (gitignored), so
-# a source that no longer compiles is a SILENT fleet-wide degradation:
-# every fresh container would fall back to the Python twins with one
-# rate-limited warning nobody reads.  (No mtime drift check: the
-# runtime's _stale() rebuild-on-load already guarantees the probes
-# below never measure an old build of an edited source.)
+# core.cpp and the pinsext/schedext/commext extensions, into a scratch
+# directory.  (No mtime drift check: the runtime's _stale()
+# rebuild-on-load already guarantees the probes below never measure an
+# old build of an edited source.)
 scratch="$(mktemp -d)"
 if ! make -s -C "$repo/parsec_tpu/native" OUT="$scratch" all; then
     echo "premerge: native build-from-source FAILED (compile error)"
@@ -164,7 +119,7 @@ else
     rc=1
 fi
 echo "== premerge probe: native-vs-python A/B (tasks) =="
-native_margin="${4:-1.05}"
+native_margin="${3:-1.05}"
 fb="${TMPDIR:-/tmp}/premerge_tasks_fb_$$.json"
 if [ -n "$tasks_off" ] && JAX_PLATFORMS=cpu PARSEC_BENCH_APP=tasks \
      PARSEC_MCA_SCHED_NATIVE=0 python "$repo/bench.py" > "$fb" 2>/dev/null; then
@@ -199,13 +154,10 @@ fi
 rm -f "$fb"
 rm -f "$tasks_off" "$on"
 echo "== premerge probe: native-vs-python A/B (ntasks, data-carrying chains) =="
-# r17: the EXTENDED C progress chain (per-class binding tables +
-# C-side local delivery walk) gets its own paired A/B on the
-# non-trivial probe — native must beat the fallback by
-# >= $ntasks_margin (default 1.3) AND report ZERO bailouts (any
-# non-empty reason means data tasks silently popped back to Python
-# and the number no longer measures the chain).
-ntasks_margin="${6:-1.3}"
+# the extended C progress chain (per-class binding tables + C-side
+# local delivery walk): any bailout reason means data tasks popped back
+# to Python and the number no longer measures the chain
+ntasks_margin="${5:-1.3}"
 nt_nat="${TMPDIR:-/tmp}/premerge_ntasks_$$.json"
 nt_fb="${TMPDIR:-/tmp}/premerge_ntasks_fb_$$.json"
 if JAX_PLATFORMS=cpu PARSEC_BENCH_APP=ntasks \
@@ -249,10 +201,8 @@ else
 fi
 rm -f "$nt_nat" "$nt_fb"
 echo "== premerge probe: aggregate multi-rank throughput (shm) =="
-# r17: N same-host ranks over shm, each with a live RemoteDepEngine —
-# comm-attached fast-complete must keep every (purely local) task on
-# the C chain: zero comm_buffered bailouts.  Self-scales N to the core count
-# (N=2 smoke on a 1-core host, with the skip reason in the JSON).
+# each rank has a live RemoteDepEngine; N self-scales to the core count
+# (N=2 smoke on a 1-core host, with the skip reason in the JSON)
 agg="${TMPDIR:-/tmp}/premerge_aggregate_$$.json"
 if JAX_PLATFORMS=cpu PARSEC_BENCH_APP=aggregate \
      python "$repo/bench.py" > "$agg" 2>/dev/null; then
@@ -309,8 +259,10 @@ def last_json(path):
 obj = last_json(sys.argv[1])
 cost_us = obj.get("overhead_us")
 bound = float(sys.argv[2])
-if cost_us is None:   # pre-r14 bench build: fall back to the ratio
-    cost_us = obj["value"] * 7.0   # vs the old 7 us/task base
+if cost_us is None:
+    print("premerge: telemetry probe JSON carries no overhead_us "
+          "(every pair skipped?)")
+    sys.exit(1)
 print(f"premerge: telemetry cost {cost_us:.3f} us/task "
       f"(bound {bound} us; ratio {obj['value']:+.1%}; off "
       f"{obj.get('tasks_off')} -> armed {obj.get('tasks_on')} tasks/s)")
@@ -325,12 +277,10 @@ else
 fi
 rm -f "$tel"
 echo "== premerge probe: journal overhead (control-plane black box armed) =="
-# r16: the control-plane journal is always-on; its emit sites are
-# control-plane only (recovery rounds, retirement handshakes,
-# barriers, job lifecycle — NO per-task emits), so the tasks probe
-# armed-vs-off must read ~0 us/task.  The absolute bound proves the C
-# run_quantum fast path never crosses the journal.
-journal_bound="${5:-0.3}"
+# the journal's emit sites are control-plane only (recovery rounds,
+# retirement handshakes, barriers, job lifecycle), so armed-vs-off must
+# read ~0 us/task: the C run_quantum fast path never crosses it
+journal_bound="${4:-0.3}"
 jnl="${TMPDIR:-/tmp}/premerge_journal_$$.json"
 if JAX_PLATFORMS=cpu PARSEC_BENCH_APP=journal \
      python "$repo/bench.py" > "$jnl" 2>/dev/null; then
@@ -456,31 +406,26 @@ then
     rc=1
 fi
 echo "== premerge probe: chaos (seeded fault plans, no-hang invariant) =="
-# 8 seeds = one pass over the quick catalog, which now includes the
-# shm-transport kill, the recv-reorder legs, AND the r12 recovery
-# cases (kill-close-recover / kill-dtd-recover: kill_rank plans that
-# must end in COMPLETED jobs with validated numbers on the survivor).
-# r16 arms --audit-journal: every smoke case runs with the
-# control-plane journal on and tools/journal_audit.py's invariant
-# auditor over the per-case bundle afterwards — a protocol-invariant
-# violation fails premerge even when the workload outcome matched.
+# 8 seeds = one pass over the quick catalog (delayed payloads, rank
+# kills over TCP and shm, recv reorder, transient task faults, the
+# kill-and-recover cases); the full one is `tools/chaos.py --seeds 12`.
+# --audit-journal: tools/journal_audit.py over every case's bundle, so a
+# protocol-invariant violation fails even when the outcome matched.
 if ! JAX_PLATFORMS=cpu python "$repo/tools/chaos.py" --seeds 8 --quick \
      --audit-journal; then
     rc=1
 fi
 echo "== premerge probe: recovery minimal-vs-full replay A/B =="
-# r13: the recorded-lineage minimal replay must re-execute STRICTLY
-# FEWER tasks than replay-from-restore-point on the acceptance kill,
-# with each leg provably taking its intended path (a silent fallback
-# to full replay fails the gate).  r15 adds a SECOND A/B line to the
-# same gate: the 3-rank DTD chain down the cross-rank skip-agreement
-# path (insert-stream prefix agreed over the wire between two
-# survivors) vs the forced full insert-stream replay.
+# the recorded-lineage minimal replay must re-execute STRICTLY FEWER
+# tasks than replay-from-restore-point on the acceptance kill, each leg
+# provably taking its intended path (a silent fallback to full replay
+# fails); a second line does the same for the 3-rank DTD chain down the
+# cross-rank skip-agreement path.
 if ! JAX_PLATFORMS=cpu python "$repo/tools/chaos.py" --ab-minimal; then
     rc=1
 fi
 echo "== premerge probe: chaos soak (random recover schedules) =="
-# r15: N=4 randomly seeded schedules drawn from the recover catalog,
+# 4 randomly seeded schedules drawn from the recover catalog,
 # each with the full per-run invariant checks (validated numerics,
 # no hang, recovery observed); the master seed is printed so any
 # failure replays exactly (PARSEC_CHAOS_SOAK_SEED=<seed> --soak 4)
@@ -488,14 +433,14 @@ if ! JAX_PLATFORMS=cpu python "$repo/tools/chaos.py" --soak 4; then
     rc=1
 fi
 echo "== premerge probe: chaos degrade (drain-before-death, audited) =="
-# r19: a seeded ramped degradation of rank 1 (frame delay incl.
+# a seeded ramped degradation of rank 1 (frame delay incl.
 # heartbeats + task-body jitter, tools/chaos.py --degrade) on a
 # 2-rank gang.  The health plane (prof/health.py) must score the
 # rank down from its heartbeat gap/jitter inflation, the serving
 # fabric must journal an evidence-carrying pre-emptive drain and
 # stop placing on the rank STRICTLY BEFORE the heartbeat detector
 # declares it dead (comm_peer_timeout_s is never approached), and
-# the journal must pass the auditor clean — including the r19 H1
+# the journal must pass the auditor clean — including the H1
 # health invariant (drains evidence-backed, drained ranks never
 # placement-targeted).
 if ! JAX_PLATFORMS=cpu python "$repo/tools/chaos.py" --degrade; then
