@@ -29,6 +29,7 @@ class DeviceStats:
                  "chained_launches", "chained_tasks", "launches",
                  "held_tasks", "defused_waves", "starved_waits",
                  "inflight_waits", "compiles", "warm_waits",
+                 "release_passes",
                  "replicas_adopted", "replicas_released",
                  "replica_bytes_peak")
 
@@ -55,7 +56,10 @@ class DeviceStats:
         #: launches that waited for room under device_inflight_depth;
         #: first calls of a program (a trace and a compile or a cache
         #: read hides in each) plus the background width compiles;
-        #: launches that blocked on a fused width's background compile
+        #: launches that blocked on a fused width's background compile;
+        #: passes of the completer that took at least one task
+        #: ((executed_tasks + held_tasks) / release_passes tasks a pass:
+        #: 1.0 where it never found more than one handed over)
         self.launches = 0
         self.held_tasks = 0
         self.defused_waves = 0
@@ -63,6 +67,7 @@ class DeviceStats:
         self.inflight_waits = 0
         self.compiles = 0
         self.warm_waits = 0
+        self.release_passes = 0
         #: SHARED copies this chip held for counted consumers of another
         #: chip's tile (comm/ici.py expect; pushed over ICI or pulled by
         #: a stage-in), how many of them left again at their last

@@ -1714,6 +1714,11 @@ class XlaDevice(Device):
     # tasks providing backpressure.
     # ------------------------------------------------------------------
     def _completer_loop(self):
+        """One PASS a turn: whatever the managers have handed over is
+        taken under one hold of ``_cond``, released task by task outside
+        it, retired under one more hold and drained (probed, finalized)
+        once.  A pass of one entry is the per-task loop this replaced;
+        its size follows the queue."""
         from parsec_tpu.core import scheduling
         while True:
             with self._cond:
@@ -1722,67 +1727,80 @@ class XlaDevice(Device):
                         while not self._inflight and not self._stop:
                             self._cond.wait(0.1)
                             if self._retire and not self._inflight:
-                                break   # idle tick: see below
-                if not self._inflight:
-                    if self._stop:
-                        break   # stopped and drained
-                    inf = None
-                else:
-                    inf = self._inflight.popleft()
-                    # _completing keeps the task visible to sync() between
-                    # the queue pop and the retire append:
-                    # complete_execution below is what wakes Context.wait,
-                    # which may race into sync()
-                    self._completing += 1
-                    self._cond.notify_all()
-            if inf is None:
-                # Nothing in flight, but retired tasks still hold their
-                # pins: their outputs were not ready when last probed,
-                # and nothing would probe again before the NEXT dispatch
-                # — which may itself be waiting in _reserve for exactly
-                # those pins (tight device_mem_mb + a slow or busy
-                # device: the manager then starved for its 30 s and
-                # failed the task with device-oom).  Re-probe while idle.
-                try:
-                    self._drain_retired(max_unfinalized=self._runahead)
-                except Exception as exc:
-                    self.stats.faults += 1
-                    if self.es is not None:
-                        self.es.context.record_error(exc, None)
-                continue
-            try:
-                if not inf.prepublished:
-                    # chain-held tasks planted their Deferred payloads at
-                    # hold time; rewriting here could clobber a resolution
-                    for fname, arr in inf.outputs.items():
-                        dc = inf.task.data.get(fname)
-                        if dc is not None:
-                            dc.payload = arr
-                # dep release and the scheduling of successors: the one
-                # span that is per task by design (seq = the launch the
-                # task rode), so its arguments are built only when live
-                release = open_span(
-                    inf.es, "fin.release",
-                    pool=inf.task.taskpool.taskpool_id,
-                    cls=inf.task.task_class.name, seq=inf.seq) \
-                    if spans_live(inf.es) else SPAN_OFF
-                try:
-                    scheduling.complete_execution(inf.es, inf.task)
-                finally:
-                    release.end()
-            except Exception as exc:
-                self.stats.faults += 1
-                inf.es.context.record_error(exc, inf.task)
-            with self._cond:
-                self._retire.append(inf)
-                self._completing -= 1
-                self._cond.notify_all()
+                                break   # idle tick: see the drain
+                if not self._inflight and self._stop:
+                    break   # stopped and drained
+                # from the head, in dispatch order, and never past the
+                # valve: released and unfinalized together stay within
+                # device_runahead + 1, as one entry a turn kept them
+                take = min(len(self._inflight),
+                           max(1, self._runahead - len(self._retire)))
+                batch = [self._inflight.popleft() for _ in range(take)]
+                if batch:
+                    # _completing keeps the pass visible to sync() between
+                    # the queue pop and the retire: complete_execution
+                    # below is what wakes Context.wait, which may race
+                    # into sync()
+                    self._completing += take
+                    self._cond.notify_all()   # room was made
+            if batch:
+                self.stats.release_passes += 1
+                with open_span(self.es, "fin.pass", n=take):
+                    for inf in batch:
+                        self._release(inf, scheduling)
+                    with self._cond:
+                        self._retire.extend(batch)
+                        self._completing -= take
+                        self._cond.notify_all()
+            # ONE probe and one finalization a pass.  With no batch it is
+            # the idle tick: nothing in flight, but retired tasks still
+            # hold their pins: their outputs were not ready when last
+            # probed, and nothing would probe again before the NEXT
+            # dispatch — which may itself be waiting in _reserve for
+            # exactly those pins (tight device_mem_mb + a slow or busy
+            # device: the manager then starved for its 30 s and failed
+            # the task with device-oom).  Re-probe while idle.
             try:
                 self._drain_retired(max_unfinalized=self._runahead)
             except Exception as exc:   # the completer thread must survive
                 self.stats.faults += 1
-                inf.es.context.record_error(exc, inf.task)
+                if self.es is not None:
+                    self.es.context.record_error(
+                        exc, batch[-1].task if batch else None)
+            # the idle wait must not keep the pass's entries alive: they
+            # name their tasks' output arrays, and a job's last ones
+            # would stay on the device beside the next job's
+            batch = inf = None
         self._drain_retired(max_unfinalized=0)
+
+    def _release(self, inf: _Inflight, scheduling) -> None:
+        """Publish one dispatched task's outputs and release its deps
+        (``scheduling``: the module, imported once a thread).  A release
+        that raises is that task's error: the rest of its pass is
+        released all the same."""
+        try:
+            if not inf.prepublished:
+                # chain-held tasks planted their Deferred payloads at
+                # hold time; rewriting here could clobber a resolution
+                for fname, arr in inf.outputs.items():
+                    dc = inf.task.data.get(fname)
+                    if dc is not None:
+                        dc.payload = arr
+            # dep release and the scheduling of successors: the one
+            # span that is per task by design (seq = the launch the
+            # task rode), so its arguments are built only when live
+            release = open_span(
+                inf.es, "fin.release",
+                pool=inf.task.taskpool.taskpool_id,
+                cls=inf.task.task_class.name, seq=inf.seq) \
+                if spans_live(inf.es) else SPAN_OFF
+            try:
+                scheduling.complete_execution(inf.es, inf.task)
+            finally:
+                release.end()
+        except Exception as exc:
+            self.stats.faults += 1
+            inf.es.context.record_error(exc, inf.task)
 
     def _drain_retired(self, max_unfinalized: int) -> None:
         """Finalize retired tasks whose outputs are ready; when more than
@@ -1814,16 +1832,12 @@ class XlaDevice(Device):
                 # popped entries stay visible to sync() until their
                 # finalization lands (late errors must beat wait())
                 self._finalizing += len(batch)
-                self._cond.notify_all()
             try:
-                # (in a small-tile run nearly every task's drain finds
-                # the newest ready, so this one is per task in effect)
                 drain = open_span(self.es, "fin.drain", block=int(block),
                                   n=len(batch)) \
                     if spans_live(self.es) else SPAN_OFF
                 try:
-                    for inf in batch:
-                        self._finalize(inf, block=block)
+                    self._finalize(batch, block=block)
                 finally:
                     drain.end()
             finally:
@@ -1850,31 +1864,40 @@ class XlaDevice(Device):
                 return False
         return True
 
-    def _finalize(self, inf: _Inflight, block: bool) -> None:
-        try:
-            if block:
+    def _finalize(self, batch: List[_Inflight], block: bool) -> None:
+        """Give back what ``batch`` (retired entries, oldest first) held
+        until its outputs materialized: the load in one ``load_sub``,
+        the pins under one hold of ``_mem_lock``; replicas and arena
+        copies entry by entry, in dispatch order.  ``block`` waits for
+        the newest entry's outputs first: the chip's queue is in order,
+        so that wait covers the earlier ones."""
+        ctx = self.es.context
+        if block:
+            newest = batch[-1]
+            try:
                 import jax
-                for a in inf.outputs.values():
+                for a in newest.outputs.values():
                     try:
                         jax.block_until_ready(a)
                     except Exception as exc:
                         if "deleted" in str(exc).lower():
                             continue   # donated away — see _outputs_ready
                         raise
-        except Exception as exc:
-            # deps were already released at dispatch; a late device-side
-            # failure surfaces as a context error (sync()/wait raise)
-            self.stats.faults += 1
-            inf.es.context.record_error(exc, inf.task)
-        finally:
-            if inf.es.context._device_spans:
-                # outputs are materialized (or the failure surfaced):
-                # close the dispatch->done device span
+            except Exception as exc:
+                # deps were already released at dispatch; a late
+                # device-side failure surfaces as a context error
+                # (sync()/wait raise)
+                self.stats.faults += 1
+                ctx.record_error(exc, newest.task)
+        if ctx._device_spans:
+            # outputs are materialized (or the failure surfaced):
+            # close the dispatch->done device spans
+            for inf in batch:
                 inf.es.pins("device_done", inf.task)
-            self.load_sub(inf.load)
-            for d in inf.pinned:
-                self._unpin(d)
-            ici = inf.es.context.ici
+        self.load_sub(sum(inf.load for inf in batch))
+        self._unpin_all(d for inf in batch for d in inf.pinned)
+        ici = ctx.ici
+        for inf in batch:
             if ici is not None:
                 # deps were released at dispatch and the chip's queue is
                 # in order: a replica this task read may leave now
@@ -2020,11 +2043,7 @@ class XlaDevice(Device):
             self._retire.clear()
         if not entries:
             return
-        # newest-first: the device queue is in-order, so one blocking
-        # wait on the LAST dispatched outputs covers the earlier ones
-        self._finalize(entries[-1], block=True)
-        for inf in entries[:-1]:
-            self._finalize(inf, block=False)
+        self._finalize(entries, block=True)
 
     # ------------------------------------------------------------------
     # device memory cache management (reference: gpu_mem_lru / zone_malloc)
@@ -2034,12 +2053,17 @@ class XlaDevice(Device):
             self._pins[id(datum)] = self._pins.get(id(datum), 0) + 1
 
     def _unpin(self, datum) -> None:
+        self._unpin_all((datum,))
+
+    def _unpin_all(self, data) -> None:
         with self._mem_lock:
-            n = self._pins.get(id(datum), 0) - 1
-            if n <= 0:
-                self._pins.pop(id(datum), None)
-            else:
-                self._pins[id(datum)] = n
+            pins = self._pins
+            for datum in data:
+                n = pins.get(id(datum), 0) - 1
+                if n <= 0:
+                    pins.pop(id(datum), None)
+                else:
+                    pins[id(datum)] = n
 
     def _touch(self, datum) -> None:
         with self._mem_lock:

@@ -782,7 +782,9 @@ class RuntimeMetrics:
                     ("inflight_waits",
                      "parsec_device_inflight_waits_total"),
                     ("compiles", "parsec_device_compiles_total"),
-                    ("warm_waits", "parsec_device_warm_waits_total")):
+                    ("warm_waits", "parsec_device_warm_waits_total"),
+                    ("release_passes",
+                     "parsec_device_release_passes_total")):
                 v = getattr(st, key, None)
                 if isinstance(v, (int, float)) and v:
                     out.append(counter_sample(metric, v, labels))

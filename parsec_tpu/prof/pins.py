@@ -37,8 +37,8 @@ PINS_EVENTS = ("select", "exec_begin", "exec_end", "exec_async",
 #: background fused-width compiler (devices/xla.py, core/scheduling.py).
 SPAN_NAMES = ("mgr.starved", "mgr.launch", "mgr.pop_wave", "mgr.stage_in",
               "mgr.dispatch", "mgr.inflight_wait", "mgr.warm_wait",
-              "fin.idle", "fin.release", "fin.drain", "worker.idle",
-              "warm.compile")
+              "fin.idle", "fin.pass", "fin.release", "fin.drain",
+              "worker.idle", "warm.compile")
 #: the ICI transport's spans (comm/ici.py), around each data movement
 #: between chips, on whichever thread releases the deps (mostly a
 #: completer, inside its ``fin.release``); arguments ``bytes``, ``ndst``.

@@ -462,3 +462,347 @@ def test_chain_hold_resolves_at_sync_without_consumer():
     L = np.tril(A.to_array())
     err = np.abs(L @ L.T - spd).max() / np.abs(spd).max()
     assert err < 1e-4, err
+
+
+# ---------------------------------------------------------------------
+# the completer's pass (devices/xla.py _completer_loop): everything the
+# managers have handed over is taken under one hold of the device's
+# lock, released in dispatch order, retired and drained once
+# ---------------------------------------------------------------------
+PASS_MT = 12
+
+
+def _until(cond, what, seconds=60.0):
+    import time as _time
+    deadline = _time.monotonic() + seconds
+    while not cond():
+        assert _time.monotonic() < deadline, f"never saw: {what}"
+        _time.sleep(0.001)
+
+
+def _fan_pool(A, mt, successors=False, src=True):
+    """SRC on tile (0, mt) lets go of MUL(n): T * 2 on tile (0, n), n <
+    mt, over a CTL edge each (``src`` False: no SRC, the MULs are ready
+    at once); with ``successors`` each MUL feeds ADD(n): T + 1."""
+    p = PTG("passes", MT=mt)
+    if src:
+        p.task("SRC") \
+            .affinity(lambda A=A, MT=mt: A(0, MT)) \
+            .flow("T", "RW", IN(DATA(lambda A=A, MT=mt: A(0, MT))),
+                  OUT(DATA(lambda A=A, MT=mt: A(0, MT)))) \
+            .flow("go", "CTL", OUT(TASK(
+                "MUL", "go", lambda MT=mt: [dict(n=n) for n in range(MT)]))) \
+            .body(lambda T: T + 1.0, device="tpu")
+    out = [OUT(TASK("ADD", "T", lambda n: dict(n=n)))] if successors \
+        else [OUT(DATA(lambda n, A=A: A(0, n)))]
+    mul = p.task("MUL", n=Range(0, mt - 1)) \
+        .affinity(lambda n, A=A: A(0, n)) \
+        .flow("T", "RW", IN(DATA(lambda n, A=A: A(0, n))), *out)
+    if src:
+        mul = mul.flow("go", "CTL", IN(TASK("SRC", "go", lambda n: dict())))
+    mul.body(lambda T: T * 2.0, device="tpu")
+    if successors:
+        p.task("ADD", n=Range(0, mt - 1)) \
+            .affinity(lambda n, A=A: A(0, n)) \
+            .flow("T", "RW", IN(TASK("MUL", "T", lambda n: dict(n=n))),
+                  OUT(DATA(lambda n, A=A: A(0, n)))) \
+            .body(lambda T: T + 1.0, device="tpu")
+    return p.build()
+
+
+class _Releases:
+    """``scheduling.complete_execution`` as the completer calls it, under
+    the test's hand.  ``calls`` lists the tasks in the order of their
+    release.  The call numbered k of ``holds`` waits for ``open(k)``,
+    ``"after"`` the real release (its successors are on their way while
+    the completer is still inside its pass) or ``"before"`` it.  The
+    call numbered ``fail_at`` raises in the real one's place.  Every
+    release takes ``slow_s`` longer."""
+
+    def __init__(self, monkeypatch, holds=(), fail_at=None, slow_s=0.0):
+        import threading
+        from parsec_tpu.core import scheduling
+        self.calls = []
+        self.holds, self.fail_at, self.slow_s = dict(holds), fail_at, slow_s
+        self.held = {k: threading.Event() for k in self.holds}
+        self._gates = {k: threading.Event() for k in self.holds}
+        self._real = scheduling.complete_execution
+        monkeypatch.setattr(scheduling, "complete_execution", self)
+
+    def _hold(self, number, when):
+        if self.holds.get(number) == when:
+            self.held[number].set()
+            assert self._gates[number].wait(60.0), "the gate never opened"
+
+    def __call__(self, es, task, *a, **kw):
+        import threading
+        import time as _time
+        if not threading.current_thread().name.startswith("xla-fin"):
+            return self._real(es, task, *a, **kw)
+        number = len(self.calls)
+        self.calls.append(task)
+        self._hold(number, "before")
+        if self.slow_s:
+            _time.sleep(self.slow_s)
+        if number == self.fail_at:
+            raise RuntimeError(f"injected release fault in {task!r}")
+        try:
+            return self._real(es, task, *a, **kw)
+        finally:
+            self._hold(number, "after")
+
+    def open(self, number):
+        self._gates[number].set()
+
+
+def _dispatch_log(monkeypatch):
+    """The tasks in the order their entries were made for ``_inflight``
+    (under the device's lock, in ``_launch``): the dispatch order."""
+    from parsec_tpu.devices import xla
+    made = []
+
+    class Logged(xla._Inflight):
+        __slots__ = ()
+
+        def __init__(self, es, task, *a, **kw):
+            made.append(task)
+            super().__init__(es, task, *a, **kw)
+    monkeypatch.setattr(xla, "_Inflight", Logged)
+    return made
+
+
+def _span_log(ctx, monkeypatch):
+    """(name, arguments known at the begin and at the end) of every span
+    as it closes, the profiler's gate forced open (no session needed)."""
+    spans = []
+    monkeypatch.setattr(ctx, "_span_live", lambda: True)
+    ctx.pins_register("span_end", lambda es, event, span: spans.append(
+        (span.name, {**span.args, **(span.late or {})})))
+    return spans
+
+
+@pytest.fixture
+def pass_mca():
+    mca = {"device_max": 1, "device_fuse": 8, "device_inflight_depth": 32}
+    for k, v in mca.items():
+        params.set(k, v)
+    yield mca
+    for k in mca:
+        params.unset(k)
+
+
+def _tiles(mt, mb=8):
+    A = TwoDimBlockCyclic(mb=mb, nb=mb, lm=mb, ln=mt * mb)
+    for _m, n in A.local_tiles():
+        A.data_of(0, n).copy_on(0).payload[:] = float(n)
+    return A
+
+
+def _hand_over_behind_src(ctx, dev, rel, A, successors=False):
+    """Start the fan with the completer held at the end of SRC's release:
+    on return its first pass (SRC alone) is still open and every MUL
+    sits in ``_inflight``, handed over by the managers."""
+    ctx.add_taskpool(_fan_pool(A, PASS_MT, successors))
+    ctx.start()
+    _until(rel.held[0].is_set, "the completer at the end of SRC's release")
+    _until(lambda: len(dev._inflight) == PASS_MT, "every MUL handed over")
+    assert dev._completing == 1 and rel.calls[0].task_class.name == "SRC"
+
+
+def test_a_pass_takes_what_was_handed_over_and_releases_it_in_dispatch_order(
+        monkeypatch, pass_mca):
+    """The completer is held inside its first pass while the managers
+    hand over a whole fan: its next pass takes all of it under one hold,
+    releases it in the order it was dispatched, and finalizes it in one
+    drain."""
+    made = _dispatch_log(monkeypatch)
+    rel = _Releases(monkeypatch, holds={0: "after"})
+    A = _tiles(PASS_MT + 1)
+    with make_ctx() as ctx:
+        spans = _span_log(ctx, monkeypatch)
+        (dev,) = ctx.device_registry.accelerators
+        _hand_over_behind_src(ctx, dev, rel, A)
+        rel.open(0)
+        ctx.wait(timeout=120)
+        assert dev.stats.faults == 0
+        assert dev.stats.executed_tasks == PASS_MT + 1
+        assert dev.stats.release_passes == 2
+    assert rel.calls == made and len(made) == PASS_MT + 1
+    assert [a["n"] for name, a in spans if name == "fin.pass"] == [1, PASS_MT]
+    assert sum(1 for name, _a in spans if name == "fin.release") == \
+        PASS_MT + 1
+    drains = [a["n"] for name, a in spans if name == "fin.drain"]
+    assert max(drains) >= PASS_MT and sum(drains) <= PASS_MT + 1
+    for n in range(PASS_MT):
+        np.testing.assert_allclose(
+            np.asarray(A.data_of(0, n).pull_to_host().payload), 2.0 * n)
+
+
+def test_the_valve_holds_at_runahead_plus_one_whatever_a_pass_finds(
+        monkeypatch):
+    """Outputs that never read ready and a slow completer: the managers
+    keep ``_inflight`` full, yet no pass takes more than the valve
+    leaves room for — released and unfinalized entries together never
+    pass ``device_runahead`` + 1, and the oldest is waited for there."""
+    from parsec_tpu.devices.xla import XlaDevice
+    MT, AHEAD = 40, 4
+    monkeypatch.setattr(XlaDevice, "_outputs_ready",
+                        staticmethod(lambda inf: False))
+    drain, seen = XlaDevice._drain_retired, []
+
+    def watched(self, max_unfinalized):
+        seen.append(len(self._retire) + self._completing)
+        return drain(self, max_unfinalized)
+    monkeypatch.setattr(XlaDevice, "_drain_retired", watched)
+    _Releases(monkeypatch, slow_s=0.003)
+    mca = {"device_max": 1, "device_inflight_depth": AHEAD,
+           "device_runahead": AHEAD}
+    for k, v in mca.items():
+        params.set(k, v)
+    try:
+        A = _tiles(MT)
+        with make_ctx() as ctx:
+            spans = _span_log(ctx, monkeypatch)
+            (dev,) = ctx.device_registry.accelerators
+            assert dev._runahead == AHEAD
+            ctx.add_taskpool(_fan_pool(A, MT, src=False))
+            ctx.wait(timeout=120)
+            assert dev.stats.faults == 0
+            assert dev.stats.release_passes < MT       # passes of several
+    finally:
+        for k in mca:
+            params.unset(k)
+    assert max(seen) == AHEAD + 1
+    blocking = [a for name, a in spans
+                if name == "fin.drain" and a["block"] == 1]
+    assert blocking and all(a["n"] == 1 for a in blocking)
+    for n in range(MT):
+        np.testing.assert_allclose(
+            np.asarray(A.data_of(0, n).pull_to_host().payload), 2.0 * n)
+
+
+def test_a_release_that_raises_is_its_tasks_error_and_the_pass_goes_on(
+        monkeypatch, pass_mca):
+    """``complete_execution`` raises for the second task of a pass: the
+    error is recorded against that task, the rest of the pass is
+    released and its successors run, and the pool ends with the error
+    instead of hanging."""
+    rel = _Releases(monkeypatch, holds={0: "after"}, fail_at=2)
+    A = _tiles(PASS_MT + 1)
+    with make_ctx() as ctx:
+        (dev,) = ctx.device_registry.accelerators
+        _hand_over_behind_src(ctx, dev, rel, A, successors=True)
+        rel.open(0)
+        adds = lambda: [t for t in rel.calls  # noqa: E731
+                        if t.task_class.name == "ADD"]
+        _until(lambda: len(adds()) == PASS_MT - 1,
+               "the successor of every MUL but the failed one")
+        failed = rel.calls[2]
+        with pytest.raises(RuntimeError) as caught:
+            ctx.wait(timeout=60)
+        assert str(caught.value) == f"task {failed!r} failed"
+        assert "injected release fault" in str(caught.value.__cause__)
+        assert [t for _exc, t in ctx._errors] == [failed]
+        assert dev.stats.faults == 1 and dev.stats.release_passes >= 2
+        # the rest of its pass came after it, and went through
+        assert [t.task_class.name for t in rel.calls[:PASS_MT + 1]] == \
+            ["SRC"] + ["MUL"] * PASS_MT
+        assert sorted(t.locals["n"] for t in adds()) == sorted(
+            t.locals["n"] for t in rel.calls[1:PASS_MT + 1] if t is not failed)
+        ctx._errors.clear()
+
+
+def test_sync_entered_in_the_middle_of_a_pass_waits_for_all_of_it(
+        monkeypatch, pass_mca):
+    """``sync()`` called while a pass is half released does not return
+    until the whole pass is released and finalized: what the pass took
+    stays counted in ``_completing`` until it is in ``_retire``."""
+    import threading
+    rel = _Releases(monkeypatch, holds={0: "after", 2: "before"})
+    A = _tiles(PASS_MT + 1)
+    with make_ctx() as ctx:
+        (dev,) = ctx.device_registry.accelerators
+        _hand_over_behind_src(ctx, dev, rel, A)
+        rel.open(0)
+        _until(rel.held[2].is_set, "the second pass at its second release")
+        assert dev._completing == PASS_MT and not dev._inflight
+        assert len(dev._retire) + dev._finalizing <= 1
+        done = threading.Event()
+        syncer = threading.Thread(
+            target=lambda: (dev.sync(timeout=60), done.set()), daemon=True)
+        syncer.start()
+        assert not done.wait(0.3), "sync() returned with a pass half released"
+        assert len(rel.calls) == 3
+        rel.open(2)
+        assert done.wait(60), "sync() never returned"
+        assert len(rel.calls) == PASS_MT + 1
+        assert dev._completing == dev._finalizing == 0
+        assert not dev._retire and not dev._pins and dev.load == 0.0
+        ctx.wait(timeout=120)
+        assert dev.stats.faults == 0 and dev.stats.release_passes == 2
+
+
+def test_hand_over_under_a_short_switch_interval_loses_nothing():
+    """Workers, two managers and the completer trading places every
+    10 us over a shallow hand-over queue and a tight valve: every task
+    is released once, every pin and every unit of load comes back."""
+    import sys
+    MT = 150
+    mca = {"device_max": 1, "device_inflight_depth": 4, "device_runahead": 6}
+    for k, v in mca.items():
+        params.set(k, v)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        A = _tiles(MT)
+        with Context(nb_cores=8) as ctx:
+            (dev,) = ctx.device_registry.accelerators
+            ctx.add_taskpool(_fan_pool(A, MT, successors=True, src=False))
+            ctx.wait(timeout=120)
+            st = dev.stats
+            assert st.faults == 0 and st.executed_tasks == 2 * MT
+            assert 0 < st.release_passes <= 2 * MT
+            assert dev._completing == dev._finalizing == 0
+            assert not dev._inflight and not dev._retire
+            assert not dev._pins and dev.load == 0.0
+    finally:
+        sys.setswitchinterval(interval)
+        for k in mca:
+            params.unset(k)
+    for n in range(MT):
+        np.testing.assert_allclose(
+            np.asarray(A.data_of(0, n).pull_to_host().payload), 2.0 * n + 1)
+
+
+def test_the_idle_completer_keeps_no_entry_of_its_last_pass_alive(
+        monkeypatch, pass_mca):
+    """An entry names its task's output arrays.  Once a pool is through
+    and the device synced, nothing may hold the entries of the last pass
+    — not the completer's own frame while it waits for the next job: a
+    job's last outputs would stay on the device beside the next job's
+    (1.8 GB of a 16 GB chip in the GEMM cell of the benchmark)."""
+    import gc
+    import weakref
+    from parsec_tpu.devices import xla
+    made = []
+
+    class Weak(xla._Inflight):
+        __slots__ = ("__weakref__",)
+
+        def __init__(self, *a, **kw):
+            super().__init__(*a, **kw)
+            made.append(weakref.ref(self))
+    monkeypatch.setattr(xla, "_Inflight", Weak)
+    rel = _Releases(monkeypatch, holds={0: "after"})
+    A = _tiles(PASS_MT + 1)
+    with make_ctx() as ctx:
+        (dev,) = ctx.device_registry.accelerators
+        _hand_over_behind_src(ctx, dev, rel, A)
+        rel.open(0)
+        ctx.wait(timeout=120)
+        assert dev.stats.release_passes == 2 and len(made) == PASS_MT + 1
+
+        def all_gone():
+            gc.collect()
+            return not [r for r in made if r() is not None]
+        _until(all_gone, "every entry of the last pass collected", 10.0)

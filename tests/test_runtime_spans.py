@@ -8,6 +8,7 @@ device) runs under ``jax.profiler``; the trace is read back with
 import glob
 import os
 import re
+import threading
 
 import numpy as np
 import pytest
@@ -21,13 +22,28 @@ MB, NT, JOBS = 16, 4, 3
 #: tasks of one tiled Cholesky: POTRF nt, TRSM and SYRK nt(nt-1)/2 each,
 #: GEMM nt(nt-1)(nt-2)/6
 TASKS = NT + NT * (NT - 1) + NT * (NT - 1) * (NT - 2) // 6
-#: depth 1 makes every launch behind an unretired one wait for room
-#: (mgr.inflight_wait); the window lets siblings meet in fused waves
-#: (warm.compile) however the threads interleave
+#: depth 1 makes every launch behind an entry the completer has not
+#: taken yet wait for room (mgr.inflight_wait; the traced jobs slow the
+#: completer's releases, as a loaded host does, so that some do); the
+#: window lets siblings meet in fused waves (warm.compile) however the
+#: threads interleave
 MCA = {"device_max": 1, "device_inflight_depth": 1,
        "device_fuse_window_ms": 2.0}
 MGR_CHILDREN = ("mgr.pop_wave", "mgr.stage_in", "mgr.dispatch",
                 "mgr.inflight_wait", "mgr.warm_wait")
+
+
+def _slow_releases(mp, seconds):
+    """Every dep release takes the completer ``seconds`` longer."""
+    import time
+    from parsec_tpu.core import scheduling
+    real = scheduling.complete_execution
+
+    def slow(es, task, *a, **kw):
+        if threading.current_thread().name.startswith("xla-fin"):
+            time.sleep(seconds)
+        return real(es, task, *a, **kw)
+    mp.setattr(scheduling, "complete_execution", slow)
 
 
 def _run_jobs(jobs=JOBS):
@@ -77,7 +93,9 @@ def traced(tmp_path_factory):
     opts.host_tracer_level = 2
     jax.profiler.start_trace(out, profiler_options=opts)
     try:
-        stats, samples = _run_jobs()
+        with pytest.MonkeyPatch.context() as mp:
+            _slow_releases(mp, 0.001)
+            stats, samples = _run_jobs()
     finally:
         jax.profiler.stop_trace()
     (path,) = glob.glob(os.path.join(out, "plugins", "profile", "*",
@@ -199,6 +217,33 @@ def test_every_task_is_counted_and_released(traced):
     assert sum(a["n"] for a in by_seq.values()) == JOBS * TASKS
 
 
+def test_pass_spans_equal_the_pass_counter_and_hold_every_release(traced):
+    """One ``fin.pass`` a pass of the completer that took something, its
+    ``n`` what it took; each holds exactly ``n`` ``fin.release`` (one a
+    task, as ever) and none lies outside; what a ``fin.drain`` finalizes
+    was released before it."""
+    st = traced["stats"]
+    passes = _spans(traced, "fin.pass")
+    assert len(passes) == st["release_passes"] > 0
+    assert sum(ev[3]["n"] for ev in passes) == JOBS * TASKS
+    assert st["release_passes"] <= st["executed_tasks"] + st["held_tasks"]
+    (fin,) = [ln for ln in traced["lines"] if ln[0][0].startswith("fin.")]
+    releases = [ev for ev in fin if ev[0] == "fin.release"]
+    inside = 0
+    for _name, s, e, a in passes:
+        mine = [r for r in releases if s <= r[1] and r[2] <= e]
+        assert len(mine) == a["n"] >= 1
+        inside += len(mine)
+    assert inside == len(releases) == JOBS * TASKS
+    finalized = 0
+    for _name, s, _e, a in (ev for ev in fin if ev[0] == "fin.drain"):
+        finalized += a["n"]
+        assert a["n"] >= 1 and a["block"] in (0, 1)
+        assert finalized <= sum(1 for r in releases if r[2] <= s)
+    assert traced["samples"]["parsec_device_release_passes_total"] == \
+        st["release_passes"]
+
+
 def test_program_names_in_spans_and_modules(traced):
     pat = re.compile(r"^jit_parsec_(chain_)?[A-Z]+")
     programs = {ev[3]["program"] for name in ("mgr.dispatch", "warm.compile")
@@ -230,6 +275,7 @@ def test_no_profiler_session_same_counts():
     assert st["executed_tasks"] + st["held_tasks"] == JOBS * TASKS
     assert st["held_tasks"] == JOBS * (NT - 1)
     assert st["launches"] > 0 and st["starved_waits"] > 0
+    assert 0 < st["release_passes"] <= JOBS * TASKS
 
 
 def _module_name(jitted, *args):
