@@ -3,23 +3,19 @@
 
 Drives ``Context`` -> PTG taskpool -> dep engine -> ``XlaDevice`` once
 per app, at the sizes the repo calls its headline, in ONE process on ONE
-TPU chip, and checks every result.  The GEMM and Cholesky phases are the
-benchmark's own jobs (``benchmark/apps/``: operands, MCA settings and
-limits from ``benchmark/configs/``, the comparison ``Job.check()``), at
-the sizes of its cells 2 and 1; nothing under ``benchmark/`` imports
-this file:
+TPU chip, and checks every result.  The phases are the benchmark's own
+jobs (``benchmark/apps/``: operands, MCA settings and limits from
+``benchmark/configs/``, the comparison ``Job.check()``), at the sizes of
+its cells 2, 1 and 5; nothing under ``benchmark/`` imports this file:
 
     gemm    dplasma_gemm_bf16 at mb=12288, 3x3 tiles, kt=4: every C tile
             against the plain product, c_rel_err <= 1e-4
     potrf   dplasma_potrf_bf16 at mb=6144, nt=16 (n = 98 304, ~10 GB
             resident): one warm pass, two runs, offdiag_resid <= 0.02
-    geqrf   qr_taskpool as it runs by default (ib=512 panel engine,
-            cross-panel chain fusion), mb=6144, bf16 storage, nt cut
-            from 8 to 2 (see GEQRF_NT),
-            apps/qr_check.factorization_residual <= 2e-2
-    geqrf_per_kernel
-            the same at the uncut nt=8 with device_fuse_panel=0: the
-            panel engine at its headline size, one launch a panel kernel
+    geqrf   dplasma_geqrf_bf16 at mb=6144, nt=8 (n = 49 152), ib=512,
+            through the default path (column chains two links a
+            program): factor_resid and below_diag_max under the
+            configuration's limits
 
     python chip_smoke.py              # one chip (what the driver runs)
     python chip_smoke.py --chips 4    # ONLY the four-chip phase and the
@@ -53,33 +49,15 @@ import time
 import numpy as np
 
 from benchmark import tiles
-from benchmark.apps import gemm as gemm_app, potrf as potrf_app
+from benchmark.apps import (gemm as gemm_app, geqrf as geqrf_app,
+                            potrf as potrf_app)
 from parsec_tpu.utils.mca import params
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 
-#: geqrf has no job under benchmark/ yet: its bound is this file's (the
-#: bf16-storage class read 1.0e-2 on the chip the repo was written for)
-GEQRF_TOL = 2e-2
 #: largest relative difference allowed between a sampled potrf tile of
 #: the four-chip run and of the one-chip run: two bfloat16 spacings
 POTRF_AGREE_TOL = 1e-2
-
-#: the defaults overflow a 16 GB chip at nt=8 (HIGHEST-precision TSQRT
-#: programs carry large workspaces: depth 32 ran out of memory)
-GEQRF_MCA = {"device_fuse": 8, "device_runahead": 20,
-             "device_inflight_depth": 12, "device_fuse_window_ms": 4.0}
-#: nt of the default-path geqrf phase, cut from the headline 8; mb is
-#: not cut.  Chain fusion traces the held GEQRT/TSQRT links into their
-#: consumer's program, so every distinct (chain, wave) shape compiles
-#: GEQRT (96 s at mb=6144, ib=512) or TSQRT (159 s) once more per link
-#: (sandbox compile, PERF.md PR 21).  nt=2 asks for about three such
-#: programs, nt=3 for twice that, nt=8 for dozens: 2 is the largest nt
-#: whose cold run leaves this script inside its 1200 s
-GEQRF_NT = 2
-#: the extra geqrf phase keeps nt=8 and turns chain fusion off: GEQRT and
-#: TSQRT then compile once each, whatever nt
-GEQRF_PER_KERNEL = {"device_fuse_panel": 0}
 
 
 def log(msg: str) -> None:
@@ -290,82 +268,18 @@ def run_potrf(mb: int, nt: int, seed: int = 0, storage=None,
 # geqrf
 # ---------------------------------------------------------------------------
 
-def _qr_tile(M):
-    """Jitted ``gen(key) -> (mb, nb) tile`` of the QR operand, in M's
-    storage dtype: Gaussian entries of deviation 0.05 plus the identity
-    on EVERY tile — full rank, and stacked panels well-conditioned for
-    Cholesky-QR.  ``benchmark.tiles`` has no such operand (its entries
-    have variance 1 and only diagonal tiles take a bump)."""
-    import jax
-    import jax.numpy as jnp
-
-    @jax.jit
-    def gen(key):
-        out = 0.05 * jax.random.normal(jax.random.PRNGKey(key),
-                                       (M.mb, M.nb), jnp.float32)
-        return (out + jnp.eye(M.mb, M.nb, dtype=jnp.float32)).astype(M.dtype)
-    return gen
-
-
-def run_geqrf(mb: int, nt: int, seed: int = 0, storage: str = "bfloat16",
-              ib: int = 512, passes: int = 2, mca=None) -> dict:
-    """Tiled QR with the inner-blocked (ib) panel engine on a Gaussian +
-    identity matrix born on the device; the factor is held to
-    R^T R = A^T A on a random probe (apps/qr_check).  ``mca`` goes on
-    top of GEQRF_MCA; the phase is named after the panel path it took."""
-    import jax
-    import jax.numpy as jnp
-    from parsec_tpu.apps.qr import effective_ib, qr_taskpool
-    from parsec_tpu.apps.qr_check import factorization_residual
-    from parsec_tpu.core.context import Context
-    from parsec_tpu.data.matrix import TwoDimBlockCyclic
-
-    n = nt * mb
-    A = TwoDimBlockCyclic(mb=mb, nb=mb, lm=n, ln=n, name="A",
-                          dtype=tiles.storage_dtype(storage))
-    gen = _qr_tile(A)
-    key = {t: 2003 * seed + i for i, t in enumerate(A.local_tiles())}
-    mca = {**GEQRF_MCA, **(mca or {}), "qr_ib": ib}
-
-    t0 = time.perf_counter()
-    with _mca(**mca), Context(nb_cores=4) as ctx:
-        ib_used = effective_ib(mb)
-        chain = bool(int(params.get("device_fuse_panel", 1)))
-
-        dev = ctx.device_registry.accelerators[0]
-
-        def stage():
-            tiles.discard_scratch(ctx)           # last pass's Q panels
-            for t in A.local_tiles():
-                A.data_of(*t).overwrite_on(
-                    dev.space, jax.device_put(gen(key[t]), dev.jdev))
-        setup_s, run_s = _run_passes(
-            ctx, passes, t0, stage, lambda: qr_taskpool(A, device="tpu"), A)
-
-        t1 = time.perf_counter()
-        res = factorization_residual(
-            A, lambda m, k: gen(key[(m, k)]).astype(jnp.float32))
-        check_s = time.perf_counter() - t1
-        devices = _device_report(ctx, A)
-        tiles.discard_tiles(A)
-        tiles.discard_scratch(ctx)
-    del A
-    gc.collect()
-    out = {"phase": "geqrf" if chain else "geqrf_per_kernel",
-           "mb": mb, "nt": nt, "n": n, "ib": ib_used,
-           "storage": storage, "mca": mca,
-           "setup_s": round(setup_s, 3),
-           "run_s": [round(t, 3) for t in run_s],
-           "check_s": round(check_s, 3), "factorization_residual": res,
-           "devices": devices}
-    if ib_used != ib:
-        raise SmokeFailure(f"geqrf: asked for ib={ib}, the panel engine "
-                           f"ran ib={ib_used}")
-    if not res <= GEQRF_TOL:
-        raise SmokeFailure(f"geqrf: factorization residual {res:.3e} > "
-                           f"{GEQRF_TOL}: {out}")
-    _require_healthy("geqrf", devices, every_device=False)
-    return out
+def run_geqrf(mb: int, nt: int, ib: int = 512, seed: int = 0,
+              storage=None, passes: int = 2) -> dict:
+    """Tiled QR of n = nt*mb: the ``dplasma_geqrf_bf16`` job (general
+    random operand, ib-blocked panel engine, default chain fusion), R
+    held to R^T R = A^T A on a probe and to zeros under the diagonal."""
+    try:
+        return _run_job("geqrf", geqrf_app.Job,
+                        _config("dplasma_geqrf_bf16", storage),
+                        {"n": nt * mb, "mb": mb, "ib": ib}, seed, passes,
+                        0, False)
+    except ValueError as exc:       # an ib the panel engine would clamp
+        raise SmokeFailure(str(exc)) from exc
 
 
 # ---------------------------------------------------------------------------
@@ -540,14 +454,12 @@ def main(argv=None) -> int:
                     raise SmokeFailure(
                         f"potrf: launch fusion never engaged at nt="
                         f"{potrf_size['nt']}: {st}")
-                geqrf = run_geqrf(mb=6144, nt=GEQRF_NT, seed=args.seed)
+                geqrf = run_geqrf(mb=6144, nt=8, seed=args.seed)
                 emit(geqrf)
                 if not geqrf["devices"][0]["stats"]["chained_launches"]:
                     raise SmokeFailure(
                         f"geqrf: chain fusion never engaged: "
                         f"{geqrf['devices'][0]['stats']}")
-                emit(run_geqrf(mb=6144, nt=8, seed=args.seed,
-                               mca=GEQRF_PER_KERNEL))
     except SmokeFailure as exc:
         log(f"chip_smoke: FAILED: {exc}")
         return 1

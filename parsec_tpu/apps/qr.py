@@ -63,9 +63,27 @@ enter the data LINEARLY, like TSMQR — run at DEFAULT precision:
 
 Knobs: --mca qr_ib N (0 = unblocked; ignored unless 0 < ib < mb and
 ib | mb) and --mca qr_update_precision {default,highest} for the
-intra-panel trailing updates.  Per-block Cholesky failures fall back
-to the unblocked construction (which carries its own Householder-QR
-guard), keeping LAPACK-class robustness behind the fast path.
+intra-panel trailing updates.
+
+ONE TRACED BODY (PR 31): the mb / ib column blocks run as a
+``lax.fori_loop``, so the ib-sized Choleskys, triangular inverses and
+WY assembly — what the TPU compiler is slow on — compile once a kernel
+and not once a block.  What follows the block's place in the panel
+(re-projection / T-accumulation against the blocks before it, the
+trailing update of the blocks after it) runs as inner loops over those
+blocks, one ib-wide column block a turn: the same flop as the
+full-extent products of an unrolled panel, no flop spent on padding,
+and the loop carries, kept as stacks of column blocks
+(``_col_blocks``), are updated in place (a ``lax.switch`` over
+full-extent branches copied them at every turn: 22 of TSQRT's 53 ms on
+a v5e, PERF.md PR 31).  A block whose Gram
+matrix is not positive definite in f32 takes shifted Cholesky-QR passes
+until the plain ones hold (``_cholqr_passes``, behind ``_cholqr2`` and
+``_gram_factor``): a ``lax.while_loop`` of one pass, so one more
+ib-sized Cholesky to compile whatever the block's condition; the
+blocked kernels carry no Householder fall-back (XLA's QR expander at
+panel size was two thirds of their compile time and never ran).  The UNBLOCKED construction
+(qr_ib 0, or an ib that does not block mb) keeps its Householder guard.
 """
 
 from __future__ import annotations
@@ -113,23 +131,185 @@ def _update_precision():
     return jax.lax.Precision.HIGHEST if val == "highest" else None
 
 
-def _cholqr2(cols, jnp, hi, gram=None):
-    """CholeskyQR2 of one mb x ib column block at HIGHEST precision:
-    returns (Q, R) with Q orthonormal (two Gram+Cholesky passes — one
-    pass loses orthogonality as cond^2*eps) and R = L2^T L^T upper
-    triangular.  NaNs from an ill-conditioned block propagate to the
-    caller's finiteness guard.  ``gram`` swaps the Gram products for a
-    hand-written kernel (apps/pallas_kernels.pallas_gram_tile)."""
-    gram = gram or (lambda X: jnp.matmul(X.T, X, precision=hi))
-    G = gram(cols)
+def _shift(m: int, n: int) -> float:
+    """Diagonal shift of the stable Cholesky-QR pass for an m x n block
+    whose Gram matrix is scaled to unit diagonal: 11 (m + n + 1) u, the
+    shifted-CholeskyQR bound (Fukaya et al., SISC 2020) taken a column
+    — enough for the factor to exist whatever the block's condition,
+    small enough that the passes after it reach working accuracy."""
+    return 11.0 * (m + n + 1) * float(np.finfo(np.float32).eps) / 2.0
+
+
+def _scaled_chol(G, jnp):
+    """Cholesky of ``G`` scaled to unit diagonal (Jacobi equilibration
+    keeps a decaying R's dynamic range out of the factor): (Ls, Gs, dg)
+    with G = (dg Ls)(dg Ls)^T; NaN where G is not positive definite in
+    f32 (cond of the block beyond ~1/sqrt(eps))."""
     dg = jnp.sqrt(jnp.clip(jnp.diagonal(G), 1e-30, None))
-    L = jnp.linalg.cholesky(G / dg[:, None] / dg[None, :]) * dg[:, None]
-    Q1 = jnp.matmul(cols, tri_inv(L, precision=hi).T, precision=hi)
-    G2 = gram(Q1)
-    L2 = jnp.linalg.cholesky(G2)
-    Q = jnp.matmul(Q1, tri_inv(L2, precision=hi).T, precision=hi)
-    R = jnp.matmul(L2.T, L.T, precision=hi)
+    Gs = G / dg[:, None] / dg[None, :]
+    return jnp.linalg.cholesky(Gs), Gs, dg
+
+
+_MAX_PASSES = 6     # Cholesky-QR passes a block takes at most
+
+
+def _cholqr_passes(blocks, jnp, hi, gram):
+    """Cholesky-QR passes over the stacked block ``[blocks...]`` (a
+    tuple of row blocks with the same columns) at HIGHEST precision,
+    until it is orthonormal: (Q blocks, R) with R upper triangular and
+    [blocks...] = [Q blocks...] R.
+
+    One pass: G = gram(X), G = L L^T, X <- X L^-T, R <- L^T R.  Where G
+    is not positive definite in f32 the pass factors the SHIFTED Gram
+    matrix instead (shifted Cholesky-QR): L still folds into R exactly,
+    and X's condition drops by ~sqrt(shift) a pass, so a block of any
+    condition comes down to where the plain passes hold.  The last pass
+    is the one whose input is already near orthonormal (||G - I||_F
+    < 1/2: its cond^2 is under 3, so what it leaves is orthonormal to
+    working accuracy).  A well-conditioned block takes two passes
+    (CholeskyQR2), a square Gaussian ib x ib block (the last of every
+    GEQRT panel; cond over 1/sqrt(eps) one time in four) three or
+    four, and ``_MAX_PASSES`` bounds a rank-deficient one, whose R is
+    still a factor of its Gram matrix to the shift's accuracy.  One
+    traced body whatever the block: an ib-sized Cholesky, its shifted
+    twin behind a ``lax.cond``, one triangular inverse."""
+    from jax import lax
+    mm = lambda a, b: jnp.matmul(a, b, precision=hi)
+    n = blocks[0].shape[1]
+    eye = jnp.eye(n, dtype=blocks[0].dtype)
+    shift = _shift(sum(b.shape[0] for b in blocks), n)
+
+    def one(state):
+        X, R, _last, it = state
+        G = gram(X)
+        Ls, Gs, dg = _scaled_chol(G, jnp)
+        Ls = lax.cond(jnp.all(jnp.isfinite(Ls)), lambda _: Ls,
+                      lambda _: jnp.linalg.cholesky(Gs + shift * eye), None)
+        L = Ls * dg[:, None]
+        W = tri_inv(L, precision=hi).T
+        return (tuple(mm(x, W) for x in X), mm(L.T, R),
+                jnp.sum((G - eye) ** 2) < 0.25, it + 1)
+
+    X, R, _last, _it = lax.while_loop(
+        lambda st: jnp.logical_and(~st[2], st[3] < _MAX_PASSES), one,
+        (tuple(blocks), eye, jnp.bool_(False), jnp.int32(0)))
+    return X, R
+
+
+def _cholqr2(cols, jnp, hi, gram=None):
+    """Cholesky-QR of one mb x ib column block at HIGHEST precision:
+    returns (Q, R) with Q orthonormal and R upper triangular
+    (``_cholqr_passes``: two passes on a well-conditioned block — one
+    loses orthogonality as cond^2*eps — shifted ones first on a block
+    whose Gram matrix is not positive definite in f32).  ``gram`` swaps
+    the Gram products for a hand-written kernel
+    (apps/pallas_kernels.pallas_gram_tile)."""
+    gram = gram or (lambda X: jnp.matmul(X.T, X, precision=hi))
+    (Q,), R = _cholqr_passes((cols,), jnp, hi, lambda X: gram(X[0]))
     return Q, R
+
+
+def _gram_factor(top, bot, jnp, hi):
+    """Lower-triangular L with L L^T = top^T top + bot^T bot, the Gram
+    matrix of the stacked block [top; bot], at HIGHEST precision: its
+    scaled Cholesky factor, or — where that is not positive definite in
+    f32 — the triangular factor of ``_cholqr_passes`` over the stacked
+    block: the same factor a Householder QR of it gives, from matmuls
+    and ib-sized Choleskys alone."""
+    from jax import lax
+    mm = lambda a, b: jnp.matmul(a, b, precision=hi)
+    gram = lambda X: mm(X[0].T, X[0]) + mm(X[1].T, X[1])
+    Ls, _Gs, dg = _scaled_chol(gram((top, bot)), jnp)
+    return lax.cond(
+        jnp.all(jnp.isfinite(Ls)), lambda _: Ls * dg[:, None],
+        lambda _: _cholqr_passes((top, bot), jnp, hi, gram)[1].T, None)
+
+
+def _col_blocks(M, ib):
+    """(mb, n) -> (n / ib, mb, ib): the matrix as a stack of its ib-wide
+    column blocks.  A panel loop reads and rewrites one column block a
+    turn; as a slab of a row-major array that is a strided update the
+    compiler runs as an op of its own (80 us for 12.6 MB on a v5e, 5 ms
+    a panel kernel), as an index of the leading axis it is contiguous
+    and fused in place."""
+    mb, n = M.shape
+    return M.reshape(mb, n // ib, ib).transpose(1, 0, 2)
+
+
+def _from_col_blocks(Mb):
+    nblk, mb, ib = Mb.shape
+    return Mb.transpose(1, 0, 2).reshape(mb, nblk * ib)
+
+
+def _blk(Mb, k):
+    """Column block ``k`` of a stack of column blocks."""
+    from jax import lax
+    return lax.dynamic_index_in_dim(Mb, k, 0, keepdims=False)
+
+
+def _put(Mb, X, k):
+    """The stack with column block ``k`` replaced by ``X``."""
+    from jax import lax
+    return lax.dynamic_update_index_in_dim(Mb, X, k, 0)
+
+
+def _geqrt_blocked(Tf, ib, jnp, hi, up, gram=None):
+    """Inner-blocked GEQRT (module docstring): per-block CholeskyQR2 +
+    one re-projection against the accumulated basis at HIGHEST
+    (O(mb^2*ib) total), trailing columns updated at ``up`` precision
+    (errors enter linearly).  Q comes out explicit — the blocks ARE its
+    orthonormal columns.  Returns (R, Q)."""
+    from jax import lax
+    mb = Tf.shape[0]
+    nblk = mb // ib
+    mm = lambda a, b, p: jnp.matmul(a, b, precision=p)
+    blk, put = _blk, _put
+
+    def step(j, carry):
+        A, R, Q = carry                 # A, Q as stacks of column blocks
+        s = j * ib
+        cols = blk(A, j)
+
+        # BCGS2-flavored reorthogonalization against the blocks before
+        # this one: the trailing updates already projected it, but
+        # rounding reintroduces ~eps*cond components; one extra HIGHEST
+        # pass restores inter-block orthogonality.  The coefficients
+        # fold into R exactly.
+        def coeff(k, prj):
+            return lax.dynamic_update_slice(
+                prj, mm(blk(Q, k).T, cols, hi), (k * ib, 0))
+        prj = lax.fori_loop(0, j, coeff, jnp.zeros((mb, ib), jnp.float32))
+
+        def project(k, c):
+            return c - mm(blk(Q, k), lax.dynamic_slice(
+                prj, (k * ib, 0), (ib, ib)), hi)
+        Qj, Rjj = _cholqr2(lax.fori_loop(0, j, project, cols), jnp, hi,
+                           gram=gram)
+
+        # the blocks after this one: A_k -= Qj (Qj^T A_k), the
+        # coefficients are R[J, K]
+        def trailing(k, AR):
+            A, Rrow = AR
+            Ak = blk(A, k)
+            Rjk = mm(Qj.T, Ak, up)
+            return (put(A, Ak - mm(Qj, Rjk, up), k),
+                    lax.dynamic_update_slice(Rrow, Rjk, (0, k * ib)))
+        A, Rrow = lax.fori_loop(j + 1, nblk, trailing,
+                                (A, jnp.zeros((ib, mb), jnp.float32)))
+        # R's column block takes the projection coefficients on top of
+        # what the earlier blocks' trailing updates left there, its row
+        # block the diagonal factor and this block's coefficients
+        R = lax.dynamic_update_slice(
+            R, lax.dynamic_slice(R, (0, s), (mb, ib)) + prj, (0, s))
+        R = lax.dynamic_update_slice(
+            R, lax.dynamic_update_slice(Rrow, Rjj, (0, s)), (s, 0))
+        return A, R, put(Q, Qj, j)
+
+    zeros = jnp.zeros((mb, mb), jnp.float32)
+    _A, R, Q = lax.fori_loop(
+        0, nblk, step, (_col_blocks(Tf, ib), zeros,
+                        jnp.zeros((nblk, mb, ib), jnp.float32)))
+    return R, _from_col_blocks(Q)
 
 
 def _k(name, maker):
@@ -161,50 +341,14 @@ def _mk_geqrt(ib: int = 0, gram=None):
         Tf = T.astype(jnp.float32)
         mb = Tf.shape[0]
 
-        def stable(_):
-            return jnp.linalg.qr(Tf, mode="reduced")[::-1]
-
         if 0 < ib < mb and mb % ib == 0:
-            # inner-blocked panel (module docstring): per-block
-            # CholeskyQR2 + one re-projection against the accumulated
-            # basis at HIGHEST (O(mb^2*ib) total), trailing columns
-            # updated at DEFAULT (errors enter linearly).  Q comes out
-            # explicit — the blocks ARE its orthonormal columns — so
-            # the q1 edge and UNMQR are unchanged.
-            up = _update_precision()
-            A = Tf
-            R = jnp.zeros((mb, mb), jnp.float32)
-            Qacc = None
-            for s in range(0, mb, ib):
-                cols = A[:, s:s + ib]
-                if Qacc is not None:
-                    # BCGS2-flavored reorthogonalization: the trailing
-                    # updates already projected this block, but rounding
-                    # reintroduces ~eps*cond components; one extra
-                    # HIGHEST-precision pass restores inter-block
-                    # orthogonality.  The coefficients fold into R
-                    # exactly.
-                    prj = jnp.matmul(Qacc.T, cols, precision=hi)
-                    cols = cols - jnp.matmul(Qacc, prj, precision=hi)
-                    R = R.at[:s, s:s + ib].add(prj)
-                Qj, Rjj = _cholqr2(cols, jnp, hi, gram=gram)
-                R = R.at[s:s + ib, s:s + ib].set(Rjj)
-                if s + ib < mb:
-                    rest = A[:, s + ib:]
-                    Rjk = jnp.matmul(Qj.T, rest, precision=up)
-                    A = A.at[:, s + ib:].set(
-                        rest - jnp.matmul(Qj, Rjk, precision=up))
-                    R = R.at[s:s + ib, s + ib:].set(Rjk)
-                Qacc = Qj if Qacc is None else \
-                    jnp.concatenate([Qacc, Qj], axis=1)
-            ok = jnp.logical_and(jnp.all(jnp.isfinite(R)),
-                                 jnp.all(jnp.isfinite(Qacc)))
-            R, Qm = lax.cond(ok, lambda o: o, stable, operand=(R, Qacc))
+            # inner-blocked panel: the blocks run as one traced body; an
+            # ill-conditioned block takes shifted passes (_cholqr_passes)
+            R, Qm = _geqrt_blocked(Tf, ib, jnp, hi, _update_precision(),
+                                   gram=gram)
             return {"T": R.astype(T.dtype), "Q": Qm.astype(T.dtype)}
 
-        G = jnp.matmul(Tf.T, Tf, precision=hi)
-        dg = jnp.sqrt(jnp.clip(jnp.diagonal(G), 1e-30, None))
-        Ls = jnp.linalg.cholesky(G / dg[:, None] / dg[None, :])
+        Ls, _Gs, dg = _scaled_chol(jnp.matmul(Tf.T, Tf, precision=hi), jnp)
         L = Ls * dg[:, None]
         # CholeskyQR2: one Cholesky-QR pass loses orthogonality as
         # cond^2*eps — tiles with cond in ~1e2..3e3 pass the finite
@@ -223,6 +367,9 @@ def _mk_geqrt(ib: int = 0, gram=None):
             Qm = jnp.matmul(Q1, tri_inv(L2, precision=hi).T,
                             precision=hi)
             return R, Qm
+
+        def stable(_):
+            return jnp.linalg.qr(Tf, mode="reduced")[::-1]
 
         ok = jnp.logical_and(jnp.all(jnp.isfinite(L)),
                              jnp.all(jnp.isfinite(L2)))
@@ -276,51 +423,67 @@ def _tsqrt_wy(R, B, xp, chol, ti):
 
 def _tsqrt_blocked(T, B, ib, jnp, hi, up):
     """Inner-blocked TSQRT construction (module docstring): returns the
-    panel-wide (R', V, T^T) with T^T block lower triangular.  HIGHEST
-    work is O(mb^2*ib); the trailing updates of [R; B] run at ``up``
-    precision.  NaNs from an ill-conditioned block propagate to the
-    caller's finiteness guard."""
+    panel-wide (R', V, T^T) with T^T block lower triangular.  The
+    per-block Gram factor and WY assembly run at HIGHEST; the trailing
+    updates of [R; B] run at ``up`` precision.  An ill-conditioned
+    block takes ``_gram_factor``'s shifted passes."""
+    from jax import lax
     mb = T.shape[0]
-    Rc, Bc = T, B
-    V = jnp.zeros((mb, mb), jnp.float32)
-    Tt = jnp.zeros((mb, mb), jnp.float32)
-    for s in range(0, mb, ib):
-        Rjj = Rc[s:s + ib, s:s + ib]
-        Bj = Bc[:, s:s + ib]
-        G = (jnp.matmul(Rjj.T, Rjj, precision=hi)
-             + jnp.matmul(Bj.T, Bj, precision=hi))
-        dg = jnp.sqrt(jnp.clip(jnp.diagonal(G), 1e-30, None))
-        L = jnp.linalg.cholesky(G / dg[:, None] / dg[None, :]) \
-            * dg[:, None]
-        Rpjj, Vj, Tjt = _wy_from_L(Rjj, Bj, L, jnp,
-                                   lambda M: tri_inv(M, precision=hi),
-                                   precision=hi)
-        Rc = Rc.at[s:s + ib, s:s + ib].set(Rpjj)
-        if s + ib < mb:
-            # 5-matmul WY application to the trailing columns of the
-            # stacked panel (same shape as TSMQR, errors enter linearly)
-            C1 = Rc[s:s + ib, s + ib:]
-            C2 = Bc[:, s + ib:]
-            Z = jnp.matmul(Tjt,
-                           C1 + jnp.matmul(Vj.T, C2, precision=up),
-                           precision=up)
-            Rc = Rc.at[s:s + ib, s + ib:].set(C1 - Z)
-            Bc = Bc.at[:, s + ib:].set(
-                C2 - jnp.matmul(Vj, Z, precision=up))
-        if s:
-            # T-accumulation: Q^T = Q_j^T Q_prev^T collapses to one
-            # compact-WY pair with the block-lower-triangular
-            # T^T[J, :s] = -T_j^T (W_j^T W_prev) T^T[:s, :s]; the unit
-            # tops of W are disjoint identity columns, so W_j^T W_prev
-            # = V_j^T V[:, :s]
-            cross = jnp.matmul(Vj.T, V[:, :s], precision=hi)
-            Tt = Tt.at[s:s + ib, :s].set(
-                -jnp.matmul(Tjt, jnp.matmul(cross, Tt[:s, :s],
-                                            precision=hi),
-                            precision=hi))
-        V = V.at[:, s:s + ib].set(Vj)
-        Tt = Tt.at[s:s + ib, s:s + ib].set(Tjt)
-    return Rc, V, Tt
+    nblk = mb // ib
+    mm = lambda a, b, p: jnp.matmul(a, b, precision=p)
+    ti = lambda M: tri_inv(M, precision=hi)
+    # B and V as stacks of column blocks (_col_blocks), R's row block
+    # and the accumulation's ib x mb rows as they are
+    blk, put = _blk, _put
+    col = lambda M, k: lax.dynamic_slice(M, (0, k * ib), (ib, ib))
+    putcol = lambda M, X, k: lax.dynamic_update_slice(M, X, (0, k * ib))
+
+    def step(j, carry):
+        Rc, Bc, V, Tt = carry
+        s = j * ib
+        Rrow = lax.dynamic_slice(Rc, (s, 0), (ib, mb))
+        Rjj, Bj = col(Rrow, j), blk(Bc, j)
+        L = _gram_factor(Rjj, Bj, jnp, hi)
+        Rpjj, Vj, Tjt = _wy_from_L(Rjj, Bj, L, jnp, ti, precision=hi)
+
+        # 5-matmul WY application to the blocks after this one of the
+        # stacked panel (same shape as TSMQR, errors enter linearly)
+        def trailing(k, RB):
+            Rrow, Bc = RB
+            C1, C2 = col(Rrow, k), blk(Bc, k)
+            Z = mm(Tjt, C1 + mm(Vj.T, C2, up), up)
+            return putcol(Rrow, C1 - Z, k), put(Bc, C2 - mm(Vj, Z, up), k)
+        Rrow, Bc = lax.fori_loop(j + 1, nblk, trailing, (Rrow, Bc))
+
+        # T-accumulation: Q^T = Q_j^T Q_prev^T collapses to one
+        # compact-WY pair with the block-lower-triangular
+        # T^T[J, :s] = -T_j^T (W_j^T W_prev) T^T[:s, :s]; the unit tops
+        # of W are disjoint identity columns, so W_j^T W_prev
+        # = V_j^T V[:, :s], taken a block at a time.  V takes V_j
+        # BEFORE the blocks before it are read: one live version of the
+        # carry (read first, the compiler kept two and copied 151 MB
+        # twice a block, 11 of 48 ms on a v5e)
+        V = put(V, Vj, j)
+
+        def cross(k, X):
+            return putcol(X, mm(Vj.T, blk(V, k), hi), k)
+        zrow = jnp.zeros((ib, mb), jnp.float32)
+        X = lax.fori_loop(0, j, cross, zrow)
+
+        def times_tt(k, Y):
+            return Y + mm(col(X, k), lax.dynamic_slice(
+                Tt, (k * ib, 0), (ib, mb)), hi)
+        Trow = -mm(Tjt, lax.fori_loop(0, j, times_tt, zrow), hi)
+
+        Rc = lax.dynamic_update_slice(Rc, putcol(Rrow, Rpjj, j), (s, 0))
+        Tt = lax.dynamic_update_slice(Tt, putcol(Trow, Tjt, j), (s, 0))
+        return Rc, Bc, V, Tt
+
+    Rc, _Bc, V, Tt = lax.fori_loop(
+        0, nblk, step, (T, _col_blocks(B, ib),
+                        jnp.zeros((nblk, mb, ib), jnp.float32),
+                        jnp.zeros((mb, mb), jnp.float32)))
+    return Rc, _from_col_blocks(V), Tt
 
 
 def _mk_tsqrt(ib: int = 0):
@@ -350,12 +513,11 @@ def _mk_tsqrt(ib: int = 0):
         hi = jax.lax.Precision.HIGHEST
         mb = T.shape[0]
 
-        def unblocked(_):
-            G = (jnp.matmul(T.T, T, precision=hi)
-                 + jnp.matmul(B.T, B, precision=hi))
-            dg = jnp.sqrt(jnp.clip(jnp.diagonal(G), 1e-30, None))
-            L = jnp.linalg.cholesky(G / dg[:, None] / dg[None, :]) \
-                * dg[:, None]
+        def unblocked():
+            Ls, _Gs, dg = _scaled_chol(
+                jnp.matmul(T.T, T, precision=hi)
+                + jnp.matmul(B.T, B, precision=hi), jnp)
+            L = Ls * dg[:, None]
 
             def stable_L(_):
                 Rh = jnp.linalg.qr(jnp.concatenate([T, B], axis=0),
@@ -371,15 +533,10 @@ def _mk_tsqrt(ib: int = 0):
                               precision=hi)
 
         if 0 < ib < mb and mb % ib == 0:
-            # inner-blocked fast path; an ill-conditioned BLOCK (NaN
-            # anywhere in the result) falls back to the unblocked
-            # construction, which carries its own Householder-QR guard
-            res = _tsqrt_blocked(T, B, ib, jnp, hi, _update_precision())
-            ok = jnp.all(jnp.array([jnp.all(jnp.isfinite(x))
-                                    for x in res]))
-            Rp, V, Tt = lax.cond(ok, lambda o: o, unblocked, operand=res)
+            Rp, V, Tt = _tsqrt_blocked(T, B, ib, jnp, hi,
+                                       _update_precision())
         else:
-            Rp, V, Tt = unblocked(None)
+            Rp, V, Tt = unblocked()
         dt = Q.dtype                    # NEW-flow arena dtype = storage
         return {"T": Rp.astype(dt), "B": jnp.zeros_like(B, dtype=dt),
                 "Q": jnp.concatenate([V, Tt], axis=0).astype(dt)}
@@ -613,10 +770,15 @@ def qr_taskpool(A: TiledMatrix, device: str = "tpu") -> ParameterizedTaskpool:
     # cross-panel fused dispatch (devices/xla.py chain fusion): the
     # GEQRT(k) -> TSQRT(k+1,k) -> ... -> TSQRT(NT-1,k) column is the
     # serial spine of the DAG — each link's only missing input is its
-    # predecessor's T, so the device layer holds the head and traces the
-    # whole column INTO its consumers' launch (one dispatch round trip
-    # instead of one per link).  TSQRT co-locates on the diagonal tile's
-    # device so the column is a chain on ONE device.
+    # predecessor's T.  Both classes name TSQRT as the successor on T, so
+    # the device layer takes the column two links at a time: a head is
+    # held and traced INTO the launch of the link after it (one dispatch
+    # round trip for the pair), and the UNMQR / TSMQR waves that read a
+    # held head's Q follow that launch.  Four programs hold a panel
+    # kernel whatever NT is: jit_parsec_GEQRT, jit_parsec_TSQRT,
+    # jit_parsec_chain_GEQRT__TSQRT_x1, jit_parsec_chain_TSQRT__TSQRT_x1.
+    # TSQRT co-locates on the diagonal tile's device so the column is a
+    # chain on ONE device.
     tp.task_classes["GEQRT"].properties["fuse_chain"] = ("T", "TSQRT")
     tp.task_classes["TSQRT"].properties["fuse_chain"] = ("T", "TSQRT")
     tp.task_classes["TSQRT"].properties["coaffinity"] = \

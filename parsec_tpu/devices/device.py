@@ -26,7 +26,8 @@ class DeviceStats:
 
     __slots__ = ("executed_tasks", "bytes_in", "bytes_out", "faults",
                  "evictions", "fused_launches", "fused_tasks",
-                 "chained_launches", "chained_tasks", "launches",
+                 "chained_launches", "chained_tasks", "chain_programs",
+                 "launches",
                  "held_tasks", "defused_waves", "starved_waits",
                  "inflight_waits", "compiles", "warm_waits",
                  "release_passes",
@@ -48,6 +49,11 @@ class DeviceStats:
         #: wave) rode them (devices/xla.py device_fuse_panel)
         self.chained_launches = 0
         self.chained_tasks = 0
+        #: chain programs this device built: a (head, successor wave)
+        #: structure no device of the process had asked for before,
+        #: traced, compiled and called here.  Bounded by the taskpool's
+        #: classes and the fused widths, and 0 from a pool's second job
+        self.chain_programs = 0
         #: counts at the boundaries of the device module's spans
         #: (devices/xla.py, PERF.md section 3): jitted calls; chain heads
         #: parked without a dispatch (executed_tasks + held_tasks is

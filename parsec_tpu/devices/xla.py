@@ -92,14 +92,15 @@ params.register("device_fuse", 8,
                 "1 disables)")
 params.register("device_fuse_panel", 1,
                 "cross-panel chain fusion: a task class carrying a "
-                "'fuse_chain' property (POTRF->TRSM, GEQRT/TSQRT->TSQRT) "
-                "is HELD at dispatch — its outputs become deferred "
-                "placeholders, its deps release eagerly as usual — and "
-                "its kernel is traced INTO the consumer wave's XLA "
-                "program, so the factorization panel chain costs ONE "
-                "dispatch instead of one per link plus the Python "
-                "scheduling latency between them.  0 restores the "
-                "per-kernel panel path (the A/B attribution knob)")
+                "'fuse_chain' property (flow, successor class: "
+                "POTRF->TRSM, GEQRT/TSQRT->TSQRT) is HELD at dispatch — "
+                "its outputs become deferred placeholders, its deps "
+                "release eagerly as usual — and its kernel is traced "
+                "INTO the launch of its declared successor, ONE head a "
+                "program, so a panel link costs no dispatch of its own "
+                "and the set of chain programs follows from the classes "
+                "and the fused widths alone.  0 restores the per-kernel "
+                "panel path (the A/B attribution knob)")
 params.register("device_fuse_donate", 1,
                 "allow input-buffer donation inside CHAINED launches "
                 "(device_fuse_panel programs).  Default ON since the "
@@ -542,7 +543,8 @@ def _program_name(jf) -> str:
 def _chain_name(node_specs, wave_spec, n: int) -> str:
     """``parsec_chain_<HEADS>__<CLS>_x<n>``: the held heads in launch
     order (a run of one class as ``<CLS><count>``), then the consumer
-    wave; ``parsec_chain_<HEADS>`` where a chain is forced alone."""
+    wave; ``parsec_chain_<HEADS>`` where no wave follows.  (The device
+    itself asks for one head and its successor wave: ``_run_chain``.)"""
     heads = []
     for sp in node_specs:
         if heads and heads[-1][0] == sp.cls:
@@ -579,7 +581,7 @@ class Deferred:
     into the first consuming launch (XlaDevice._dispatch_chained), which
     resolves ``array`` for every other consumer.  Foreign consumers (a
     CPU body, another device, the ICI layer) call :meth:`force`, which
-    dispatches the held chain on its owning device."""
+    dispatches the held head alone on its owning device."""
 
     __slots__ = ("hold", "flow", "_shape", "_dtype", "array")
 
@@ -615,10 +617,10 @@ class Deferred:
             return 0
 
     def force(self):
-        """Dispatch the held chain now (owning device) and return the
+        """Dispatch the held head now (owning device) and return the
         real array."""
         if self.array is None:
-            self.hold.device._force_deferred(self)
+            self.hold.device._force_hold(self.hold)
         return self.array
 
     def is_ready(self):
@@ -643,7 +645,17 @@ class _Hold:
     ``_chain_cv``."""
 
     __slots__ = ("device", "task", "spec", "flat", "outputs", "state",
-                 "seq")
+                 "seq", "succ", "deadline")
+
+
+#: how long a task of another class that reads a held head's output
+#: waits for the launch of the head's declared successor before it
+#: forces the head alone (s), counted only while the device has nothing
+#: else in hand: behind a busy chip the successor's other input may be
+#: seconds of queued work away, and waiting costs nothing.  The bound
+#: only keeps a successor that never comes (a CPU incarnation, another
+#: pool's cancellation) from parking its siblings for good
+_HOLD_PATIENCE_S = 0.25
 
 
 _chain_jit_lock = threading.Lock()
@@ -851,6 +863,10 @@ class XlaDevice(Device):
         self._mem_lock = threading.Condition()
 
         self._pending: deque = deque()
+        #: submitted tasks that read a head held for ANOTHER class's
+        #: launch (_awaits_successor): back in front of _pending when the
+        #: head resolves or its patience runs out; under _cond
+        self._parked: deque = deque()
         self._inflight: deque = deque()
         #: chain-held tasks (cross-panel fused dispatch): id(task) ->
         #: _Hold, resolved when a consumer launch traces them in
@@ -902,19 +918,21 @@ class XlaDevice(Device):
         import time as _time
         while True:
             with self._cond:
-                if not self._pending and not self._stop:
+                first = self._take_first_locked()
+                if first is None and not self._stop:
                     # one span an episode, however many wake-ups
                     self.stats.starved_waits += 1
                     with open_span(self.es, "mgr.starved", dev=self.name):
-                        while not self._pending and not self._stop:
+                        while first is None and not self._stop:
                             self._cond.wait(0.1)
-                if self._stop and not self._pending:
+                            first = self._take_first_locked()
+                if first is None:
                     return
                 seq = self._launch_seq = self._launch_seq + 1
                 launch = open_span(self.es, "mgr.launch", dev=self.name,
                                    seq=seq)
                 with open_span(self.es, "mgr.pop_wave"):
-                    batch = self._pop_wave_locked()
+                    batch = self._pop_wave_locked(first)
                 self._launching += 1
             try:
                 if launch is not SPAN_OFF:
@@ -950,29 +968,65 @@ class XlaDevice(Device):
                     self._launching -= 1
                     self._cond.notify_all()
 
-    def _pop_wave_locked(self):
-        """Pop the next task plus every queued same-class sibling it can
-        fuse with (same kernel spec, equal non-flow args, matching
-        payload shapes), up to ``device_fuse`` (wavefront launch fusion;
+    def _awaits_successor(self, task: Task) -> bool:
+        """Whether ``task`` reads the output of a head that is held here
+        for the launch of ANOTHER class (its declared successor) and has
+        not run out of patience: such a task waits its turn in
+        ``_parked``, so that which program a head goes out in does not
+        depend on which of its consumers reached the device first.
+        Caller holds ``_cond``."""
+        if not self._held or self._stop:
+            return False
+        name = task.task_class.name
+        import time as _time
+        now = _time.monotonic()
+        for copy in task.data.values():
+            p = copy.payload if copy is not None else None
+            if isinstance(p, Deferred) and p.array is None:
+                hd = p.hold
+                if hd.device is not self or hd.succ in (None, name) \
+                        or hd.state != "held":
+                    continue
+                if self._inflight or self._retire:
+                    hd.deadline = now + _HOLD_PATIENCE_S   # chip in work
+                if now < hd.deadline:
+                    return True
+        return False
+
+    def _take_first_locked(self):
+        """The next queued task that may launch now, or None: parked
+        tasks whose wait is over go back in front of the queue first,
+        in their old order, and tasks that have to wait for a held
+        head's successor are parked.  Caller holds ``_cond``."""
+        if self._parked:
+            self._pending.extendleft(reversed(self._parked))
+            self._parked.clear()
+        while self._pending:
+            item = self._pending.popleft()
+            if not self._awaits_successor(item[0]):
+                return item
+            self._parked.append(item)
+        return None
+
+    def _pop_wave_locked(self, first):
+        """``first`` plus every queued same-class sibling it can fuse
+        with (same kernel spec, equal non-flow args, matching payload
+        shapes), up to ``device_fuse`` (wavefront launch fusion;
         reference analog: the GPU manager draining its pending FIFO into
         the exec streams, device_cuda_module.c:2697 — here the drain
         fuses the wave into one XLA program).  Non-matching entries keep
         their queue order.  Caller holds ``_cond``."""
-        first = self._pending.popleft()
         limit = int(params.get("device_fuse", 8))
         if limit <= 1:
             return [first]
         task, spec, _load = first
-        if task.task_class.properties.get("fuse_chain") \
-                and not int(params.get("device_fuse_panel", 1)):
-            # the per-kernel panel path keeps a panel-chain link (POTRF,
-            # GEQRT, TSQRT) to itself: its queued siblings sit on OTHER
-            # panels' serial chains, and a wave of them costs the
+        if task.task_class.properties.get("fuse_chain"):
+            # a panel-chain link (POTRF, GEQRT, TSQRT) goes alone: its
+            # queued siblings sit on OTHER panels' serial chains, which
+            # links meet is timing, and a wave of them costs the
             # heaviest kernel's compile once more per width (seen on a
             # v5e: a two-wide TSQRT wave at mb=6144, ~250 s and a
-            # 180 MB executable, PERF.md PR 21).  With chain fusion on,
-            # the default, links meet in waves as they always did —
-            # whether they should is for a trace to say (ROADMAP S2)
+            # 180 MB executable, PERF.md PR 21)
             return [first]
         sig = self._fuse_sig(task, spec)
         if sig is None:
@@ -994,7 +1048,8 @@ class XlaDevice(Device):
                 scan_budget -= 1
                 cand = self._pending.popleft()
                 if cand[1] is spec and \
-                        self._fuse_sig(cand[0], spec) == sig:
+                        self._fuse_sig(cand[0], spec) == sig \
+                        and not self._awaits_successor(cand[0]):
                     batch.append(cand)
                 else:
                     rest.append(cand)
@@ -1148,12 +1203,15 @@ class XlaDevice(Device):
             # (with two managers the delta can hold the other's bytes)
             stage.end(bytes_in=self.stats.bytes_in - bytes0)
             stage = SPAN_OFF
-            if n == 1 and spec.writable \
+            chained = any(isinstance(a, Deferred) for a in flat)
+            if n == 1 and spec.writable and not chained \
                     and self._chain_eligible(batch[0][0], spec):
                 # chain head (POTRF(k), TSQRT(m,k)...): hold instead of
                 # dispatching — deps release eagerly through the normal
                 # completer path with Deferred payloads, and the kernel
-                # is traced into the consumer wave's launch
+                # is traced into its successor's launch.  A link that
+                # reads a held head is that launch, never a second hold:
+                # one head a program, whatever the column's length
                 self.stats.held_tasks += 1
                 self._hold_task(batch[0], flat, pinned_per[0],
                                 release_per[0], seq)
@@ -1161,8 +1219,9 @@ class XlaDevice(Device):
             # a panel-chain link (POTRF, GEQRT, TSQRT) is the class whose
             # every width is a Cholesky-class compile: not for cover()
             cover = not batch[0][0].task_class.properties.get("fuse_chain")
-            if any(isinstance(a, Deferred) for a in flat):
-                outs_per_task = self._dispatch_chained(spec, n, flat, cover)
+            if chained:
+                outs_per_task = self._dispatch_chained(
+                    spec, n, flat, cover, batch[0][0].task_class.name)
                 fused = False
             else:
                 fused, outs_per_task = self._dispatch_plain(spec, n, flat,
@@ -1334,6 +1393,12 @@ class XlaDevice(Device):
         h.spec = spec
         h.flat = list(flat)
         h.state = "held"
+        # (flow, successor class); a bare flow name takes any consumer
+        fc = task.task_class.properties["fuse_chain"]
+        h.succ = fc[1] if isinstance(fc, (tuple, list)) and len(fc) > 1 \
+            else None
+        import time as _time
+        h.deadline = _time.monotonic() + _HOLD_PATIENCE_S
         h.outputs = {}
         for fl in spec.writable:
             dc = task.data.get(fl)
@@ -1355,44 +1420,20 @@ class XlaDevice(Device):
             self._inflight.append(inf)
             self._cond.notify_all()
 
-    def _claim_chain(self, roots: List[Deferred]) -> List[_Hold]:
-        """Claim the transitive closure of held tasks the given
-        placeholders depend on, all-or-nothing (two concurrent claimers
-        can never wait on each other, so no deadlock): returns the
-        claimed holds in topological (creation) order, or [] once
-        everything resolved while waiting."""
-        while True:
-            with self._chain_cv:
-                need: List[_Hold] = []
-                seen = set()
-
-                def visit(d):
-                    if d.array is not None:
-                        return
-                    hd = d.hold
-                    if id(hd) in seen or hd.state == "resolved":
-                        return
-                    seen.add(id(hd))
-                    for a in hd.flat or ():
-                        if isinstance(a, Deferred):
-                            visit(a)
-                    need.append(hd)   # post-order = dependencies first
-
-                for d in roots:
-                    visit(d)
-                if not need:
-                    return []
-                if all(hd.state == "held" for hd in need):
-                    for hd in need:
-                        hd.state = "launching"
-                    return sorted(need, key=lambda hd: hd.seq)
-                # part of the chain is being launched by another thread:
-                # wait for its resolution, then recompute the closure
+    def _claim(self, hd: _Hold) -> bool:
+        """Take a held head for launching; False once it has resolved.
+        Waits while another thread is launching it (that launch either
+        resolves it or hands it back: ``_unclaim``)."""
+        with self._chain_cv:
+            while hd.state == "launching":
                 self._chain_cv.wait(0.1)
+            if hd.state == "resolved":
+                return False
+            hd.state = "launching"
+            return True
 
-    def _run_chain(self, claimed: List[_Hold], wave_spec=None, n=0,
-                   flat=None):
-        """Trace the claimed chain (and optional consumer wave) into ONE
+    def _run_chain(self, hd: _Hold, wave_spec, n, flat):
+        """Trace the claimed head and its successor wave into ONE
         jitted program and dispatch it.  A leaf is donated to XLA only
         when it feeds a WRITTEN flow position and appears exactly once
         in the whole program (the usage count is the chained analog of
@@ -1402,14 +1443,15 @@ class XlaDevice(Device):
         leaf_ix: Dict[int, int] = {}
         leaf_uses: Dict[int, int] = {}
         donatable: set = set()
-        node_ix = {id(hd): i for i, hd in enumerate(claimed)}
 
         def desc(a, writable=False):
             if isinstance(a, Deferred):
                 if a.array is not None:
                     a = a.array
                 else:
-                    return ("n", node_ix[id(a.hold)], a.flow)
+                    if a.hold is not hd:     # the caller's failure path
+                        raise KeyError("placeholder of another head")
+                    return ("n", 0, a.flow)
             if hasattr(a, "shape") and hasattr(a, "dtype"):
                 j = leaf_ix.get(id(a))
                 if j is None:
@@ -1426,13 +1468,10 @@ class XlaDevice(Device):
                   for a in sp.arg_names]
             return tuple(desc(a, wr[i]) for i, a in enumerate(args))
 
-        node_descs = [spec_descs(hd.spec, hd.flat) for hd in claimed]
-        wave_descs = ()
-        if wave_spec is not None and n:
-            k = len(wave_spec.arg_names)
-            wave_descs = tuple(
-                spec_descs(wave_spec, flat[t * k:(t + 1) * k])
-                for t in range(n))
+        node_descs = [spec_descs(hd.spec, hd.flat)]
+        k = len(wave_spec.arg_names)
+        wave_descs = tuple(spec_descs(wave_spec, flat[t * k:(t + 1) * k])
+                           for t in range(n))
         # REGRESSION GUARD (r8, the geqrf wrong-R flake): chained
         # launches donate NOTHING by default.  A/B under load +
         # delay_dispatch fault plans attributed the intermittent wrong
@@ -1445,88 +1484,106 @@ class XlaDevice(Device):
         donate = tuple(sorted(j for j in donatable
                               if leaf_uses.get(j) == 1)) \
             if self._chain_donate else ()
-        key = (tuple((hd.spec.fn, hd.spec.cls, d)
-                     for hd, d in zip(claimed, node_descs)),
-               (wave_spec.fn, wave_spec.cls) if wave_spec is not None
-               else None, wave_descs, donate)
+        key = (((hd.spec.fn, hd.spec.cls, node_descs[0]),),
+               (wave_spec.fn, wave_spec.cls), wave_descs, donate)
         hash(key)    # unhashable static -> the caller's failure path
-        jf = _chain_jitted(key, [hd.spec for hd in claimed], node_descs,
-                           wave_spec, wave_descs, donate)
-        node_outs, wave_outs = self._call(jf, leaves)
+        with _chain_jit_lock:
+            built = key not in _chain_jit_cache
+        jf = _chain_jitted(key, [hd.spec], node_descs, wave_spec,
+                           wave_descs, donate)
+        (head_outs,), wave_outs = self._call(jf, leaves)
+        self.stats.chain_programs += built
         self.stats.chained_launches += 1
-        self.stats.chained_tasks += len(claimed) + \
-            (n if wave_spec is not None else 0)
-        return node_outs, wave_outs
+        self.stats.chained_tasks += 1 + n
+        return head_outs, wave_outs
 
-    def _resolve_holds(self, claimed: List[_Hold], node_outs) -> None:
-        """Publish a dispatched chain's outputs: fill every Deferred and
+    def _run_alone(self, hd: _Hold) -> Dict[str, Any]:
+        """Dispatch a claimed head through its class's plain program
+        (``jit_parsec_<CLS>``, the one a link that is never held runs):
+        a head forced without its successor adds no program."""
+        flat = [a.array if isinstance(a, Deferred) else a for a in hd.flat]
+        donate = self._chain_donate \
+            and not self._donation_hazard(hd.spec, flat)
+        return hd.spec.bind_outputs(self._call(hd.spec.jitted(donate), flat))
+
+    def _resolve_hold(self, hd: _Hold, outs) -> None:
+        """Publish a dispatched head's outputs: fill every Deferred and
         swap the placeholder payloads for the real (asynchronous)
         arrays, then wake claim-waiters."""
         with self._chain_cv:
-            for hd, outs in zip(claimed, node_outs):
-                for fl, arr in outs.items():
-                    d = hd.outputs.get(fl)
-                    if d is not None:
-                        d.array = arr
-                    dc = hd.task.data.get(fl)
-                    # identity check, not isinstance: on an RW chain the
-                    # SAME copy carries successive holds' placeholders
-                    # (TSQRT column T), and resolving an earlier link
-                    # must not regress the payload over a later one
-                    if dc is not None and dc.payload is d:
-                        dc.payload = arr
-                hd.state = "resolved"
-                hd.flat = None          # release the leaf input buffers
-                self._held.pop(id(hd.task), None)
+            for fl, arr in outs.items():
+                d = hd.outputs.get(fl)
+                if d is not None:
+                    d.array = arr
+                dc = hd.task.data.get(fl)
+                # identity check, not isinstance: on an RW chain the
+                # SAME copy carries successive holds' placeholders
+                # (TSQRT column T), and resolving an earlier link
+                # must not regress the payload over a later one
+                if dc is not None and dc.payload is d:
+                    dc.payload = arr
+            hd.state = "resolved"
+            hd.flat = None          # release the leaf input buffers
+            self._held.pop(id(hd.task), None)
             self._chain_cv.notify_all()
+        with self._cond:
+            if self._parked:            # their wait is over
+                self._cond.notify_all()
 
-    def _unclaim(self, claimed: List[_Hold]) -> None:
+    def _unclaim(self, hd: _Hold) -> None:
         with self._chain_cv:
-            for hd in claimed:
-                if hd.state == "launching":
-                    hd.state = "held"
+            hd.state = "held"
             self._chain_cv.notify_all()
 
     def _dispatch_chained(self, spec: XlaKernel, n: int, flat: List[Any],
-                          cover: bool = False) -> List[Dict[str, Any]]:
-        """Launch a wave whose inputs include unresolved chain
-        placeholders: claim the chain, trace it in front of the wave in
-        one program, resolve the held tasks' outputs from the same
-        launch.  Returns the wave's bound outputs per task."""
+                          cover: bool, cls: str) -> List[Dict[str, Any]]:
+        """Launch a wave of class ``cls`` whose inputs include unresolved
+        chain placeholders.  ONE head held for this class is traced in
+        front of the wave in one program (``jit_parsec_chain_<HEAD>__
+        <cls>_x<n>``) and resolved from the same launch; every other
+        head the wave reads — held for another class whose launch did
+        not come in time, or a second one for this class — is forced
+        alone first.  So the chain programs a taskpool can ask for are
+        its (declaring class, declared successor, fused width) triples,
+        whatever its size and whoever arrived first.  Returns the
+        wave's bound outputs per task."""
         while True:
-            claimed = self._claim_chain(
-                [a for a in flat if isinstance(a, Deferred)
-                 and a.array is None])
-            # chains resolved while waiting substitute transparently
+            holds = {id(a.hold): a.hold for a in flat
+                     if isinstance(a, Deferred) and a.array is None}
+            mine = [hd for hd in holds.values() if hd.succ in (None, cls)]
+            head = min(mine, key=lambda hd: hd.seq) if mine else None
+            for hd in holds.values():
+                if hd is not head:
+                    self._force_hold(hd)
+            claimed = head is not None and self._claim(head)
+            # heads resolved meanwhile substitute transparently
             flat = [a.array if isinstance(a, Deferred)
                     and a.array is not None else a for a in flat]
             if not claimed:
                 if any(isinstance(a, Deferred) for a in flat):
-                    continue          # raced a fresh hold: re-claim
+                    continue          # raced a fresh hold: look again
                 _f, outs = self._dispatch_plain(spec, n, flat, cover)
                 return outs
             try:
-                node_outs, wave_outs = self._run_chain(claimed, spec, n,
-                                                       flat)
+                head_outs, wave_outs = self._run_chain(head, spec, n, flat)
             except Exception:
-                self._unclaim(claimed)
+                self._unclaim(head)
                 raise
-            self._resolve_holds(claimed, node_outs)
+            self._resolve_hold(head, head_outs)
             return wave_outs
 
-    def _force_deferred(self, d: Deferred) -> None:
-        """Dispatch the chain behind one placeholder without a consumer
-        wave (foreign-device/CPU consumers, sync, teardown)."""
-        while d.array is None:
-            claimed = self._claim_chain([d])
-            if not claimed:
-                continue              # resolved concurrently
-            try:
-                node_outs, _ = self._run_chain(claimed)
-            except Exception:
-                self._unclaim(claimed)
-                raise
-            self._resolve_holds(claimed, node_outs)
+    def _force_hold(self, hd: _Hold) -> None:
+        """Dispatch a held head alone, without its successor (a consumer
+        of another class whose patience ran out, foreign-device/CPU
+        consumers, sync, teardown)."""
+        if not self._claim(hd):
+            return                    # resolved concurrently
+        try:
+            outs = self._run_alone(hd)
+        except Exception:
+            self._unclaim(hd)
+            raise
+        self._resolve_hold(hd, outs)
 
     def _resolve_all_held(self) -> None:
         """Force every remaining hold (sync/teardown): consumers that
@@ -1541,8 +1598,7 @@ class XlaDevice(Device):
                 busy = any(hd.state == "launching"
                            for hd in self._held.values())
             if pending:
-                # newest first: its closure covers its predecessors
-                self._force_deferred(next(iter(pending[-1].outputs.values())))
+                self._force_hold(pending[0])
                 continue
             if not busy:
                 return
@@ -2027,7 +2083,8 @@ class XlaDevice(Device):
         unbounded, like a stream synchronize."""
         with self._cond:
             ok = self._cond.wait_for(
-                lambda: (not self._pending and self._launching == 0
+                lambda: (not self._pending and not self._parked
+                         and self._launching == 0
                          and self._completing == 0
                          and self._finalizing == 0
                          and not self._inflight) or self._stop,

@@ -773,6 +773,8 @@ class RuntimeMetrics:
                     ("chained_launches",
                      "parsec_device_chained_launches_total"),
                     ("chained_tasks", "parsec_device_chained_tasks_total"),
+                    ("chain_programs",
+                     "parsec_device_chain_programs_total"),
                     # the counts at the boundaries of the device
                     # module's thread-state spans (devices/device.py)
                     ("launches", "parsec_device_launches_total"),

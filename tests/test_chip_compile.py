@@ -8,13 +8,16 @@ time; nothing runs, so they say nothing about results or speed.
 Kept to about a minute on one worker.  Three panel kernels are too slow
 to compile at the full mb=6144 in a test and are compiled here at the
 largest mb that fits; the builder compiled them by hand at full width
-before the first chip run of PR 21, and the two Cholesky-class ones
-again for PR 28 (no symmetrization, TRSM in eight blocks an edge);
-seconds on this sandbox's CPU, compiles only:
+before the first chip run of PR 21, the two Cholesky-class ones again
+for PR 28 (no symmetrization, TRSM in eight blocks an edge) and the two
+QR ones for PR 31 (the ib blocks as one loop body with inner loops a
+column block at a time, no Householder fall-back: 131 -> 9.6 s and
+252 -> 14.4 s on the same day; 96 and 159 s at PR 21); seconds on this
+sandbox's CPU, compiles only:
 
     POTRF diagonal (cholesky + tri_inv), 6144 bf16      31 s   (here 2048)
-    GEQRT ib=512, 6144 bf16                              96 s   (here 1024)
-    TSQRT ib=512, 6144 bf16                             159 s   (here 1024)
+    GEQRT ib=512, 6144 bf16                             9.6 s   (here 2048)
+    TSQRT ib=512, 6144 bf16                            14.4 s   (here 2048)
     chained POTRF + 8-wide TRSM wave, 6144 bf16          29 s   (not here)
 
 PR 28's compiles of the last read 29 / 39 / 60 s and the parent's
@@ -151,17 +154,21 @@ def test_qr_tsmqr_6144_bf16(spec):
 
 
 @pytest.mark.parametrize("kernel", ["geqrt", "tsqrt"])
-def test_qr_panel_kernels_ib512_1024(spec, kernel):
-    """The inner-blocked panel engine (two ib=512 blocks); full width
-    compiled by hand (module docstring)."""
+def test_qr_panel_kernels_ib512_2048(spec, kernel):
+    """The inner-blocked panel engine (four ib=512 blocks: one loop
+    body, a branch a block for what follows the block's place); full
+    width compiled by hand (module docstring)."""
     import jax.numpy as jnp
     from parsec_tpu.apps import qr
-    mb, bf = 1024, jnp.bfloat16
+    mb, bf = 2048, jnp.bfloat16
     t = spec((mb, mb), bf)
     if kernel == "geqrt":
-        _compile(qr._mk_geqrt(512), t, t)
+        c = _compile(qr._mk_geqrt(512), t, t)
     else:
-        _compile(qr._mk_tsqrt(512), t, t, spec((2 * mb, mb), bf))
+        c = _compile(qr._mk_tsqrt(512), t, t, spec((2 * mb, mb), bf))
+    # one loop over the blocks, and no Householder expander behind it
+    hlo = c.as_text()
+    assert "while" in hlo and "householder" not in hlo.lower()
 
 
 def test_pallas_blocked_matmul_compiles_to_mosaic(spec):

@@ -137,19 +137,32 @@ def test_gemm_phase_sees_half_a_product(devices, monkeypatch):
                     passes=1)
 
 
-@pytest.mark.parametrize("nt, mca, phase", [
-    (cs.GEQRF_NT, None, "geqrf"),
-    (8, cs.GEQRF_PER_KERNEL, "geqrf_per_kernel")])
-def test_geqrf_phases_one_device(devices, nt, mca, phase):
-    """Both geqrf phases as main() runs them: the default path (chain
-    fusion on) at its cut nt, and the per-kernel panel path at nt=8."""
+@pytest.mark.parametrize("nt", [3, 8])
+def test_geqrf_phase_one_device(devices, nt):
+    """The geqrf phase as main() runs it, the benchmark's job through
+    the default path (chain fusion on), at the uncut nt = 8 too: two
+    chain programs whatever nt is."""
     devices(1)
-    out = cs.run_geqrf(mb=64, nt=nt, ib=16, seed=3, storage="float32",
-                       mca=mca)
+    out = cs.run_geqrf(mb=64, nt=nt, ib=16, seed=3, storage="float32")
     d = _one(out)
-    assert out["phase"] == phase and out["nt"] == nt
-    assert out["ib"] == 16 and out["factorization_residual"] < 1e-5
-    assert bool(d["stats"]["chained_launches"]) == (phase == "geqrf")
+    assert out["phase"] == "geqrf" and out["n"] == nt * 64
+    assert out["ib"] == 16
+    assert out["compared"]["factor_resid"]["value"] < 1e-5
+    assert out["compared"]["below_diag_max"]["value"] == 0.0
+    assert d["stats"]["chained_launches"] > 0
+    assert d["stats"]["chain_programs"] <= 2
+
+
+def test_geqrf_phase_sees_a_wrong_factor(devices, monkeypatch):
+    """TSMQR left out: the trailing matrix is never updated, and the
+    phase must fail on the configuration's own limit."""
+    from parsec_tpu.apps import qr
+    devices(1)
+    monkeypatch.setitem(qr._kernels, "tsmqr",
+                        lambda Q, C1, C2: {"C1": C1, "C2": C2})
+    with pytest.raises(cs.SmokeFailure, match="factor_resid"):
+        cs.run_geqrf(mb=64, nt=4, ib=16, seed=3, storage="float32",
+                     passes=1)
 
 
 def test_geqrf_phase_refuses_a_clamped_ib(devices):

@@ -323,11 +323,11 @@ def test_wavefront_fusion_batches_same_class_waves():
 @pytest.mark.parametrize("fuse_panel", [0, 1])
 def test_chain_links_go_alone_on_the_per_kernel_panel_path(fuse_panel):
     """The same 16-wide wave as above, but of a class that names a
-    fuse_chain (POTRF, GEQRT, TSQRT do).  With device_fuse_panel=0 every
-    link is dispatched alone: a wave of them costs the heaviest kernel's
-    compile once more per width (minutes for a two-wide TSQRT wave at
-    mb=6144 on a v5e).  With chain fusion on, the default, queued links
-    still meet in waves, as they always did."""
+    fuse_chain (POTRF, GEQRT, TSQRT do).  Every link is dispatched
+    alone, chain fusion on or off (PR 31: which links of OTHER panels'
+    chains meet in the queue is timing, and a wave of them costs the
+    heaviest kernel's compile once more per width — minutes for a
+    two-wide TSQRT wave at mb=6144 on a v5e)."""
     import time as _time
 
     from parsec_tpu.core.context import Context
@@ -358,10 +358,7 @@ def test_chain_links_go_alone_on_the_per_kernel_panel_path(fuse_panel):
             ctx.wait(timeout=120)
             st = ctx.device_registry.devices[1].stats
             assert st.executed_tasks == MT
-            if fuse_panel:
-                assert st.fused_launches >= 1 and st.fused_tasks >= 2
-            else:
-                assert st.fused_launches == 0 and st.fused_tasks == 0
+            assert st.fused_launches == 0 and st.fused_tasks == 0
     finally:
         params.unset("device_fuse")
         params.unset("device_max")
