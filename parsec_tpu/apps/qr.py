@@ -30,13 +30,38 @@ so the 2mb x 2mb orthogonal transform is Phi^T = I - [I;V] T^T [I;V]^T
 in the textbook T^T = S (R + V^T B)^-1 collapses to triangular ones via
 M = -S^-T R'^T S).  TSQRT is then one mb-sized Cholesky + two
 triangular inverses (recursive Newton, apps/potrf.tri_inv) + matmuls,
-and TSMQR is five mb^3-class matmuls:
+and TSMQR three mb^3-class products, 6 mb^3 flop for the 4 that LAPACK
+counts:
 
     Z = T^T (C1 + V^T C2);   C1 -= Z;   C2 -= V Z
 
-Everything lowers to the systolic array; the Q edges shrink from
-(2mb)^2 dense factors to the (2mb x mb) [V; T^T] pair.  R ends in the
-upper triangle; tiles below are zeroed.
+Everything lowers to the systolic array; R ends in the upper triangle;
+tiles below are zeroed.
+
+THE Q EDGE IN COLUMN GROUPS OF W (PR 32).  The reflector travels as mb /
+W compact-WY reflectors, one a group of W columns, Q^T = Q_last^T ...
+Q_0^T: arena ``q2`` is (mb + W, mb), V (mb x mb) over the W x mb strip
+of the groups' W x W factors T_G^T side by side — the diagonal blocks of
+what a panel-wide T^T would be; nothing outside them is computed or
+stored.  TSMQR applies the groups in turn, group 0 first,
+
+    Z = T_G^T (C1[G] + V_G^T C2);   C1[G] -= Z;   C2 -= V_G Z
+
+at 4 mb^3 + 2 W mb^2 flop: V^T C2 and V Z cost what they did, the
+product with T^T shrinks from mb wide to W wide (4.33 mb^3 at W = mb /
+6, against 6).  The running C2 stays in f32 across the groups of a task
+and rounds to the tiles' storage dtype once, on output; the operands go
+to the MXU in the storage dtype.  W is ``group_width(mb, ib)``, a
+function of the shapes alone, and TSQRT and TSMQR (device and CPU
+bodies alike) read it off the edge they are handed: narrow enough that
+T^T's product and TSQRT's accumulation (below) stay small, wide enough
+that the rank-W update of an f32 tile — W / 4 flop a byte read and
+written — does not fall under the chip's ridge (240 on a v5e): at mb =
+6144, ib = 512 the cell ran fastest at W = 1024, TSMQR alone 7.2 ms a
+task there against 10.3 at W = 512 and 8.8 panel-wide (PERF.md section
+6, PR 32: the table over W that fixed ``_GROUP_MIN``).  W = mb,
+one group, is the panel-wide factor and the (2 mb, mb) edge: what the
+unblocked construction emits, and a pool built for the CPU.
 
 INNER BLOCKING (ib; the DPLASMA dgeqrf panel discipline, r6): the
 panel CONSTRUCTION is cond^2-sensitive and must run at HIGHEST matmul
@@ -53,13 +78,16 @@ enter the data LINEARLY, like TSMQR — run at DEFAULT precision:
            before its own two Cholesky-QR passes), trailing columns
            updated at DEFAULT.
     TSQRT: per-block compact-WY from the ib x ib Gram of [R_jj; B_j],
-           trailing columns of [R; B] updated by the 5-matmul WY
-           application at DEFAULT, and the per-block (V_j, T_j^T)
-           pairs aggregated into ONE panel-wide (V, T^T) with the
-           standard T-accumulation
-               T^T[J, :s] = -T_j^T (V_j^T V[:, :s]) T^T[:s, :s]
-           (block lower triangular), so TSMQR's 5-matmul application
-           and the q2 edge layout are UNCHANGED.
+           trailing columns of [R; B] updated by the WY application at
+           DEFAULT, and the per-block (V_j, T_j^T) pairs of ONE GROUP
+           of W columns aggregated into the group's (V_G, T_G^T) with
+           the standard T-accumulation, against the blocks of the same
+           group alone (s0 = the group's first column)
+               T_G^T[J, s0:s] = -T_j^T (V_j^T V[:, s0:s]) T_G^T[s0:s, s0:s]
+           (block lower triangular; W-wide HIGHEST products, mb / ib *
+           (W / ib - 1) / 2 of each kind a panel: 6 + 6 at mb = 6144,
+           W = 1024, where a panel-wide T takes 66 + 66, mb wide;
+           DPLASMA's -i never forms one either).
 
 Knobs: --mca qr_ib N (0 = unblocked; ignored unless 0 < ib < mb and
 ib | mb) and --mca qr_update_precision {default,highest} for the
@@ -110,6 +138,10 @@ params.register("qr_update_precision", "default",
 
 _kernels = {}
 
+#: the group width W every traced blocked TSQRT took, keyed
+#: ("TSQRT", mb, ib): for tests and PERF.md, as apps/potrf.py's
+selected = {}
+
 
 def effective_ib(mb: int) -> int:
     """The inner blocking actually used for an mb-wide panel: the
@@ -122,6 +154,45 @@ def effective_ib(mb: int) -> int:
     if ib <= 0 or ib >= mb or mb % ib:
         return 0
     return ib
+
+
+#: narrowest group of reflector columns TSQRT accumulates and TSMQR
+#: applies at once: the width at which the cell ran fastest on a v5e at
+#: mb = 6144, ib = 512 (PERF.md section 6, PR 32: 90.9 TF/s at 1024,
+#: 89.6 at 1536, 84.6 at 2048, 75.8 at 512, 65.4 panel-wide)
+_GROUP_MIN = 1024
+
+
+def group_width(mb: int, ib: int) -> int:
+    """Columns W a group of the TSQRT -> TSMQR reflector holds, for a
+    device pool: the narrowest multiple of ib that divides mb and
+    reaches ``_GROUP_MIN``; mb (one group, the panel-wide factor) where
+    none does, or the panel is unblocked (ib 0)."""
+    if ib:
+        for W in range(ib, mb, ib):
+            if mb % W == 0 and W >= _GROUP_MIN:
+                return W
+    return mb
+
+
+def qr_executed_flops(cls: str, mb: int, ib: int, W: int) -> float:
+    """Flop one task of class ``cls`` executes on mb x mb tiles (the
+    device load-balancing weights; benchmark/reference/geqrf.py counts
+    the USEFUL flop), the matmul-class products alone.  TSMQR: V^T C2
+    and V Z whole, T^T a group at a time.  TSQRT, blocked: per block the
+    Gram matrix and V (4 mb ib^2), the trailing update of every block
+    after it (4 mb ib^2 a pair), the accumulation against the blocks of
+    its group before it (2 ib^2 (mb + W) a pair); unblocked: the two
+    Gram products, V and T^T."""
+    if cls == "TSMQR":
+        return 4.0 * mb ** 3 + 2.0 * W * mb ** 2
+    if cls == "TSQRT" and ib:
+        nblk, bpg = mb // ib, W // ib
+        return ib ** 2 * (4.0 * mb * nblk + 2.0 * mb * nblk * (nblk - 1)
+                          + (mb + W) * nblk * (bpg - 1.0))
+    whole = {"GEQRT": 2.0, "UNMQR": 2.0, "TSQRT": 8.0}
+    # the stores of a routed pool move a tile, no flops
+    return whole[cls] * mb ** 3 if cls in whole else 1.0
 
 
 def _update_precision():
@@ -421,19 +492,21 @@ def _tsqrt_wy(R, B, xp, chol, ti):
     return _wy_from_L(R, B, chol(G), xp, ti)
 
 
-def _tsqrt_blocked(T, B, ib, jnp, hi, up):
-    """Inner-blocked TSQRT construction (module docstring): returns the
-    panel-wide (R', V, T^T) with T^T block lower triangular.  The
-    per-block Gram factor and WY assembly run at HIGHEST; the trailing
-    updates of [R; B] run at ``up`` precision.  An ill-conditioned
-    block takes ``_gram_factor``'s shifted passes."""
+def _tsqrt_blocked(T, B, ib, W, jnp, hi, up):
+    """Inner-blocked TSQRT construction (module docstring): returns
+    (R', V, T^T) with T^T the W x mb strip of the grouped compact-WY
+    factor's mb / W diagonal blocks side by side, each block lower
+    triangular.  The per-block Gram factor and WY assembly run at
+    HIGHEST; the trailing updates of [R; B] run at ``up`` precision.
+    An ill-conditioned block takes ``_gram_factor``'s shifted passes."""
     from jax import lax
     mb = T.shape[0]
-    nblk = mb // ib
+    nblk, bpg = mb // ib, W // ib
     mm = lambda a, b, p: jnp.matmul(a, b, precision=p)
     ti = lambda M: tri_inv(M, precision=hi)
-    # B and V as stacks of column blocks (_col_blocks), R's row block
-    # and the accumulation's ib x mb rows as they are
+    # B and V as stacks of column blocks (_col_blocks), T^T as a stack
+    # of its groups' diagonal blocks, R's row block and the
+    # accumulation's ib x W rows as they are
     blk, put = _blk, _put
     col = lambda M, k: lax.dynamic_slice(M, (0, k * ib), (ib, ib))
     putcol = lambda M, X, k: lax.dynamic_update_slice(M, X, (0, k * ib))
@@ -446,8 +519,8 @@ def _tsqrt_blocked(T, B, ib, jnp, hi, up):
         L = _gram_factor(Rjj, Bj, jnp, hi)
         Rpjj, Vj, Tjt = _wy_from_L(Rjj, Bj, L, jnp, ti, precision=hi)
 
-        # 5-matmul WY application to the blocks after this one of the
-        # stacked panel (same shape as TSMQR, errors enter linearly)
+        # WY application to the blocks after this one of the stacked
+        # panel (same shape as TSMQR, errors enter linearly)
         def trailing(k, RB):
             Rrow, Bc = RB
             C1, C2 = col(Rrow, k), blk(Bc, k)
@@ -455,35 +528,40 @@ def _tsqrt_blocked(T, B, ib, jnp, hi, up):
             return putcol(Rrow, C1 - Z, k), put(Bc, C2 - mm(Vj, Z, up), k)
         Rrow, Bc = lax.fori_loop(j + 1, nblk, trailing, (Rrow, Bc))
 
-        # T-accumulation: Q^T = Q_j^T Q_prev^T collapses to one
+        # T-accumulation inside the block's GROUP (blocks g0 .. j-1,
+        # columns g0 * ib on): Q^T = Q_j^T Q_prev^T collapses to one
         # compact-WY pair with the block-lower-triangular
         # T^T[J, :s] = -T_j^T (W_j^T W_prev) T^T[:s, :s]; the unit tops
         # of W are disjoint identity columns, so W_j^T W_prev
-        # = V_j^T V[:, :s], taken a block at a time.  V takes V_j
-        # BEFORE the blocks before it are read: one live version of the
-        # carry (read first, the compiler kept two and copied 151 MB
-        # twice a block, 11 of 48 ms on a v5e)
+        # = V_j^T V[:, :s], taken a block at a time.  The groups before
+        # this one stay reflectors of their own: TSMQR applies them in
+        # turn.  V takes V_j BEFORE the blocks before it are read: one
+        # live version of the carry (read first, the compiler kept two
+        # and copied 151 MB twice a block, 11 of 48 ms on a v5e)
         V = put(V, Vj, j)
+        g = j // bpg
+        g0 = g * bpg
 
         def cross(k, X):
-            return putcol(X, mm(Vj.T, blk(V, k), hi), k)
-        zrow = jnp.zeros((ib, mb), jnp.float32)
-        X = lax.fori_loop(0, j, cross, zrow)
+            return putcol(X, mm(Vj.T, blk(V, k), hi), k - g0)
+        zrow = jnp.zeros((ib, W), jnp.float32)
+        X = lax.fori_loop(g0, j, cross, zrow)
 
         def times_tt(k, Y):
-            return Y + mm(col(X, k), lax.dynamic_slice(
-                Tt, (k * ib, 0), (ib, mb)), hi)
-        Trow = -mm(Tjt, lax.fori_loop(0, j, times_tt, zrow), hi)
+            return Y + mm(col(X, k - g0), lax.dynamic_slice(
+                Tt, (g, (k - g0) * ib, 0), (1, ib, W))[0], hi)
+        Trow = -mm(Tjt, lax.fori_loop(g0, j, times_tt, zrow), hi)
 
         Rc = lax.dynamic_update_slice(Rc, putcol(Rrow, Rpjj, j), (s, 0))
-        Tt = lax.dynamic_update_slice(Tt, putcol(Trow, Tjt, j), (s, 0))
+        Tt = lax.dynamic_update_slice(
+            Tt, putcol(Trow, Tjt, j - g0)[None], (g, (j - g0) * ib, 0))
         return Rc, Bc, V, Tt
 
     Rc, _Bc, V, Tt = lax.fori_loop(
         0, nblk, step, (T, _col_blocks(B, ib),
                         jnp.zeros((nblk, mb, ib), jnp.float32),
-                        jnp.zeros((mb, mb), jnp.float32)))
-    return Rc, _from_col_blocks(V), Tt
+                        jnp.zeros((mb // W, W, W), jnp.float32)))
+    return Rc, _from_col_blocks(V), _from_col_blocks(Tt)
 
 
 def _mk_tsqrt(ib: int = 0):
@@ -532,9 +610,18 @@ def _mk_tsqrt(ib: int = 0):
                               lambda M: tri_inv(M, precision=hi),
                               precision=hi)
 
+        # the group width is the edge's: Q is (mb + W, mb)
+        W = Q.shape[0] - mb
         if 0 < ib < mb and mb % ib == 0:
-            Rp, V, Tt = _tsqrt_blocked(T, B, ib, jnp, hi,
+            if W <= 0 or W % ib or mb % W:
+                raise ValueError(f"TSQRT: a Q edge of {Q.shape} groups "
+                                 f"no mb={mb} panel of ib={ib} blocks")
+            selected[("TSQRT", mb, ib)] = W
+            Rp, V, Tt = _tsqrt_blocked(T, B, ib, W, jnp, hi,
                                        _update_precision())
+        elif W != mb:
+            raise ValueError(f"TSQRT: the unblocked panel is one group, "
+                             f"its Q edge (2 mb, mb), not {Q.shape}")
         else:
             Rp, V, Tt = unblocked()
         dt = Q.dtype                    # NEW-flow arena dtype = storage
@@ -547,17 +634,23 @@ def _mk_tsmqr():
     def fn(Q, C1, C2):
         import jax.numpy as jnp
         mb = C1.shape[0]
-        V, Tt = Q[:mb, :], Q[mb:, :]
-        # f32 accumulation through the 5-matmul WY application; outputs
-        # round back to the tile storage dtype (bf16 in mp mode)
-        inner = (C1.astype(jnp.float32)
-                 + jnp.matmul(V.T, C2, preferred_element_type=jnp.float32))
-        Z = jnp.matmul(Tt, inner.astype(Tt.dtype),
-                       preferred_element_type=jnp.float32)
-        C2n = C2.astype(jnp.float32) - jnp.matmul(
-            V, Z.astype(V.dtype), preferred_element_type=jnp.float32)
-        return {"C1": (C1.astype(jnp.float32) - Z).astype(C1.dtype),
-                "C2": C2n.astype(C2.dtype)}
+        W = Q.shape[0] - mb             # the edge's group width
+        f32 = jnp.float32
+        mm = lambda a, b: jnp.matmul(a, b, preferred_element_type=f32)
+        # Q^T = Q_last^T ... Q_0^T, one compact-WY reflector a group of
+        # W columns, group 0 first.  Operands go to the MXU in the
+        # tiles' storage dtype; the running C2 stays in f32 across the
+        # groups and rounds to it once, on output (rows G of C1 are
+        # touched by group G alone)
+        acc, op, top = C2.astype(f32), C2, []
+        for lo in range(0, mb, W):
+            V, Tt = Q[:mb, lo:lo + W], Q[mb:, lo:lo + W]
+            rows = C1[lo:lo + W].astype(f32)
+            Z = mm(Tt, (rows + mm(V.T, op)).astype(Tt.dtype))
+            top.append((rows - Z).astype(C1.dtype))
+            acc = acc - mm(V, Z.astype(V.dtype))
+            op = acc.astype(C2.dtype)
+        return {"C1": jnp.concatenate(top, axis=0), "C2": op}
     return fn
 
 
@@ -580,6 +673,9 @@ def qr_taskpool(A: TiledMatrix, device: str = "tpu") -> ParameterizedTaskpool:
     # inner blocking + trailing-update precision resolve ONCE per build;
     # they key the kernel memo so an MCA change cannot alias a stale jit
     ib = effective_ib(mb)
+    # the reflector's group width follows the shapes; CPU bodies alone
+    # keep the panel-wide factor (float64 products have no ridge to miss)
+    W = group_width(mb, ib) if use_device else mb
     upd = str(params.get("qr_update_precision", "default")).lower()
     from parsec_tpu.apps.pallas_kernels import (pallas_gram_tile,
                                                 use_pallas_qr_gram)
@@ -603,7 +699,8 @@ def qr_taskpool(A: TiledMatrix, device: str = "tpu") -> ParameterizedTaskpool:
 
     p = PTG("geqrf", NT=NT)
     p.arena("q1", (mb, mb), dtype=A.dtype)
-    p.arena("q2", (2 * mb, mb), dtype=A.dtype)   # stacked [V; T^T]
+    # V over the W x mb strip of T^T's mb / W diagonal blocks
+    p.arena("q2", (mb + W, mb), dtype=A.dtype)
 
     # GEQRT(k): diagonal QR
     tb = p.task("GEQRT", k=Range(0, NT - 1)) \
@@ -675,24 +772,37 @@ def qr_taskpool(A: TiledMatrix, device: str = "tpu") -> ParameterizedTaskpool:
                                             for n in range(k + 1, NT)]),
                   when=lambda k, NT=NT: k < NT - 1))
 
-    def cpu_tsqrt(T, B, Q):
-        # same compact-WY math as the device kernel, in float64 for
-        # stability (Cholesky-QR squares the condition number)
-        R64 = np.asarray(T, dtype=np.float64)
-        B64 = np.asarray(B, dtype=np.float64)
+    def cpu_wy(R64, B64):
         try:
-            Rp, V, Tt = _tsqrt_wy(R64, B64, np, np.linalg.cholesky,
-                                  _np_tri_inv)
+            return _tsqrt_wy(R64, B64, np, np.linalg.cholesky, _np_tri_inv)
         except np.linalg.LinAlgError:
             # non-PD Gram matrix: Householder QR of the stacked panel
             # gives the same triangular factor, unconditionally stably
             Rh = np.linalg.qr(np.concatenate([R64, B64], axis=0),
                               mode="r")
             s = np.where(np.diagonal(Rh) >= 0, 1.0, -1.0)
-            Rp, V, Tt = _wy_from_L(R64, B64, (s[:, None] * Rh).T, np,
-                                   _np_tri_inv)
+            return _wy_from_L(R64, B64, (s[:, None] * Rh).T, np,
+                              _np_tri_inv)
+
+    def cpu_tsqrt(T, B, Q):
+        # same compact-WY math as the device kernel, in float64 for
+        # stability (Cholesky-QR squares the condition number), a group
+        # of the edge's W columns at a time: the group's pair from its
+        # own columns of [R; B], then applied to the columns after it —
+        # the pair the device's blocks accumulate to (one QR, one sign
+        # choice, one unit-top form)
+        R64 = np.array(T, dtype=np.float64)
+        B64 = np.array(B, dtype=np.float64)
+        W_ = np.asarray(Q).shape[0] - mb
+        V, Tt = np.empty((mb, mb)), np.empty((W_, mb))
+        for lo in range(0, mb, W_):
+            G, rest = slice(lo, lo + W_), slice(lo + W_, mb)
+            R64[G, G], V[:, G], Tt[:, G] = cpu_wy(R64[G, G], B64[:, G])
+            Z = Tt[:, G] @ (R64[G, rest] + V[:, G].T @ B64[:, rest])
+            R64[G, rest] -= Z
+            B64[:, rest] -= V[:, G] @ Z
         dt = np.asarray(T).dtype
-        return {"T": Rp.astype(dt), "B": np.zeros_like(np.asarray(B)),
+        return {"T": R64.astype(dt), "B": np.zeros_like(np.asarray(B)),
                 "Q": np.concatenate([V, Tt], axis=0).astype(dt)}
     bodies(tb, _k(("tsqrt", ib, upd), lambda: _mk_tsqrt(ib)), cpu_tsqrt)
 
@@ -732,12 +842,16 @@ def qr_taskpool(A: TiledMatrix, device: str = "tpu") -> ParameterizedTaskpool:
                                                            k=k + 1)),
                   when=lambda m, n, k: m > k + 1 and n > k + 1))
     def cpu_tsmqr(Q, C1, C2):
-        mb_ = np.asarray(C1).shape[0]
         Qn = np.asarray(Q)
-        V, Tt = Qn[:mb_, :], Qn[mb_:, :]
-        C1n, C2n = np.asarray(C1), np.asarray(C2)
-        Z = Tt @ (C1n + V.T @ C2n)
-        return {"C1": C1n - Z, "C2": C2n - V @ Z}
+        W_ = Qn.shape[0] - mb
+        C1n, C2n = np.array(C1), np.array(C2)
+        for lo in range(0, mb, W_):
+            G = slice(lo, lo + W_)
+            V, Tt = Qn[:mb, G], Qn[mb:, G]
+            Z = Tt @ (C1n[G] + V.T @ C2n)
+            C1n[G] -= Z
+            C2n -= V @ Z
+        return {"C1": C1n, "C2": C2n}
     bodies(tb, _k("tsmqr", _mk_tsmqr), cpu_tsmqr)
 
     if routed:
@@ -761,12 +875,8 @@ def qr_taskpool(A: TiledMatrix, device: str = "tpu") -> ParameterizedTaskpool:
 
     tp = p.build()
     for name, tc in tp.task_classes.items():
-        # executed-flop weights for device load balancing (stores move
-        # a tile, no flops)
-        tc.properties["flops"] = {"GEQRT": 2.0 * mb ** 3,
-                                  "UNMQR": 2.0 * mb ** 3,
-                                  "TSQRT": 6.0 * mb ** 3,
-                                  "TSMQR": 10.0 * mb ** 3}.get(name, 1.0)
+        # executed-flop weights for device load balancing
+        tc.properties["flops"] = qr_executed_flops(name, mb, ib, W)
     # cross-panel fused dispatch (devices/xla.py chain fusion): the
     # GEQRT(k) -> TSQRT(k+1,k) -> ... -> TSQRT(NT-1,k) column is the
     # serial spine of the DAG — each link's only missing input is its

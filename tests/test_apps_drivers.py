@@ -210,28 +210,66 @@ def test_geqrt_blocked_orthogonal():
     assert np.abs(np.tril(R, -1)).max() == 0.0
 
 
-def test_tsqrt_blocked_wy_pair_annihilates():
-    """Blocked TSQRT: the aggregated panel-wide (V, T^T) pair — with
-    the block-lower-triangular T-accumulation — must form an ORTHOGONAL
-    transform that annihilates B and reproduces R' exactly, so TSMQR's
-    unchanged 5-matmul application stays correct."""
+def _dense_qt(pair, mb):
+    """Q^T of a TSQRT edge ``[V; T^T strip]`` as a dense 2 mb x 2 mb
+    matrix: the product of its groups' reflectors
+    I - [E_G; V_G] T_G^T [E_G; V_G]^T, group 0 applied first."""
+    W = pair.shape[0] - mb
+    V, strip = pair[:mb], pair[mb:]
+    Qt = np.eye(2 * mb)
+    for lo in range(0, mb, W):
+        Wg = np.vstack([np.eye(mb)[:, lo:lo + W], V[:, lo:lo + W]])
+        Qt = (np.eye(2 * mb) - Wg @ strip[:, lo:lo + W] @ Wg.T) @ Qt
+    return Qt
+
+
+@pytest.mark.parametrize("mb, ib, W", [
+    (32, 8, 32),        # one group: the panel-wide factor
+    (32, 8, 16),        # two groups
+    (32, 8, 8),         # mb / ib groups: no accumulation at all
+    (48, 8, 16),        # three groups of two blocks
+    (32, 0, 32),        # unblocked: one group by construction
+])
+def test_tsqrt_edge_then_tsmqr_is_the_dense_qt_of_the_panel(mb, ib, W):
+    """TSQRT's edge — V over the W x mb strip of T^T's diagonal blocks,
+    accumulated inside column groups of W alone — is an ORTHOGONAL
+    transform that annihilates B and reproduces R', and TSMQR's group
+    loop applies that same transform to [C1; C2]."""
     import jax.numpy as jnp
-    from parsec_tpu.apps.qr import _mk_tsqrt
-    mb, ib = 32, 8
+    from parsec_tpu.apps import qr
     rng = np.random.default_rng(7)
     Rin = np.triu(rng.standard_normal((mb, mb))).astype(np.float32) \
         + 3 * np.eye(mb, dtype=np.float32)
     B = rng.standard_normal((mb, mb)).astype(np.float32)
-    out = _mk_tsqrt(ib)(jnp.asarray(Rin), jnp.asarray(B),
-                        jnp.zeros((2 * mb, mb), jnp.float32))
+    qr.selected.clear()
+    out = qr._mk_tsqrt(ib)(jnp.asarray(Rin), jnp.asarray(B),
+                           jnp.zeros((mb + W, mb), jnp.float32))
+    assert out["Q"].shape == (mb + W, mb)
+    assert qr.selected == ({("TSQRT", mb, ib): W} if ib else {})
     Rp = np.asarray(out["T"], np.float64)
-    pair = np.asarray(out["Q"], np.float64)
-    V, Tt = pair[:mb], pair[mb:]
-    W = np.vstack([np.eye(mb), V])
-    Phi_t = np.eye(2 * mb) - W @ Tt @ W.T          # = Q^T
+    Qt = _dense_qt(np.asarray(out["Q"], np.float64), mb)
     stacked = np.vstack([Rin, B]).astype(np.float64)
-    applied = Phi_t @ stacked
+    applied = Qt @ stacked
     assert np.abs(applied[:mb] - Rp).max() / np.abs(Rp).max() < 1e-5
     assert np.abs(applied[mb:]).max() < 1e-4       # B annihilated
-    assert np.abs(Phi_t @ Phi_t.T - np.eye(2 * mb)).max() < 1e-5
+    assert np.abs(Qt @ Qt.T - np.eye(2 * mb)).max() < 1e-5
+    # [R; B] = Q [R'; 0]
+    assert np.abs(Qt.T[:, :mb] @ Rp - stacked).max() < 1e-4
     assert np.abs(np.asarray(out["B"])).max() == 0.0
+    C = rng.standard_normal((2 * mb, mb)).astype(np.float32)
+    got = qr._mk_tsmqr()(out["Q"], jnp.asarray(C[:mb]), jnp.asarray(C[mb:]))
+    want = Qt @ C
+    assert np.abs(np.asarray(got["C1"]) - want[:mb]).max() < 1e-4
+    assert np.abs(np.asarray(got["C2"]) - want[mb:]).max() < 1e-4
+
+
+def test_tsqrt_refuses_an_edge_that_groups_no_block():
+    """The group width is read off the Q edge: one that is no multiple
+    of ib, does not divide mb, or is not (2 mb, mb) on an unblocked
+    panel is a build error, not a wrong factor."""
+    import jax.numpy as jnp
+    from parsec_tpu.apps import qr
+    t = jnp.eye(32, dtype=jnp.float32)
+    for ib, rows in ((8, 32 + 12), (8, 32 + 24), (0, 32 + 16)):
+        with pytest.raises(ValueError, match="TSQRT"):
+            qr._mk_tsqrt(ib)(t, t, jnp.zeros((rows, 32), jnp.float32))

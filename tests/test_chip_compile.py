@@ -146,18 +146,31 @@ def test_potrf_diagonal_kernel_2048(spec):
 
 
 def test_qr_tsmqr_6144_bf16(spec):
+    """The group loop at the cell's shapes (six groups of 1024): the
+    f32 running tile passes from group to group without a copy (a
+    ``copy`` of the tile's shape is what would make the rank-W updates
+    memory-bound, PERF.md section 7)."""
+    import re
     import jax.numpy as jnp
     from parsec_tpu.apps import qr
     bf = jnp.bfloat16
-    _compile(qr._mk_tsmqr(), spec((2 * MB, MB), bf), spec((MB, MB), bf),
-             spec((MB, MB), bf))
+    W = qr.group_width(MB, 512)
+    assert MB % W == 0 and W < MB
+    c = _compile(qr._mk_tsmqr(), spec((MB + W, MB), bf), spec((MB, MB), bf),
+                 spec((MB, MB), bf))
+    assert not re.findall(r"= f32\[%d,%d\]\S* copy\(" % (MB, MB),
+                          c.as_text())
+    # 4 mb^3 + 2 W mb^2 executed, where the panel-wide factor took 6 mb^3
+    flop = c.cost_analysis()["flops"] / MB ** 3
+    assert abs(flop - (4 + 2 * W / MB)) < 0.01
 
 
 @pytest.mark.parametrize("kernel", ["geqrt", "tsqrt"])
 def test_qr_panel_kernels_ib512_2048(spec, kernel):
     """The inner-blocked panel engine (four ib=512 blocks: one loop
-    body, a branch a block for what follows the block's place); full
-    width compiled by hand (module docstring)."""
+    body, a branch a block for what follows the block's place; TSQRT's
+    reflector in two groups of 1024); full width compiled by hand
+    (module docstring)."""
     import jax.numpy as jnp
     from parsec_tpu.apps import qr
     mb, bf = 2048, jnp.bfloat16
@@ -165,7 +178,8 @@ def test_qr_panel_kernels_ib512_2048(spec, kernel):
     if kernel == "geqrt":
         c = _compile(qr._mk_geqrt(512), t, t)
     else:
-        c = _compile(qr._mk_tsqrt(512), t, t, spec((2 * mb, mb), bf))
+        c = _compile(qr._mk_tsqrt(512), t, t, spec((mb + 1024, mb), bf))
+        assert qr.selected[("TSQRT", mb, 512)] == 1024
     # one loop over the blocks, and no Householder expander behind it
     hlo = c.as_text()
     assert "while" in hlo and "householder" not in hlo.lower()

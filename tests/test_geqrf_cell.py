@@ -28,7 +28,7 @@ def _factor(a, mb, ib, jobs=1, spans=None):
     ``qr_taskpool(A, device="tpu")``; returns the last factor, the
     device's counters after every job and the ``program`` of every
     ``mgr.dispatch`` span."""
-    from parsec_tpu.apps.qr import qr_taskpool
+    from parsec_tpu.apps import qr
     n = a.shape[0]
     programs, stats = [], []
     params.set("qr_ib", ib)
@@ -45,7 +45,7 @@ def _factor(a, mb, ib, jobs=1, spans=None):
             for _ in range(jobs):
                 A = TwoDimBlockCyclic(mb=mb, nb=mb, lm=n,
                                       ln=n).from_array(a.copy())
-                ctx.add_taskpool(qr_taskpool(A, device="tpu"))
+                ctx.add_taskpool(qr.qr_taskpool(A, device="tpu"))
                 ctx.wait(timeout=120)
                 stats.append(
                     ctx.device_registry.accelerators[0].stats.as_dict())
@@ -57,20 +57,31 @@ def _factor(a, mb, ib, jobs=1, spans=None):
     return out, stats, programs
 
 
-@pytest.mark.parametrize("nt, ib", [(3, 0), (3, 8), (4, 0), (4, 8)])
-def test_qr_device_path_against_the_plain_reference(nt, ib):
+@pytest.mark.parametrize("nt, ib, W", [
+    (3, 0, None), (3, 8, None), (4, 0, None), (4, 8, None),
+    (3, 8, 16), (4, 8, 8)])
+def test_qr_device_path_against_the_plain_reference(nt, ib, W, monkeypatch):
     """R of the tiled algorithm, blocked and unblocked, against the
     plain Householder QR of the same seeded operand: the comparison that
     decides the cell's ``correct`` under the configuration's own limits,
-    and R itself up to the signs of its rows."""
+    and R itself up to the signs of its rows.  ``W``: the reflector in
+    column groups that narrow (two and mb / ib groups a panel; the
+    rule's own choice at this size is one)."""
     import json
     import os
     import jax.numpy as jnp
     from benchmark import harness
+    from parsec_tpu.apps import qr
     mb = 32
     n = nt * mb
     a = _operand(n, 100 * nt + ib)
+    assert qr.group_width(mb, ib) == mb
+    if W:
+        monkeypatch.setattr(qr, "group_width", lambda mb, ib: W)
+    qr._kernels.clear()
+    qr.selected.clear()
     out, stats, _programs = _factor(a, mb, ib)
+    assert qr.selected == ({("TSQRT", mb, ib): W or mb} if ib else {})
     with open(os.path.join(harness.ROOT, "benchmark", "configs",
                            "dplasma_geqrf_bf16.json")) as f:
         limits = json.load(f)["limits"]
@@ -131,12 +142,24 @@ def test_first_pool_counts_the_chain_programs_it_builds():
     assert stats[0]["chain_programs"] == len(chains) == 2
 
 
+def _tsmqr_gap(edge, top, bot, Rp):
+    """TSMQR's group loop on the panel its edge came from: how far
+    Q^T [top; bot] lies from [R'; 0], in units of the largest R'."""
+    import jax.numpy as jnp
+    from parsec_tpu.apps.qr import _mk_tsmqr
+    got = _mk_tsmqr()(edge, jnp.asarray(top), jnp.asarray(bot))
+    return max(np.abs(np.asarray(got["C1"], np.float64) - Rp).max(),
+               np.abs(np.asarray(got["C2"])).max()) / np.abs(Rp).max()
+
+
+@pytest.mark.parametrize("W", [32, 16])
 @pytest.mark.parametrize("cond", [1e3, 1e6])
-def test_blocked_panel_kernels_hold_on_an_ill_conditioned_panel(cond):
+def test_blocked_panel_kernels_hold_on_an_ill_conditioned_panel(cond, W):
     """The blocked GEQRT / TSQRT have no Householder fall-back any more:
     a column block whose Gram matrix is not positive definite in f32
     takes the shifted Cholesky-QR branch, and R still satisfies
-    R^T R = A^T A."""
+    R^T R = A^T A — whether the reflector is one group or two, and
+    TSMQR's group loop still turns the panel into [R'; 0]."""
     import jax.numpy as jnp
     from parsec_tpu.apps.qr import _mk_geqrt, _mk_tsqrt
     mb, ib = 32, 8
@@ -153,11 +176,12 @@ def test_blocked_panel_kernels_hold_on_an_ill_conditioned_panel(cond):
     tt = top.astype(np.float64).T @ top
     assert np.abs(R.T @ R - tt).max() / np.abs(tt).max() < tol
     t = _mk_tsqrt(ib)(g["T"], jnp.asarray(bot),
-                      jnp.zeros((2 * mb, mb), jnp.float32))
+                      jnp.zeros((mb + W, mb), jnp.float32))
     Rp = np.asarray(t["T"], np.float64)
     assert np.isfinite(Rp).all() and np.isfinite(np.asarray(t["Q"])).all()
     ata = panel.astype(np.float64).T @ panel
     assert np.abs(Rp.T @ Rp - ata).max() / np.abs(ata).max() < tol
+    assert _tsmqr_gap(t["Q"], g["T"], bot, Rp) < tol
 
 
 def test_chain_programs_is_scraped_with_the_device_counters():
@@ -255,8 +279,9 @@ def test_gram_factor_of_a_stacked_block_that_is_not_positive_definite(block):
     assert _gram_gap(L.T, np.concatenate([top, bot])) < 1e-4
 
 
+@pytest.mark.parametrize("W", [64, 32, 16])
 @pytest.mark.parametrize("smin", [1.6e-6, 0.0])
-def test_blocked_panel_kernels_with_an_ill_conditioned_last_block(smin):
+def test_blocked_panel_kernels_with_an_ill_conditioned_last_block(smin, W):
     """GEQRT and TSQRT, inner-blocked, on tiles whose LAST ib columns
     are (nearly) dependent on the ones before: the block that is left
     after the earlier blocks are projected out is what fails a plain
@@ -280,7 +305,83 @@ def test_blocked_panel_kernels_with_an_ill_conditioned_last_block(smin):
     r0 = np.triu(_with_smallest(mb, 1.0, 13))
     r0[:, -1] = r0[:, 0] * 2.0
     out = qr._mk_tsqrt(ib)(jnp.asarray(r0), jnp.asarray(b),
-                           jnp.zeros((2 * mb, mb), jnp.float32))
+                           jnp.zeros((mb + W, mb), jnp.float32))
     Rp = np.asarray(out["T"])
     assert np.isfinite(Rp).all() and np.isfinite(np.asarray(out["Q"])).all()
     assert _gram_gap(np.triu(Rp), np.concatenate([r0, b])) < 1e-4
+    assert out["Q"].shape == (mb + W, mb)
+
+
+# ---------------------------------------------------------------------------
+# the Q edge between TSQRT and TSMQR: V over the W x mb strip of T^T's
+# diagonal blocks (PR 32).  A task of one pool may run either incarnation
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("mb, ib, W", [(32, 8, 16), (48, 8, 16), (32, 8, 8)])
+@pytest.mark.parametrize("tsqrt_on", ["device", "cpu"])
+def test_the_two_incarnations_agree_through_the_edge(mb, ib, W, tsqrt_on,
+                                                     monkeypatch):
+    """Device TSQRT -> ``cpu_tsmqr`` and ``cpu_tsqrt`` -> device TSMQR
+    of a device pool give the C1 / C2 that device -> device gives, at a
+    group width under mb: the CPU bodies read W off the same edge,
+    factor a group at a time in float64 and run the same group loop."""
+    import jax.numpy as jnp
+    from parsec_tpu.apps import qr
+    monkeypatch.setattr(qr, "group_width", lambda mb, ib: W)
+    params.set("qr_ib", ib)
+    try:
+        tp = qr.qr_taskpool(TwoDimBlockCyclic(mb=mb, nb=mb, lm=2 * mb,
+                                              ln=2 * mb), device="tpu")
+    finally:
+        params.unset("qr_ib")
+    cpu = {c: dict(tp.task_classes[c].incarnations)["cpu"].__ptg_fn__
+           for c in ("TSQRT", "TSMQR")}
+    rng = np.random.default_rng(mb + W)
+    R = (np.triu(rng.standard_normal((mb, mb)))
+         + 3 * np.eye(mb)).astype(np.float32)
+    B, C1, C2 = (rng.standard_normal((mb, mb)).astype(np.float32)
+                 for _ in range(3))
+    edge0 = np.zeros((mb + W, mb), np.float32)
+    dev = qr._mk_tsqrt(ib)(jnp.asarray(R), jnp.asarray(B),
+                           jnp.asarray(edge0))
+    want = qr._mk_tsmqr()(dev["Q"], jnp.asarray(C1), jnp.asarray(C2))
+    if tsqrt_on == "device":
+        got = cpu["TSMQR"](np.asarray(dev["Q"]), C1, C2)
+    else:
+        host = cpu["TSQRT"](R, B, edge0)
+        assert host["Q"].shape == (mb + W, mb)
+        assert np.abs(host["T"] - np.asarray(dev["T"])).max() < 1e-4
+        assert np.abs(host["Q"] - np.asarray(dev["Q"])).max() < 1e-4
+        got = qr._mk_tsmqr()(jnp.asarray(host["Q"]), jnp.asarray(C1),
+                             jnp.asarray(C2))
+    for flow in ("C1", "C2"):
+        assert np.abs(np.asarray(got[flow])
+                      - np.asarray(want[flow])).max() < 1e-4
+
+
+def test_the_group_width_follows_the_shapes_and_a_cpu_pool_keeps_one_group():
+    """The rule reads the shapes alone: the narrowest multiple of ib
+    that divides mb and reaches the floor, one group where none does or
+    the panel is unblocked; ``device="cpu"`` keeps one group whatever
+    the shapes (W = mb, the (2 mb, mb) edge); and the load-balancing
+    weights follow what is executed."""
+    from parsec_tpu.apps import qr
+    assert qr.group_width(6144, 512) == 1024
+    assert qr.group_width(6144, 0) == 6144
+    assert qr.group_width(1024, 512) == 1024
+    assert qr.group_width(2560, 512) == 2560
+    assert qr.group_width(6144, 768) == 1536
+    params.set("qr_ib", 512)
+    try:
+        A = TwoDimBlockCyclic(mb=6144, nb=6144, lm=12288, ln=12288)
+        pools = {d: qr.qr_taskpool(A, device=d) for d in ("cpu", "tpu")}
+    finally:
+        params.unset("qr_ib")
+    edge = {d: tp.arenas["q2"].shape for d, tp in pools.items()}
+    assert edge == {"cpu": (2 * 6144, 6144), "tpu": (6144 + 1024, 6144)}
+    flops = {c: tc.properties["flops"] / 6144.0 ** 3
+             for c, tc in pools["tpu"].task_classes.items()}
+    # 4 mb^3 + 2 W mb^2 in TSMQR; in TSQRT the trailing updates'
+    # 2 mb^3 (1 - 1 / 12) and the mb^2 ib terms
+    assert flops["TSMQR"] == pytest.approx(4 + 1 / 3)
+    assert 2.0 < flops["TSQRT"] < 2.6
