@@ -35,6 +35,11 @@ nobody reads — POTRF factors the lower triangle alone, as DPLASMA's
 dpotrf_L does.  Both then execute (b+1)/(2b) of the full product's
 flop; ``selected`` says which b every traced tile order got.
 
+Two front ends, one set of kernels: ``potrf_taskpool`` declares the
+dataflow as a PTG, ``potrf_dtd_taskpool`` inserts the same tasks one by
+one (DPLASMA's ``testing_dpotrf_dtd``) and lets the runtime discover
+it; both run the same device programs under the same names.
+
 The priority schedule drives the critical path (POTRF > TRSM > SYRK >
 GEMM at equal k) exactly like DPLASMA's priority hints, and same-class
 waves (the TRSM panel, the SYRK/GEMM trailing updates) are fused into
@@ -226,13 +231,61 @@ def _k_gemm(precision):
     return fn
 
 
-def potrf_taskpool(A: TiledMatrix, device: str = "tpu",
-                   precision: Optional[str] = None) -> ParameterizedTaskpool:
-    """Factor the lower triangle of A in place: A = L @ L^T."""
+# -- the host bodies: the fall-back incarnation of every class, whatever
+# -- front end inserts it
+
+def _cpu_potrf(T, W):
+    import scipy.linalg as sl
+    L = np.linalg.cholesky(np.asarray(T, dtype=np.float32))
+    Winv = sl.solve_triangular(L, np.eye(L.shape[0], dtype=L.dtype),
+                               lower=True)
+    return {"T": L.astype(np.asarray(T).dtype), "W": Winv}
+
+
+def _cpu_potrf_last(T):
+    return np.linalg.cholesky(
+        np.asarray(T, dtype=np.float32)).astype(np.asarray(T).dtype)
+
+
+def _cpu_trsm(W, C):
+    out = np.asarray(C, dtype=np.float32) @ \
+        np.asarray(W, dtype=np.float32).T
+    return out.astype(np.asarray(C).dtype)
+
+
+def _cpu_syrk(T, R):
+    r = np.asarray(R, dtype=np.float32)
+    return (np.asarray(T, dtype=np.float32) -
+            r @ r.T).astype(np.asarray(T).dtype)
+
+
+def _cpu_gemm(C, L, R):
+    acc = np.asarray(L, dtype=np.float32) @ \
+        np.asarray(R, dtype=np.float32).T
+    return (np.asarray(C, dtype=np.float32) -
+            acc).astype(np.asarray(C).dtype)
+
+
+def _check_tiling(A: TiledMatrix) -> None:
     if A.mt != A.nt:
         raise ValueError("potrf needs a square tile grid")
     if A.lm % A.mb or A.ln % A.nb:
         raise ValueError("potrf tiles must divide the matrix evenly")
+
+
+_RANK = {"POTRF": 3, "TRSM": 2, "SYRK": 1, "GEMM": 0}
+
+
+def _priority(cls: str, NT: int, k: int) -> int:
+    """The critical path first: POTRF > TRSM > SYRK > GEMM at equal k,
+    an earlier panel over a later one (DPLASMA's priority hints)."""
+    return 6 if cls == "POTRFL" else 3 * NT - 3 * k + _RANK[cls]
+
+
+def potrf_taskpool(A: TiledMatrix, device: str = "tpu",
+                   precision: Optional[str] = None) -> ParameterizedTaskpool:
+    """Factor the lower triangle of A in place: A = L @ L^T."""
+    _check_tiling(A)
     NT = A.mt
     mb = A.mb
     use_device = device in ("tpu", "xla", "gpu")
@@ -249,7 +302,7 @@ def potrf_taskpool(A: TiledMatrix, device: str = "tpu",
 
     tb = p.task("POTRF", k=Range(0, NT - 2)) \
         .affinity(lambda k, A=A: A(k, k)) \
-        .priority(lambda k, NT=NT: 3 * NT - 3 * k + 3) \
+        .priority(lambda k, NT=NT: _priority("POTRF", NT, k)) \
         .flow("T", "RW",
               IN(DATA(lambda k, A=A: A(k, k)), when=lambda k: k == 0),
               IN(TASK("SYRK", "T", lambda k: dict(k=k - 1, m=k)),
@@ -261,33 +314,24 @@ def potrf_taskpool(A: TiledMatrix, device: str = "tpu",
                        lambda k, NT=NT: [dict(m=m, k=k)
                                          for m in range(k + 1, NT)])))
 
-    def cpu_potrf(T, W):
-        import scipy.linalg as sl
-        L = np.linalg.cholesky(np.asarray(T, dtype=np.float32))
-        Winv = sl.solve_triangular(L, np.eye(L.shape[0], dtype=L.dtype),
-                                   lower=True)
-        return {"T": L.astype(np.asarray(T).dtype), "W": Winv}
-    add_bodies(tb, _k_potrf(precision), cpu_potrf)
+    add_bodies(tb, _k_potrf(precision), _cpu_potrf)
 
     # the final diagonal tile: no panel below it, so no inverse is needed
     tb = p.task("POTRFL") \
         .affinity(lambda A=A, NT=NT: A(NT - 1, NT - 1)) \
-        .priority(lambda NT=NT: 6) \
+        .priority(lambda NT=NT: _priority("POTRFL", NT, NT - 1)) \
         .flow("T", "RW",
               IN(DATA(lambda A=A, NT=NT: A(NT - 1, NT - 1)),
                  when=lambda NT=NT: NT == 1),
               IN(TASK("SYRK", "T", lambda NT=NT: dict(k=NT - 2, m=NT - 1)),
                  when=lambda NT=NT: NT > 1),
               OUT(DATA(lambda A=A, NT=NT: A(NT - 1, NT - 1))))
-    add_bodies(tb, _k_potrf_last(precision),
-               lambda T: np.linalg.cholesky(
-                   np.asarray(T, dtype=np.float32)
-               ).astype(np.asarray(T).dtype))
+    add_bodies(tb, _k_potrf_last(precision), _cpu_potrf_last)
 
     tb = p.task("TRSM", k=Range(0, NT - 2),
                 m=Range(lambda k: k + 1, NT - 1)) \
         .affinity(lambda m, k, A=A: A(m, k)) \
-        .priority(lambda k, NT=NT: 3 * NT - 3 * k + 2) \
+        .priority(lambda k, NT=NT: _priority("TRSM", NT, k)) \
         .flow("W", "READ", IN(TASK("POTRF", "W", lambda k: dict(k=k)))) \
         .flow("C", "RW",
               IN(DATA(lambda m, k, A=A: A(m, k)), when=lambda k: k == 0),
@@ -304,15 +348,11 @@ def potrf_taskpool(A: TiledMatrix, device: str = "tpu",
                   when=lambda m, NT=NT: m < NT - 1),
               OUT(DATA(lambda m, k, A=A: A(m, k))))
 
-    def cpu_trsm(W, C):
-        out = np.asarray(C, dtype=np.float32) @ \
-            np.asarray(W, dtype=np.float32).T
-        return out.astype(np.asarray(C).dtype)
-    add_bodies(tb, _k_trsm(precision), cpu_trsm)
+    add_bodies(tb, _k_trsm(precision), _cpu_trsm)
 
     tb = p.task("SYRK", m=Range(1, NT - 1), k=Range(0, lambda m: m - 1)) \
         .affinity(lambda m, A=A: A(m, m)) \
-        .priority(lambda k, NT=NT: 3 * NT - 3 * k + 1) \
+        .priority(lambda k, NT=NT: _priority("SYRK", NT, k)) \
         .flow("T", "RW",
               IN(DATA(lambda m, A=A: A(m, m)), when=lambda k: k == 0),
               IN(TASK("SYRK", "T", lambda m, k: dict(m=m, k=k - 1)),
@@ -325,17 +365,13 @@ def potrf_taskpool(A: TiledMatrix, device: str = "tpu",
                   when=lambda m, k: k < m - 1)) \
         .flow("R", "READ", IN(TASK("TRSM", "C", lambda m, k: dict(m=m,
                                                                   k=k))))
-    def cpu_syrk(T, R):
-        r = np.asarray(R, dtype=np.float32)
-        return (np.asarray(T, dtype=np.float32) -
-                r @ r.T).astype(np.asarray(T).dtype)
-    add_bodies(tb, _k_syrk(precision), cpu_syrk)
+    add_bodies(tb, _k_syrk(precision), _cpu_syrk)
 
     tb = p.task("GEMM", n=Range(1, NT - 2),
                 m=Range(lambda n: n + 1, NT - 1),
                 k=Range(0, lambda n: n - 1)) \
         .affinity(lambda m, n, A=A: A(m, n)) \
-        .priority(lambda k, NT=NT: 3 * NT - 3 * k) \
+        .priority(lambda k, NT=NT: _priority("GEMM", NT, k)) \
         .flow("C", "RW",
               IN(DATA(lambda m, n, A=A: A(m, n)), when=lambda k: k == 0),
               IN(TASK("GEMM", "C", lambda m, n, k: dict(m=m, n=n, k=k - 1)),
@@ -348,12 +384,7 @@ def potrf_taskpool(A: TiledMatrix, device: str = "tpu",
                                                                   k=k)))) \
         .flow("R", "READ", IN(TASK("TRSM", "C", lambda n, k: dict(m=n,
                                                                   k=k))))
-    def cpu_gemm(C, L, R):
-        acc = np.asarray(L, dtype=np.float32) @ \
-            np.asarray(R, dtype=np.float32).T
-        return (np.asarray(C, dtype=np.float32) -
-                acc).astype(np.asarray(C).dtype)
-    add_bodies(tb, _k_gemm(precision), cpu_gemm)
+    add_bodies(tb, _k_gemm(precision), _cpu_gemm)
 
     tp = p.build()
     for name, tc in tp.task_classes.items():
@@ -378,6 +409,86 @@ def potrf_taskpool(A: TiledMatrix, device: str = "tpu",
     # re-runnable source, and the pool recovers instead of failing
     tp.recovery_collections = [A]
     return tp
+
+
+#: (device, precision, mb) -> the five DTD task classes, made once a process
+_dtd_classes = {}
+
+
+def _potrf_dtd_classes(device: str, precision: Optional[str], mb: int):
+    """The PTG's classes declared for insertion: the same kernels, host
+    bodies, executed-flop weights and POTRF -> TRSM chain, under the
+    same names, so that both front ends run the same device programs."""
+    from parsec_tpu.dsl.dtd import INOUT, INPUT, OUTPUT, create_task_class
+    key = (device, precision, mb)
+    classes = _dtd_classes.get(key)
+    if classes is None:
+        classes = {}
+        for name, args, modes, kernel, cpu_fn in (
+                ("POTRF", ("T", "W"), (INOUT, OUTPUT),
+                 _k_potrf(precision), _cpu_potrf),
+                ("POTRFL", ("T",), (INOUT,),
+                 _k_potrf_last(precision), _cpu_potrf_last),
+                ("TRSM", ("W", "C"), (INPUT, INOUT),
+                 _k_trsm(precision), _cpu_trsm),
+                ("SYRK", ("T", "R"), (INOUT, INPUT),
+                 _k_syrk(precision), _cpu_syrk),
+                ("GEMM", ("C", "L", "R"), (INOUT, INPUT, INPUT),
+                 _k_gemm(precision), _cpu_gemm)):
+            cls = create_task_class(
+                name, args, modes,
+                properties={"flops": potrf_executed_flops(name, mb)})
+            if device in ("tpu", "xla", "gpu"):
+                cls.add_chore(device, kernel)
+            classes[name] = cls.add_chore("cpu", cpu_fn)
+        classes["POTRF"].properties["fuse_chain"] = ("W", "TRSM")
+        _dtd_classes[key] = classes
+    return classes
+
+
+def potrf_dtd_taskpool(A: TiledMatrix, device: str = "tpu",
+                       precision: Optional[str] = None):
+    """The same factorization written as DPLASMA's ``testing_dpotrf_dtd``
+    writes it: a DTD taskpool whose inserter, once the pool is attached
+    and started, inserts the right-looking tile Cholesky task by task —
+    POTRF(A[k][k] INOUT), TRSM(A[k][k]'s inverse INPUT, A[m][k] INOUT),
+    SYRK(A[m][k] INPUT, A[m][m] INOUT), GEMM(A[m][k] INPUT, A[n][k]
+    INPUT, A[m][n] INOUT) — then flushes.  The runtime finds the DAG
+    from the order of the inserts and each tile's access mode; every
+    tile sees its updates in insert order, which is the PTG's k order."""
+    from parsec_tpu.dsl.dtd import DTDTaskpool
+    _check_tiling(A)
+    NT, mb = A.mt, A.mb
+    cls = _potrf_dtd_classes(device, precision, mb)
+
+    def inserter(tp):
+        insert = tp.insert_task
+        T = {(m, n): tp.tile_of(A, m, n)
+             for m in range(NT) for n in range(m + 1)}
+        # every tile is inserted under the mode its class declares for it
+        (potrf, mp), (trsm, mt), (syrk, ms), (gemm, mg) = (
+            (cls[c], cls[c].modes) for c in ("POTRF", "TRSM", "SYRK", "GEMM"))
+        for k in range(NT - 1):
+            # the panel inverse is always f32, even when tiles store bf16
+            W = tp.tile_arena((mb, mb), np.float32)
+            insert(potrf, (T[k, k], mp[0]), (W, mp[1]),
+                   priority=_priority("POTRF", NT, k))
+            pr = _priority("TRSM", NT, k)
+            for m in range(k + 1, NT):
+                insert(trsm, (W, mt[0]), (T[m, k], mt[1]), priority=pr)
+            ps, pg = _priority("SYRK", NT, k), _priority("GEMM", NT, k)
+            for m in range(k + 1, NT):
+                insert(syrk, (T[m, m], ms[0]), (T[m, k], ms[1]), priority=ps)
+                for n in range(k + 1, m):
+                    insert(gemm, (T[m, n], mg[0]), (T[m, k], mg[1]),
+                           (T[n, k], mg[2]), priority=pg)
+        # the last diagonal tile has no panel below it: no inverse
+        last = cls["POTRFL"]
+        insert(last, (T[NT - 1, NT - 1], last.modes[0]),
+               priority=_priority("POTRFL", NT, NT - 1))
+        tp.data_flush_all()
+
+    return DTDTaskpool("potrf_dtd", inserter=inserter)
 
 
 def potrf_flops(n: int) -> float:

@@ -188,6 +188,9 @@ class Context:
         #: its own probe here
         from parsec_tpu.prof.pins import no_span_sink
         self._span_live = no_span_sink
+        #: counters of the DTD pools that terminated here (a DTDStats,
+        #: made by the first such pool: dsl/dtd/insert.py)
+        self.dtd_stats = None
         #: transient-task retry budget, cached off the worker hot path
         #: (core/scheduling.task_progress probes it per task)
         self._retry_max = int(params.get("task_retry_max", 0))

@@ -237,6 +237,11 @@ def stage_in_host(task: Task) -> None:
             # a chain-held device task's output reached a CPU body:
             # dispatch the held chain now (devices/xla.py Deferred)
             copy.payload = p.force()
+        elif copy.flags & FLAG_SCRATCH and isinstance(p, np.ndarray) \
+                and not p.flags.writeable:
+            # an unbacked NEW tile (Arena.unbacked_buffer) reached a
+            # host body after all: back it now
+            copy.payload = np.zeros(p.shape, p.dtype)
         datum = copy.data
         with datum._lock:
             if copy.flags & FLAG_COW:

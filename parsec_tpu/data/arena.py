@@ -51,10 +51,21 @@ class Arena:
             if len(self._free) < self._max:
                 self._free.append(buf)
 
-    def get_copy(self, data: Optional[Data] = None, device: int = 0) -> DataCopy:
+    def unbacked_buffer(self) -> np.ndarray:
+        """What a copy holds whose first writer is expected on a device:
+        the arena's shape, dtype and nbytes over ONE element (a
+        zero-stride, read-only view), so a tile that is materialized in
+        device memory (XlaDevice._stage_in) never costs a host buffer.
+        A host body that comes to write it gets a real buffer at its
+        stage-in (engine.stage_in_host)."""
+        return np.broadcast_to(np.zeros((), self.dtype), self.shape)
+
+    def get_copy(self, data: Optional[Data] = None, device: int = 0,
+                 backed: bool = True) -> DataCopy:
         """Allocate a fresh arena-backed copy, optionally attached to a datum
-        (reference: parsec_arena_get_copy, arena.h:136)."""
-        buf = self.get_buffer()
+        (reference: parsec_arena_get_copy, arena.h:136).  ``backed`` False
+        hands out the copy over :meth:`unbacked_buffer`."""
+        buf = self.get_buffer() if backed else self.unbacked_buffer()
         if data is None:
             data = Data(nb_elts=self.elt_size)
         copy = DataCopy(data, device, payload=buf,
@@ -75,9 +86,11 @@ class Arena:
             buf, copy.payload = copy.payload, None
             if buf is not None:
                 copy.coherency = Coherency.INVALID
-        if buf is None:
+        if buf is None or (isinstance(buf, np.ndarray)
+                           and not buf.flags.writeable):
             return    # already released (idempotent: multiple lifetime
-                      # managers may race to the same conclusion)
+                      # managers may race to the same conclusion), or
+                      # never backed: nothing for the freelist
         self.release_buffer(buf)
 
     # -- repo-entry holds (reference: refcounted repo copies,

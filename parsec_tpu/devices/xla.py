@@ -666,6 +666,13 @@ _chain_jit_lock = threading.Lock()
 _chain_jit_cache: Dict[Any, Any] = {}
 
 
+def _declared_successor(fuse_chain) -> Optional[str]:
+    """The successor class of a ``fuse_chain`` = (flow, successor class)
+    property; None where a bare flow name takes any consumer."""
+    return fuse_chain[1] if isinstance(fuse_chain, (tuple, list)) \
+        and len(fuse_chain) > 1 else None
+
+
 def _chain_jitted(key, node_specs, node_descs, wave_spec, wave_descs,
                   donate=()):
     """One XLA program executing the held chain nodes in topological
@@ -1352,8 +1359,14 @@ class XlaDevice(Device):
         flow = task.task_class.flow(flow_name)
         if flow is None:
             return False
-        from parsec_tpu.core.task import ToTask
         ici = ctx.ici
+        expects = getattr(tp, "expects_successor", None)
+        if expects is not None:
+            # a discovered graph (dsl/dtd): the flows declare no
+            # successor, the pool knows which were inserted.  Several
+            # chips: nobody has looked where discovery places a chain
+            return ici is None and expects(task, _declared_successor(fc))
+        from parsec_tpu.core.task import ToTask
         found = False
         try:
             for dep in flow.active_outputs(task.locals):
@@ -1394,9 +1407,8 @@ class XlaDevice(Device):
         h.flat = list(flat)
         h.state = "held"
         # (flow, successor class); a bare flow name takes any consumer
-        fc = task.task_class.properties["fuse_chain"]
-        h.succ = fc[1] if isinstance(fc, (tuple, list)) and len(fc) > 1 \
-            else None
+        h.succ = _declared_successor(
+            task.task_class.properties["fuse_chain"])
         import time as _time
         h.deadline = _time.monotonic() + _HOLD_PATIENCE_S
         h.outputs = {}

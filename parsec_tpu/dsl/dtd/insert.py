@@ -39,9 +39,11 @@ from parsec_tpu.core.errors import PeerFailedError
 from parsec_tpu.core.task import (Flow, HookReturn, Task, TaskClass,
                                   normalize_body_outputs)
 from parsec_tpu.core.taskpool import Taskpool
+from parsec_tpu.data.arena import Arena
 from parsec_tpu.data.collection import DataCollection, DataRef
 from parsec_tpu.data.data import (ACCESS_READ, ACCESS_RW, ACCESS_WRITE,
-                                  Coherency, Data, new_data)
+                                  Coherency, Data, FLAG_SCRATCH, new_data)
+from parsec_tpu.prof.pins import open_span
 from parsec_tpu.utils.mca import params
 from parsec_tpu.utils.output import warning
 
@@ -180,10 +182,20 @@ class DTDTile:
     tile on the wire)."""
 
     __slots__ = ("data", "last_writer", "readers", "home_rank", "version",
-                 "wire_key", "v0_sent", "lanes", "applied_ver")
+                 "wire_key", "v0_sent", "lanes", "applied_ver",
+                 "home_space")
 
-    def __init__(self, data: Data, home_rank: int = 0, wire_key: Any = None):
+    def __init__(self, data: Data, home_rank: int = 0, wire_key: Any = None,
+                 home_space: Optional[int] = None):
         self.data = data
+        #: the memory space a flush returns the tile to: where the pool
+        #: found its newest valid copy (0 = the host; a tile born on a
+        #: device is at home there and a flush moves nothing); -1 for
+        #: a NEW tile of the pool's arenas, which has no home
+        if home_space is None:
+            newest = data.newest_copy()
+            home_space = newest.device if newest is not None else 0
+        self.home_space = home_space
         self.last_writer: Optional["_DTDState"] = None
         self.readers: List["_DTDState"] = []
         self.home_rank = home_rank
@@ -234,7 +246,7 @@ class _DTDState:
     __slots__ = ("task", "remaining", "successors", "done", "affinity",
                  "rank", "is_recv", "needed", "tile", "version", "payload",
                  "remote_sends", "pushout", "region", "local_writes",
-                 "insert_pos")
+                 "insert_pos", "bound")
 
     def __init__(self, task: Optional[Task], rank: int = 0):
         self.task = task
@@ -245,7 +257,14 @@ class _DTDState:
         self.rank = rank
         self.is_recv = False
         self.needed = False
-        self.pushout: List["DTDTile"] = []
+        # containers few tasks ever fill start as shared empties: a task
+        # discovered long before it runs lives long enough to reach the
+        # collector's oldest generation, and every container it carries
+        # brings the next full collection nearer (PERF.md §6, PR 33)
+        self.pushout: Sequence["DTDTile"] = ()
+        #: (flow name, tile) of every tile argument: bound to a copy
+        #: when the task becomes ready (DTDTaskpool._bind), dropped then
+        self.bound: Sequence[Tuple[str, "DTDTile"]] = ()
         self.tile: Optional[DTDTile] = None
         self.version = 0
         self.payload: Optional[np.ndarray] = None
@@ -253,7 +272,7 @@ class _DTDState:
         #: selects the slice extent its payload applies into
         self.region: Any = None
         #: (dst_rank, tile, version, lane) payloads to ship at completion
-        self.remote_sends: set = set()
+        self.remote_sends: Any = ()
         #: (tile, version, lane) writes this task performs locally —
         #: dynamic_release advances each tile's applied_ver from them
         #: once the body has actually run
@@ -268,12 +287,54 @@ class _DTDState:
 _seq = itertools.count()
 
 
+class DTDStats:
+    """Counters of the discovery front end, at the boundaries of its
+    spans (``dtd.insert``, ``dtd.window_wait``): on every pool
+    (``DTDTaskpool.stats``), and summed over a context's terminated
+    pools on ``Context.dtd_stats`` (scraped as ``parsec_dtd_*_total``)."""
+
+    __slots__ = ("inserted_tasks", "window_waits", "tracked_tiles",
+                 "new_tiles")
+
+    def __init__(self):
+        #: tasks inserted for execution on this rank
+        self.inserted_tasks = 0
+        #: inserts that found the window full and blocked
+        self.window_waits = 0
+        #: tiles of collections (or raw Data) the pool tracked
+        self.tracked_tiles = 0
+        #: tiles the pool made itself (tile_new, tile_arena)
+        self.new_tiles = 0
+
+    def add(self, other: "DTDStats") -> None:
+        for k in self.__slots__:
+            setattr(self, k, getattr(self, k) + getattr(other, k))
+
+    def as_dict(self) -> Dict[str, int]:
+        return {k: getattr(self, k) for k in self.__slots__}
+
+
 class DTDTaskpool(Taskpool):
     """Taskpool populated by ``insert_task`` calls
-    (reference: parsec_dtd_taskpool_new, insert_function.c:1412)."""
+    (reference: parsec_dtd_taskpool_new, insert_function.c:1412).
 
-    def __init__(self, name: str = "dtd"):
+    ``inserter(pool)``, where given, is the pool's insert stream: it runs
+    on the thread that starts the pool (``Context.start`` /
+    ``Context.wait``: the reference's main-thread model), once the pool
+    is attached, and the pool's hold is dropped where it returns — so
+    such a pool goes through ``Context.add_taskpool`` + ``Context.wait``
+    like any other and needs no ``wait()`` of its own."""
+
+    def __init__(self, name: str = "dtd",
+                 inserter: Optional[Callable[["DTDTaskpool"], None]] = None):
         super().__init__(name=name)
+        self._inserter = inserter
+        self.stats = DTDStats()
+        #: (shape, dtype) -> the arena tile_arena draws from
+        self._arenas: Dict[Any, Arena] = {}
+        #: a flush asked for while tasks were in flight: made at
+        #: termination, when no writer can still be running
+        self._flush_pending = False
         self._dep_lock = threading.Lock()
         self._tiles: Dict[Any, DTDTile] = {}   # guarded-by: _dep_lock, _window
         #: guarded-by: _dep_lock, _window
@@ -352,6 +413,9 @@ class DTDTaskpool(Taskpool):
         termdet.taskpool_addto_runtime_actions(self, 1)
         self.myrank = context.rank
         self.nranks = context.nranks
+        if context.dtd_stats is None:
+            context.dtd_stats = DTDStats()
+        self.on_complete(self._at_termination)
         if self.nranks > 1 and context.comm is not None:
             context.comm.dtd_drain_backlog(self)
             # flush home AT TERMINATION (before _taskpool_terminated
@@ -366,6 +430,35 @@ class DTDTaskpool(Taskpool):
     def _flush_on_complete(self, tp) -> None:
         if not self.cancelled and self._finished:
             self._flush_home()
+
+    def _at_termination(self, tp) -> None:
+        self.context.dtd_stats.add(self.stats)
+        if self._flush_pending and not self.cancelled:
+            self._flush_tiles()
+
+    def startup(self) -> List[Task]:
+        """Run the pool's inserter on the starting thread; the end of
+        the insert stream drops the pool's hold, as ``wait()`` does for
+        a pool inserted into by hand.  An inserter that raises is the
+        context's error (``Context.wait`` raises it); the hold is dropped
+        all the same, so nothing waits for the rest of the stream."""
+        if self._inserter is not None and not self._finished:
+            es = self.context.streams[0]
+            span = open_span(es, "dtd.insert", pool=self.taskpool_id)
+            n0 = self.stats.inserted_tasks
+            try:
+                self._inserter(self)
+            except Exception as exc:
+                self.context.record_pool_error(self, exc)
+            finally:
+                span.end(n=self.stats.inserted_tasks - n0)
+                self._end_of_stream()
+        return []
+
+    def _end_of_stream(self) -> None:
+        if not self._finished:
+            self._finished = True
+            self.termdet.taskpool_addto_runtime_actions(self, -1)
 
     def recovery_reset(self) -> None:
         """Recovery restart (core/recovery.py): drop every lane/window/
@@ -421,9 +514,7 @@ class DTDTaskpool(Taskpool):
         if self.context is None:
             raise RuntimeError("taskpool not attached to a context")
         self.context.start()
-        if not self._finished:
-            self._finished = True
-            self.termdet.taskpool_addto_runtime_actions(self, -1)
+        self._end_of_stream()
         import time
         deadline = None if timeout is None else time.monotonic() + timeout
         while not self.wait_local(0.1):
@@ -628,6 +719,7 @@ class DTDTaskpool(Taskpool):
                 t = DTDTile(datum, home_rank=home, wire_key=wire)
                 self._tiles[key] = t
                 self._tiles_by_wire[wire] = t
+                self.stats.tracked_tiles += 1
             return t
 
     def tile_new(self, shape: Tuple[int, ...], dtype: Any = np.float32,
@@ -636,8 +728,31 @@ class DTDTaskpool(Taskpool):
         Distributed pools must call this identically on every rank (SPMD
         insertion); ``home_rank`` owns the final flushed value."""
         datum = new_data(np.zeros(shape, dtype), key=key)
+        return self._adopt_new(datum, home_rank, home_space=0)
+
+    def tile_arena(self, shape: Tuple[int, ...],
+                   dtype: Any = np.float32) -> DTDTile:
+        """A NEW tile of the pool's arena of that shape, as a PTG
+        ``NEW`` flow is one: its content is undefined until its first
+        writer has run, and where that writer runs on a device the tile
+        is materialized in device memory (``XlaDevice._stage_in``) — no
+        host buffer is allocated for it and nothing crosses the host
+        link.  It has no home: no flush moves it, and
+        ``XlaDevice.discard_scratch`` drops what is left of it."""
+        akey = (tuple(shape), np.dtype(dtype).str)
+        arena = self._arenas.get(akey)
+        if arena is None:
+            arena = self._arenas[akey] = Arena(tuple(shape), dtype)
+        copy = arena.get_copy(backed=False)
+        copy.flags |= FLAG_SCRATCH
+        return self._adopt_new(copy.data, self.myrank, home_space=-1)
+
+    def _adopt_new(self, datum: Data, home_rank: int,
+                   home_space: int) -> DTDTile:
         wire = ("n", next(self._new_seq))
-        t = DTDTile(datum, home_rank=home_rank, wire_key=wire)
+        t = DTDTile(datum, home_rank=home_rank, wire_key=wire,
+                    home_space=home_space)
+        self.stats.new_tiles += 1
         with self._dep_lock:
             if self._lineage is not None and self._skip_note is None:
                 # _new_seq is not reset across a restart, so replayed
@@ -650,14 +765,28 @@ class DTDTaskpool(Taskpool):
         return t
 
     def data_flush_all(self) -> None:
-        """Push every tracked tile home to its host copy
-        (reference: parsec_dtd_data_flush_all).  Pulls device copies to
-        the LOCAL host; the cross-rank flush home to each tile's owner
-        happens at ``wait()`` (_flush_home), once no writer can still be
-        in flight — flushing a tile another rank is mid-writing would be
-        a torn flush."""
+        """Return every tracked tile to its home
+        (reference: parsec_dtd_data_flush_all): a tile the pool found on
+        the host is pulled back to its host copy; a tile it found on a
+        device (``DTDTile.home_space``) is at home where its last writer
+        left it, and a NEW arena tile has none, so neither moves.  The
+        flush is the ordering point of the insert stream: asked for
+        while tasks are in flight (from an inserter, or before
+        ``wait()``) it is made at the pool's termination, once no
+        writer can still be running — flushing a tile mid-write would
+        be a torn flush.  The cross-rank flush home to each tile's owner
+        happens at ``wait()`` (_flush_home)."""
+        es = self.context.streams[0] if self.context is not None else None
+        with open_span(es, "dtd.flush", pool=self.taskpool_id):
+            if self.context is not None and not self.completed:
+                self._flush_pending = True
+            else:
+                self._flush_tiles()
+
+    def _flush_tiles(self) -> None:
+        self._flush_pending = False
         with self._dep_lock:
-            tiles = list(self._tiles.values())
+            tiles = [t for t in self._tiles.values() if t.home_space == 0]
         for t in tiles:
             t.data.pull_to_host()
 
@@ -922,7 +1051,9 @@ class DTDTaskpool(Taskpool):
         self.task_classes[f"{tc.name}#{tc.task_class_id}"] = tc
 
     def create_task_class(self, name: str, arg_names: Sequence[str],
-                          modes: Sequence[_Mode]) -> "DTDTaskClass":
+                          modes: Sequence[_Mode],
+                          properties: Optional[Dict[str, Any]] = None
+                          ) -> "DTDTaskClass":
         """Explicit task-class API (reference:
         parsec_dtd_create_task_classv, insert_function.c:2539 area):
         declare the argument layout once, attach one chore per device
@@ -930,14 +1061,11 @@ class DTDTaskpool(Taskpool):
         :meth:`insert_task` in place of a function.  One logical task
         can carry CPU and TPU chores; the runtime picks per execution
         (the incarnation iteration of scheduling.execute)."""
-        if len(arg_names) != len(modes):
-            raise ValueError("one name per argument mode")
-        return DTDTaskClass(name, list(arg_names),
-                            [m.base for m in modes])
+        return create_task_class(name, arg_names, modes, properties)
 
 
-    def _cpu_hook(self, fn: Callable, names: List[str],
-                  writable: List[str]):
+    @staticmethod
+    def _cpu_hook(fn: Callable, names: List[str], writable: List[str]):
         def hook(es, task):
             args = []
             for i, n in enumerate(names):
@@ -956,15 +1084,19 @@ class DTDTaskpool(Taskpool):
                 copy = task.data.get(fname)
                 if copy is None:
                     continue
-                if isinstance(copy.payload, np.ndarray):
+                if isinstance(copy.payload, np.ndarray) \
+                        and copy.payload.flags.writeable:
                     np.copyto(copy.payload, np.asarray(value))
                 else:
                     copy.payload = value
             return None
         return hook
 
-    def _device_hook(self, fn: Callable, names: List[str], flows, writable,
+    @staticmethod
+    def _device_hook(fn: Callable, names: List[str], flows, writable,
                      cls: Optional[str] = None):
+        """The device incarnation of ``fn``: bound to no pool (it goes
+        by the task's), so that a class made once serves every pool."""
         from parsec_tpu.devices.xla import XlaKernel
         spec = XlaKernel(fn, names, [f.name for f in flows], writable,
                          cls=cls)
@@ -980,7 +1112,8 @@ class DTDTaskpool(Taskpool):
                 if task.dtd is not None else None
             if aff is not None and not isinstance(aff, (int, np.integer)):
                 try:
-                    pref = self._as_tile(aff).data.preferred_device
+                    pref = task.taskpool._as_tile(
+                        aff).data.preferred_device
                 except TypeError:
                     pref = None
                 if pref is not None and 1 <= pref < len(reg.devices) \
@@ -1069,13 +1202,17 @@ class DTDTaskpool(Taskpool):
             # the threshold (reference: dtd_window_size/threshold,
             # insert_function.h:131-141)
             if self._inflight >= self.window_size:
-                while self._inflight >= self.threshold:
-                    self._raise_context_error()
-                    self._window.wait(0.1)
+                self.stats.window_waits += 1
+                with open_span(self.context.streams[0], "dtd.window_wait",
+                               inflight=self._inflight):
+                    while self._inflight >= self.threshold:
+                        self._raise_context_error()
+                        self._window.wait(0.1)
 
         # parse/validate args FIRST: raising after the nb_tasks increment
         # would leave the count high forever and hang wait() (ADVICE r1)
         tracked: List[Tuple[DTDTile, _Mode, Any]] = []
+        bound: List[Tuple[str, DTDTile]] = []
         for i, (value, mode, flags, region) in enumerate(nargs):
             name = names[i]
             if mode is VALUE:
@@ -1088,7 +1225,7 @@ class DTDTaskpool(Taskpool):
                 task.data[name] = datum.copy_on(0)
             elif mode in (INPUT, OUTPUT, INOUT, DONT_TRACK):
                 tile = self._as_tile(value)
-                task.data[name] = tile.data.copy_on(0)
+                bound.append((name, tile))
                 if mode is not DONT_TRACK:
                     tracked.append((tile, mode,
                                     region.rid if region is not None
@@ -1097,11 +1234,13 @@ class DTDTaskpool(Taskpool):
                     # force the result home at completion instead of
                     # staying producer/device-resident until a flush
                     # (reference: PARSEC_PUSHOUT)
-                    state.pushout.append(tile)
+                    state.pushout = (*state.pushout, tile)
             else:
                 raise TypeError(f"unsupported arg mode {mode!r}")
 
+        state.bound = bound
         self.termdet.taskpool_addto_nb_tasks(self, 1)
+        self.stats.inserted_tasks += 1
         to_schedule: List[Task] = []
         with self._dep_lock:
             self._inflight += 1
@@ -1113,8 +1252,44 @@ class DTDTaskpool(Taskpool):
             if state.remaining == 0:
                 to_schedule.append(task)
         if to_schedule:
+            self._bind(to_schedule)
             scheduling.schedule(self.context.streams[0], to_schedule)
         return task
+
+    @staticmethod
+    def _bind(ready: List[Task]) -> None:
+        """Bind each tile argument of the tasks that just became ready
+        to its datum's newest valid copy: every predecessor has
+        published its outputs by now, so that is the copy its last
+        writer left — a device copy for a tile that lives on a device
+        (no host copy is needed, made or read), the host copy for one
+        that lives on the host.  The execution site resolves coherency
+        from there (stage_in_host, the device module's stage-in)."""
+        for task in ready:
+            state = task.dtd
+            for name, tile in state.bound:
+                datum = tile.data
+                copy = datum.newest_copy()
+                task.data[name] = copy if copy is not None \
+                    else datum.copy_on(0)
+            state.bound = ()
+
+    def expects_successor(self, task: Task, cls: Optional[str]) -> bool:
+        """Whether a task of class ``cls`` (any class where None) that
+        depends on ``task`` is known or may still come: one was
+        discovered already, or the insert stream is open.  What the
+        device module asks before it holds a chain head for its
+        declared successor (``XlaDevice._chain_eligible``): under
+        discovery a successor exists only once the inserter is past it."""
+        state = task.dtd
+        if not isinstance(state, _DTDState):
+            return False
+        if not self._finished:
+            return True
+        with self._dep_lock:
+            return any(s.task is not None and
+                       (cls is None or s.task.task_class.name == cls)
+                       for s in state.successors)
 
     # -- distributed placement & remote tracking ---------------------------
     def _task_rank(self, args) -> int:
@@ -1208,6 +1383,8 @@ class DTDTaskpool(Taskpool):
                     if key not in lw.remote_sends:
                         # recorded either way so N readers on one rank
                         # cost ONE payload on the wire
+                        if not lw.remote_sends:
+                            lw.remote_sends = set()
                         lw.remote_sends.add(key)
                         if lw.done:
                             sends.append(key)
@@ -1720,23 +1897,34 @@ class DTDTaskpool(Taskpool):
                 self._window.notify_all()
         for dst, msg in outgoing:
             self._dtd_send_contained(dst, msg)
+        self._bind(ready)
         return ready
 
 
 class DTDTaskClass:
     """User-declared DTD task class with explicit per-device chores
-    (reference: parsec_dtd_create_task_classv + parsec_dtd_add_chore)."""
+    (reference: parsec_dtd_create_task_classv + parsec_dtd_add_chore).
+
+    A class belongs to the process, not to a pool: its chores' hooks and
+    device kernels are built at its first insert and every later pool
+    it is inserted into registers a record of them under the class's
+    name — a solver that declares its classes once builds nothing for
+    its second factorization.  ``properties`` are the runtime's hints,
+    as a PTG class carries them (``flops``, ``fuse_chain``...)."""
 
     def __init__(self, name: str, arg_names: List[str],
-                 modes: List[_Mode]):
+                 modes: List[_Mode],
+                 properties: Optional[Dict[str, Any]] = None):
         self.name = name
         self.arg_names = arg_names
         self.modes = modes
+        self.properties: Dict[str, Any] = dict(properties or {})
         self.chores: List[Tuple[str, Callable]] = []
-        self._tc: Optional[TaskClass] = None
+        #: (argument names, incarnations), built at the first insert
+        self._built: Optional[Tuple[list, list]] = None
 
     def add_chore(self, device: str, fn: Callable) -> "DTDTaskClass":
-        if self._tc is not None:
+        if self._built is not None:
             raise RuntimeError("add_chore after the class was first "
                                "inserted (chore table is frozen)")
         self.chores.append((device, fn))
@@ -1748,37 +1936,56 @@ class DTDTaskClass:
                 f"task class {self.name!r}: insert arg modes {modes} do "
                 f"not match the declared {tuple(self.modes)}")
 
-    def materialize(self, pool: DTDTaskpool) -> TaskClass:
-        if self._tc is not None:
-            if self._tc.taskpool is not pool:
-                raise RuntimeError(
-                    f"task class {self.name!r} is bound to another pool")
-            return self._tc
-        if not self.chores:
-            raise RuntimeError(f"task class {self.name!r} has no chores")
-        names: List[Optional[str]] = [
-            None if mode is AFFINITY else self.arg_names[i]
-            for i, mode in enumerate(self.modes)]
+    def _flows(self, names) -> List[Flow]:
         flows = []
         for i, mode in enumerate(self.modes):
             if mode in (INPUT, OUTPUT, INOUT, DONT_TRACK, SCRATCH):
                 access = mode.access if mode in (INPUT, OUTPUT, INOUT) \
                     else ACCESS_READ
                 flows.append(Flow(names[i], access))
-        writable = [f.name for f in flows if f.access & ACCESS_WRITE]
-        bound = [n for n in names if n is not None]
-        incarnations = []
-        for device, fn in self.chores:
-            if device in ("tpu", "xla", "gpu"):
-                incarnations.append(
-                    (device, pool._device_hook(fn, bound, flows, writable,
-                                               cls=self.name)))
-            else:
-                incarnations.append(
-                    ("cpu", pool._cpu_hook(fn, bound, writable)))
-        tc = TaskClass(self.name, params=[("tid", None)], flows=flows,
-                       incarnations=incarnations)
+        return flows
+
+    def materialize(self, pool: DTDTaskpool) -> TaskClass:
+        tc = pool._classes.get(self)
+        if tc is not None:
+            return tc
+        if self._built is None:
+            if not self.chores:
+                raise RuntimeError(
+                    f"task class {self.name!r} has no chores")
+            names: List[Optional[str]] = [
+                None if mode is AFFINITY else self.arg_names[i]
+                for i, mode in enumerate(self.modes)]
+            flows = self._flows(names)
+            writable = [f.name for f in flows if f.access & ACCESS_WRITE]
+            bound = [n for n in names if n is not None]
+            incarnations = []
+            for device, fn in self.chores:
+                if device in ("tpu", "xla", "gpu"):
+                    incarnations.append(
+                        (device, pool._device_hook(fn, bound, flows,
+                                                   writable, cls=self.name)))
+                else:
+                    incarnations.append(
+                        ("cpu", pool._cpu_hook(fn, bound, writable)))
+            self._built = (names, incarnations)
+        names, incarnations = self._built
+        tc = TaskClass(self.name, params=[("tid", None)],
+                       flows=self._flows(names), incarnations=incarnations,
+                       properties=self.properties)
         tc.dtd_names = names
         pool.add_task_class_dynamic(tc)
-        self._tc = tc
+        pool._classes[self] = tc
         return tc
+
+
+def create_task_class(name: str, arg_names: Sequence[str],
+                      modes: Sequence[_Mode],
+                      properties: Optional[Dict[str, Any]] = None
+                      ) -> DTDTaskClass:
+    """A :class:`DTDTaskClass` that belongs to no pool yet
+    (``DTDTaskpool.create_task_class`` is the same call)."""
+    if len(arg_names) != len(modes):
+        raise ValueError("one name per argument mode")
+    return DTDTaskClass(name, list(arg_names), [m.base for m in modes],
+                        properties)

@@ -655,6 +655,7 @@ class RuntimeMetrics:
         out.extend(self._collect_comm())
         out.extend(self._collect_sched())
         out.extend(self._collect_devices())
+        out.extend(self._collect_dtd())
         out.extend(self._collect_service())
         for fn in list(self._collectors):
             try:
@@ -791,6 +792,15 @@ class RuntimeMetrics:
                 if isinstance(v, (int, float)) and v:
                     out.append(counter_sample(metric, v, labels))
         return out
+
+    def _collect_dtd(self) -> List[dict]:
+        """The discovery front end's counters, summed over the
+        context's terminated DTD pools (dsl/dtd/insert.py DTDStats)."""
+        st = getattr(self.context, "dtd_stats", None)
+        if st is None:
+            return []
+        return [counter_sample(f"parsec_dtd_{k}_total", v)
+                for k, v in st.as_dict().items() if v]
 
     def _collect_service(self) -> List[dict]:
         svc = self._service
