@@ -13,9 +13,9 @@ and ``benchmark/`` are the benchmark (``python -m benchmark.run
 proof that the main path starts on a chip.
 
 ``PARSEC_BENCH_APP`` names the probe (``_AUX_MODES``: tasks, ntasks,
-rtt, bw, aggregate, telemetry, journal, tracer, fabric); anything else,
-or nothing, is an error.  A probe prints exactly ONE JSON line on
-stdout:
+rtt, bw, aggregate, telemetry, journal, tracer, fabric, release);
+anything else, or nothing, is an error.  A probe prints exactly ONE
+JSON line on stdout:
     {"metric": ..., "value": N, "unit": ..., "vs_baseline": N,
      "device": {"platform": ..., "kind": ..., "count": N}, ...}
 ``device`` is what JAX reports for the process; ``vs_baseline`` is the
@@ -735,6 +735,101 @@ def run_fabric_bench(n_jobs: int = 0):
     return n_jobs / dt, extras
 
 
+def _call_counts(codes, fn):
+    """Run ``fn()`` and count the calls of each code object in ``codes``
+    (name -> code) on every thread: ``sys.monitoring`` local events, so
+    only those functions pay for the count."""
+    mon = sys.monitoring
+    tool = mon.PROFILER_ID
+    counts = dict.fromkeys(codes, 0)
+    names = {code: name for name, code in codes.items()}
+
+    def started(code, offset):
+        counts[names[code]] += 1    # under the interpreter lock
+
+    mon.use_tool_id(tool, "bench-release")
+    try:
+        mon.register_callback(tool, mon.events.PY_START, started)
+        for code in names:
+            mon.set_local_events(tool, code, mon.events.PY_START)
+        fn()
+    finally:
+        for code in names:
+            mon.set_local_events(tool, code, 0)
+        mon.register_callback(tool, mon.events.PY_START, None)
+        mon.free_tool_id(tool)
+    return counts
+
+
+def run_release_bench(nt: int = 32, mb: int = 16):
+    """COUNTS a task of the nt = 32 tiled Cholesky DAG (the shape of the
+    benchmark's host-paced cells), the PTG beside the DTD front end, on
+    one CPU device: calls of a ``dsl/ptg/api._named`` by-name adapter
+    (``kwargs`` built by name lookup), calls of its positional form, and
+    locked mutations of a data repo.  Not a speed: a PR that puts the
+    by-name adapter or a repo hold a task back on the release path is
+    seen here without a chip (PERF.md section 6, PR 34: 12.7 by-name
+    calls and 4.7 repo mutations a PTG task before it)."""
+    from parsec_tpu.apps import potrf
+    from parsec_tpu.core.context import Context
+    from parsec_tpu.data.datarepo import DataRepo
+    from parsec_tpu.data.matrix import TwoDimBlockCyclic
+    from parsec_tpu.dsl.ptg import api
+    from parsec_tpu.utils.mca import params
+
+    n = nt * mb
+    rng = np.random.default_rng(0)
+    m = rng.standard_normal((n, n)).astype(np.float32)
+    spd = m @ m.T + n * np.eye(n, dtype=np.float32)
+    by_name = api._named(lambda: 0)
+    codes = {"by_name": by_name.__code__}
+    for i, probe in enumerate((lambda: 0, lambda a: 0, lambda a, b: 0,
+                               lambda a, b, c: 0)):
+        names = frozenset("abc"[:i])
+        codes[f"positional{i}"] = api._positional(
+            api._named(probe), names).__code__
+    for meth in ("lookup_entry_and_create", "entry_addto_usage_limit",
+                 "entry_used_once"):
+        codes[meth] = getattr(DataRepo, meth).__code__
+    out = {}
+    params.set("device_max", 1)
+    try:
+        with Context(nb_cores=int(os.environ.get("PARSEC_BENCH_CORES",
+                                                 4))) as ctx:
+            for front, build in (("ptg", potrf.potrf_taskpool),
+                                 ("dtd", potrf.potrf_dtd_taskpool)):
+                A = TwoDimBlockCyclic(mb=mb, nb=mb, lm=n,
+                                      ln=n).from_array(spd.copy())
+                tp = build(A, device="tpu")
+
+                def job():
+                    ctx.add_taskpool(tp)
+                    ctx.wait(timeout=600)
+                c = _call_counts(codes, job)
+                L = np.tril(A.to_array())
+                err = np.abs(L @ L.T - spd).max() / np.abs(spd).max()
+                if not err < 1e-3:
+                    raise RuntimeError(
+                        f"release bench: {front} factor wrong ({err})")
+                tasks = nt * (nt + 1) * (nt + 2) // 6
+                out[front] = {
+                    "tasks": tasks,
+                    "by_name_calls_per_task":
+                        round(c["by_name"] / tasks, 3),
+                    "positional_calls_per_task": round(sum(
+                        v for k, v in c.items()
+                        if k.startswith("positional")) / tasks, 3),
+                    "repo_mutations_per_task": round(
+                        (c["lookup_entry_and_create"]
+                         + c["entry_addto_usage_limit"]
+                         + c["entry_used_once"]) / tasks, 3),
+                    "release": tp.release_stats.as_dict()}
+    finally:
+        params.unset("device_max")
+    return out["ptg"]["by_name_calls_per_task"], {"release": out,
+                                                  "host": _host_info()}
+
+
 #: mode -> (runner, metric name, unit, self-declared target, "higher is
 #: better").  tools/premerge_bench.sh runs every one but tracer, which
 #: tier-1 runs (tests/test_bench_modes.py).
@@ -753,6 +848,8 @@ _AUX_MODES = {
     "tracer": (run_tracer_bench, "tracer_overhead", "us/task", 1.0, False),
     "fabric": (run_fabric_bench, "fabric_jobs_per_s", "jobs/s",
                10.0, True),
+    "release": (run_release_bench, "ptg_by_name_calls_per_task",
+                "calls/task", 1.0, False),
 }
 
 

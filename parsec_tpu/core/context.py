@@ -21,7 +21,8 @@ from typing import Any, Callable, List, Optional
 
 from parsec_tpu.core import scheduling
 from parsec_tpu.core.task import Task
-from parsec_tpu.core.taskpool import Taskpool, TaskpoolState
+from parsec_tpu.core.taskpool import (ReleaseStats, Taskpool,
+                                      TaskpoolState)
 from parsec_tpu.core import termdet as termdet_mod
 from parsec_tpu.sched import create as create_scheduler
 from parsec_tpu.utils.mca import components, params
@@ -191,6 +192,9 @@ class Context:
         #: counters of the DTD pools that terminated here (a DTDStats,
         #: made by the first such pool: dsl/dtd/insert.py)
         self.dtd_stats = None
+        #: counters of the release walk, summed over the pools that
+        #: terminated here (core/taskpool.py ReleaseStats)
+        self.release_stats = ReleaseStats()
         #: transient-task retry budget, cached off the worker hot path
         #: (core/scheduling.task_progress probes it per task)
         self._retry_max = int(params.get("task_retry_max", 0))

@@ -29,6 +29,8 @@ from parsec_tpu.utils.output import warning
 
 import numpy as np
 
+_TODESC, _NOCLASS = TaskClass._CK_TODESC, TaskClass._CK_NOCLASS
+
 
 class PendingRecord:
     """Dep-countdown record for a not-yet-ready task
@@ -64,14 +66,18 @@ _rec_pool = MemoryPool(factory=lambda: PendingRecord(0, None),
 
 def deliver_dep(taskpool, succ_tc: TaskClass, succ_locals: Dict[str, int],
                 flow_name: str, copy: Optional[DataCopy],
-                source: Optional[Tuple[TaskClass, Tuple]]) -> Optional[Task]:
+                source: Optional[Tuple[TaskClass, Tuple]],
+                key: Optional[Tuple] = None) -> Optional[Task]:
     """Record one dependency arrival at a local successor; return the
-    instantiated Task exactly when it becomes ready."""
-    # dep expressions may address peers by their FREE parameters only;
-    # derived single-value params (JDF derived-local idiom) are filled
-    # here so the instantiated task carries the full local set
-    succ_locals = succ_tc.complete_locals(succ_locals)
-    key = succ_tc.make_key(succ_locals)
+    instantiated Task exactly when it becomes ready.  ``key``, where the
+    caller has it (the release walk), says ``succ_locals`` are complete
+    and is theirs."""
+    if key is None:
+        # dep expressions may address peers by their FREE parameters
+        # only; derived single-value params (JDF derived-local idiom)
+        # are filled here so the instantiated task carries the full
+        # local set
+        succ_locals, key = succ_tc.locate(succ_locals)
 
     nd = taskpool._native_deps
     if nd is not None:
@@ -357,23 +363,30 @@ def _writeback(task: Task, flow: Flow, copy: DataCopy, ref,
 
 
 def release_deps(es, task: Task) -> List[Task]:
-    """Evaluate output deps of a completed task, deliver to successors,
-    manage repo lifetime; return newly-ready local tasks
-    (reference: generated release_deps + iterate_successors,
-    jdf2c.c:7175,7631 -> parsec.c:1783)."""
+    """Hand a completed task's outputs on: ONE walk over its class's
+    release plan (``TaskClass.release_plan``: each output dep's kind,
+    successor class, flow, write bit and whether either end declares a
+    datatype, settled once a class); deliver to successors, manage repo
+    lifetime; return newly-ready local tasks (reference: generated
+    release_deps + iterate_successors, jdf2c.c:7175,7631 ->
+    parsec.c:1783).
+
+    What the pool and its context are is read once a release.  A
+    delivery asks nothing further where the context is one rank without
+    a comm engine (no successor is remote: the affinity is not
+    evaluated), no grapher listens and no replay filter is set; each of
+    those, and an edge with a ``dtt``, is a general arm of the same walk
+    (``ReleaseStats.general_deliveries`` counts the deliveries that took
+    one)."""
     tp = task.taskpool
     tc = task.task_class
-    myrank = tp.context.rank if tp.context else 0
-    grapher = tp.context.grapher if tp.context else None
-    ready: List[Task] = []
-    consumers = 0
-    entry = None
-    #: arena-backed copies whose only consumers are remote: nothing local
-    #: creates a repo entry for them, so they are returned to the freelist
-    #: once flush_activations has serialized the payload (ADVICE r1: the
-    #: QR NEW-temporary leak on distributed runs)
-    remote_only_arena: List[DataCopy] = []
-
+    ctx = tp.context
+    if ctx is not None:
+        myrank, grapher, ici, comm = ctx.rank, ctx.grapher, ctx.ici, ctx.comm
+        one_rank = comm is None and ctx.nranks == 1
+    else:
+        myrank, grapher, ici, comm = 0, None, None, None
+        one_rank = True
     #: minimal-replay restart gate (core/recovery.py): local deliveries
     #: to consumers outside the replay plan are redundant re-sends of
     #: already-materialized work — skipping them HERE (not in
@@ -381,104 +394,143 @@ def release_deps(es, task: Task) -> List[Task]:
     #: producer's entry still retires.  Remote activations always fire;
     #: the receiving rank's own filter decides there.
     replay_filter = tp._replay_filter
+    plain = one_rank and grapher is None and replay_filter is None
+    pins_cbs = es._pins_map.get("deliver_dep")
+    locals_ = task.locals
+    data = task.data
+    ready: List[Task] = []
+    consumers = 0
+    entry = None
+    n_deliveries = n_general = 0
+    #: arena-backed copies whose only consumers are remote: nothing local
+    #: creates a repo entry for them, so they are returned to the freelist
+    #: once flush_activations has serialized the payload (ADVICE r1: the
+    #: QR NEW-temporary leak on distributed runs)
+    remote_only_arena: List[DataCopy] = []
 
-    # only flows with output deps can deliver anything (class-level
-    # partition, core/task.py): a CTL-only or sink flow skips the whole
-    # delivery bookkeeping below
-    for flow in tc._out_flows:
-        copy = task.data.get(flow.name)
+    # only flows with output deps are in the plan (class-level partition,
+    # core/task.py): a CTL-only or sink flow skips the whole delivery
+    # bookkeeping below
+    for flow_name, flow_index, access, deps, flow in tc.release_plan():
+        copy = data.get(flow_name)
         # gather this flow's local deliveries first: a copy fanning out to
         # several consumers must hand any WRITE-consumer a copy-on-write
         # duplicate, or its in-place update races the other readers
         # (reference: data-copy duplication for RW flows on shared copies)
         local_deliveries: List[Tuple] = []
         remote_count = 0
-        for dep in flow.active_outputs(task.locals):
-            end = dep.end
-            if isinstance(end, ToDesc):
+        for guard, kind, payload in deps:
+            if guard is not None and not guard(locals_):
+                continue
+            if kind == _TODESC:
                 if copy is not None:
-                    _writeback(task, flow, copy, end.ref_fn(task.locals),
-                               dtt=as_dtt(dep.dtt))
-            elif isinstance(end, ToTask):
-                succ_tc = tp.task_classes[end.task_class]
-                for succ_locals in end.instances(task.locals):
-                    # dep expressions address peers by free params; fill
-                    # derived ones NOW — rank_of/make_key below may need
-                    # them (e.g. an affinity over a derived local)
-                    succ_locals = succ_tc.complete_locals(succ_locals)
+                    _writeback(task, flow, copy, payload[0](locals_),
+                               dtt=payload[1])
+                continue
+            if kind == _NOCLASS:
+                raise KeyError(
+                    f"{task}: flow {flow_name} feeds task class "
+                    f"{payload.task_class!r}, which the taskpool lacks")
+            end, succ_tc, dflow, succ_write, dep, edge_dtt = payload
+            insts = end.params_fn(locals_)
+            if not isinstance(insts, (list, tuple)):
+                insts = (insts,)
+            n_deliveries += len(insts)
+            if edge_dtt or not plain:
+                n_general += len(insts)
+            for succ_locals in insts:
+                # dep expressions address peers by free params; derived
+                # ones are filled NOW, and the key made, once a delivery
+                succ_locals, key = succ_tc.locate(succ_locals)
+                if not plain:
                     if grapher is not None:
-                        grapher.edge(task, succ_tc.make_key(succ_locals),
-                                     flow.name)
-                    if succ_tc.rank_of(succ_locals) != myrank:
-                        tp.context.remote_dep_activate(
+                        grapher.edge(task, key, flow_name)
+                    if not one_rank and \
+                            succ_tc.rank_of(succ_locals) != myrank:
+                        ctx.remote_dep_activate(
                             es, task, flow, dep, succ_tc, succ_locals, copy)
                         remote_count += 1
                         continue
                     if replay_filter is not None and \
-                            succ_tc.make_key(succ_locals) \
-                            not in replay_filter:
+                            key not in replay_filter:
                         continue   # consumer not re-enumerated (minimal)
-                    local_deliveries.append(
-                        (succ_tc, succ_locals, end.flow, dep))
-            # Null outputs: data is discarded (arena copies will be
-            # released by the repo retirement below, or were views)
+                local_deliveries.append(
+                    (succ_tc, succ_locals, dflow, dep, key, succ_write,
+                     edge_dtt))
         total = len(local_deliveries) + remote_count
-        if copy is None and total > 0 and flow.access != 0:
-            # a data (non-CTL) flow handing None downstream: legal — the
-            # successor's input binds NULL — but almost always a graph
-            # bug, so flag it like the reference does (ptgpp
-            # forward_{READ,RW}_NULL golden behavior)
-            warning("A NULL is forwarded from %s flow %s to %d "
-                    "successor(s)", task, flow.name, total)
-        if remote_count and not local_deliveries and copy is not None \
-                and copy.arena is not None:
-            remote_only_arena.append(copy)
-        ici = tp.context.ici if tp.context is not None else None
-        if copy is not None and ici is not None and local_deliveries \
-                and (len(local_deliveries) > 1
-                     or ici.device_resident(copy)):
-            # the flow may leave this chip: ici counts the consumers of
-            # each other chip and moves the tile (comm/ici.py fan_out).
-            # Host-resident single-consumer edges — the dominant
-            # same-device case — skip the affinity resolution entirely;
-            # multi-consumer fan-outs qualify even from host (one
-            # replication beats N separate stage-ins).
-            ici.fan_out(tp, copy, local_deliveries)
-        for succ_tc, succ_locals, dflow, odep in local_deliveries:
+        if copy is None:
+            if total > 0 and access != 0:
+                # a data (non-CTL) flow handing None downstream: legal —
+                # the successor's input binds NULL — but almost always a
+                # graph bug, so flag it like the reference does (ptgpp
+                # forward_{READ,RW}_NULL golden behavior)
+                warning("A NULL is forwarded from %s flow %s to %d "
+                        "successor(s)", task, flow_name, total)
+            hold = False
+        else:
+            # a repo entry, the source a consumer records and its
+            # entry_used_once keep an ARENA buffer off the freelist until
+            # the last reader is done; a copy without one (a tile of the
+            # collection, alive through the tasks that bind it) takes
+            # none of the three
+            hold = copy.arena is not None
+            if hold and remote_count and not local_deliveries:
+                remote_only_arena.append(copy)
+            if ici is not None and local_deliveries \
+                    and (len(local_deliveries) > 1
+                         or ici.device_resident(copy)):
+                # the flow may leave this chip: ici counts the consumers
+                # of each other chip and moves the tile (comm/ici.py
+                # fan_out).  Host-resident single-consumer edges — the
+                # dominant same-device case — skip the affinity
+                # resolution entirely; multi-consumer fan-outs qualify
+                # even from host (one replication beats N separate
+                # stage-ins).
+                ici.fan_out(tp, copy, local_deliveries)
+        src = (tc, task.key) if hold else None
+        for succ_tc, succ_locals, dflow, dep, key, succ_write, edge_dtt \
+                in local_deliveries:
             dcopy = copy
             if copy is not None:
-                # edge datatype: the consumer's IN dtt wins, else the
-                # producer's OUT dtt (reference: receiver-side datatype
-                # lookup, remote_dep_get_datatypes)
-                edge_dtt = _edge_dtt(succ_tc, dflow, succ_locals) \
-                    or as_dtt(odep.dtt)
-                if edge_dtt is not None and needs_reshape(copy, edge_dtt):
-                    dcopy = tp.reshape.get_copy(copy, edge_dtt)
-            if dcopy is not None and total > 1 and \
-                    succ_tc.flow(dflow).access & ACCESS_WRITE:
-                dcopy = _cow_copy(dcopy)
-            if entry is None and copy is not None:
-                entry = tc.repo.lookup_entry_and_create(task.key)
-            if copy is not None:
-                if entry.copies[flow.flow_index] is not copy \
-                        and copy.arena is not None:
-                    # entry hold on the arena buffer: a NEW-flow copy
-                    # chained through several tasks lives in every
-                    # producer's entry, and only the LAST retirement may
-                    # return it to the freelist (reference: refcounted
-                    # repo copies, datarepo.h:50-58)
-                    copy.arena.retain_copy(copy)
-                entry.copies[flow.flow_index] = copy
-                consumers += 1
-            src = (tc, task.key) if copy is not None else None
-            es.pins("deliver_dep", (task, succ_tc, succ_locals, dflow))
-            t = deliver_dep(tp, succ_tc, succ_locals, dflow, dcopy, src)
+                if edge_dtt:
+                    # edge datatype: the consumer's IN dtt wins, else the
+                    # producer's OUT dtt (reference: receiver-side
+                    # datatype lookup, remote_dep_get_datatypes)
+                    dtt = _edge_dtt(succ_tc, dflow, succ_locals) \
+                        or as_dtt(dep.dtt)
+                    if dtt is not None and needs_reshape(copy, dtt):
+                        dcopy = tp.reshape.get_copy(copy, dtt)
+                if total > 1 and succ_write:
+                    dcopy = _cow_copy(dcopy)
+                if hold:
+                    if entry is None:
+                        entry = tc.repo.lookup_entry_and_create(task.key)
+                    if entry.copies[flow_index] is not copy:
+                        # entry hold on the arena buffer: a NEW-flow copy
+                        # chained through several tasks lives in every
+                        # producer's entry, and only the LAST retirement
+                        # may return it to the freelist (reference:
+                        # refcounted repo copies, datarepo.h:50-58)
+                        copy.arena.retain_copy(copy)
+                        entry.copies[flow_index] = copy
+                    consumers += 1
+            if pins_cbs:
+                for cb in pins_cbs:
+                    cb(es, "deliver_dep", (task, succ_tc, succ_locals, dflow))
+            t = deliver_dep(tp, succ_tc, succ_locals, dflow, dcopy, src, key)
             if t is not None:
                 ready.append(t)
 
     if entry is not None:
         entry.on_retire = _make_retire(task)
         tc.repo.entry_addto_usage_limit(task.key, consumers)
+    if n_deliveries:
+        stats = tp.release_stats
+        stats.deliveries += n_deliveries
+        if n_general:
+            stats.general_deliveries += n_general
+        if entry is not None:
+            stats.repo_holds += 1
 
     # dynamically-discovered pools (DTD) resolve successors from their
     # runtime dep graph rather than from flow expressions
@@ -489,8 +541,8 @@ def release_deps(es, task: Task) -> List[Task]:
     # ship buffered remote activations as one message per flow down the
     # bcast tree (reference: parsec_remote_dep_activate after
     # iterate_successors filled the rank bitmask)
-    if tp.context is not None and tp.context.comm is not None:
-        tp.context.comm.flush_activations(es, task)
+    if comm is not None:
+        comm.flush_activations(es, task)
         # flush serialized every outgoing payload synchronously: arena
         # temporaries with no local consumer can go home now — unless an
         # earlier producer's repo entry still holds the chained buffer

@@ -24,6 +24,7 @@ from typing import (Any, Callable, Dict, Iterable, List, Optional, Sequence,
 from parsec_tpu.data.data import (ACCESS_NONE, ACCESS_READ, ACCESS_RW,
                                   ACCESS_WRITE, DataCopy)
 from parsec_tpu.data.collection import DataRef
+from parsec_tpu.data.reshape import as_dtt
 
 
 class HookReturn(IntEnum):
@@ -258,6 +259,9 @@ class TaskClass:
         self._ft_inputs = [d for f in self.flows for d in f.inputs
                            if isinstance(d.end, FromTask)]
         self._param_names = tuple(p for p, _ in self.params)
+        self._param_set = frozenset(self._param_names)
+        #: the release plan (release_plan()), resolved at the first ask
+        self._release_plan = None
         self.incarnations = list(incarnations)
         if body is not None:
             self.incarnations.append(("cpu", body))
@@ -292,7 +296,7 @@ class TaskClass:
         expressions may name peers by the free parameters alone, but
         task instances carry the full local set.  A missing param whose
         range holds more than one value is a real addressing error."""
-        if all(p in locals_ for p, _ in self.params):
+        if self._param_set <= locals_.keys():
             return locals_
         out = dict(locals_)
         g = self.taskpool.globals if self.taskpool is not None else {}
@@ -306,6 +310,20 @@ class TaskClass:
                     f"is not single-valued ({len(vals)} candidates)")
             out[name] = vals[0]
         return out
+
+    def locate(self, locals_: Dict[str, int]) -> Tuple[Dict[str, int], Tuple]:
+        """The instance a dep expression names: its completed locals and
+        its key, each made once (the release walk hands both to
+        ``deliver_dep``).  The common case — every parameter present, the
+        default key — is one C-level pass over the parameter names."""
+        try:
+            vals = tuple(map(locals_.__getitem__, self._param_names))
+        except KeyError:
+            locals_ = self.complete_locals(locals_)
+            vals = tuple(map(locals_.__getitem__, self._param_names))
+        if self.key_fn is not None:
+            return locals_, (self.name, self.key_fn(locals_))
+        return locals_, (self.name,) + vals
 
     # -- parameter space ---------------------------------------------------
     def iter_space(self, globals_: Dict[str, Any]) -> Iterable[Dict[str, int]]:
@@ -340,14 +358,20 @@ class TaskClass:
         if not deps:
             return 0    # startup-enumeration fast path
         n = 0
-        for dep in deps:
-            if dep.applies(locals_):
-                n += dep.multiplicity(locals_)
+        for dep in deps:    # Dep.applies / .multiplicity, in line
+            guard = dep.guard
+            if guard is not None and not guard(locals_):
+                continue
+            if dep.count is not None:
+                n += int(dep.count(locals_))
+            else:
+                res = dep.end.params_fn(locals_)
+                n += len(res) if isinstance(res, (list, tuple)) else 1
         return n
 
     # binding-table kinds, mirrored by native/schedext.c (CK_*)
     _CK_NULL, _CK_FROMDESC, _CK_NEW, _CK_FROMTASK, _CK_BAIL = 0, 1, 2, 3, 4
-    _CK_TOTASK, _CK_OBAIL = 10, 11
+    _CK_TOTASK, _CK_OBAIL, _CK_TODESC, _CK_NOCLASS = 10, 11, 12, 13
 
     def _native_in_table(self):
         """Per-in-flow binding table for the C ``prepare_input`` twin:
@@ -380,41 +404,68 @@ class TaskClass:
             table.append((flow.name, tuple(deps)))
         return tuple(table)
 
-    def _native_out_table(self):
-        """Per-out-flow delivery table for the C ``release_deps`` twin:
-        ``(flow_name, flow_index, access, ((guard, kind, payload), ...))``
-        with payload ``(end, succ_tc, succ_flow_name, succ_write)`` for
-        local-capable ToTask deps.  Writebacks (ToDesc), reshaping edges
-        (any dtt on either side), and unresolvable successors are BAIL
-        entries; Null outputs deliver nothing and are omitted (exactly
-        the Python walk's no-op arm)."""
+    def release_plan(self):
+        """What a completed task of this class hands on, resolved once
+        a class: the one table both release walks read —
+        ``engine.release_deps`` and its C twin (``schedext.c``
+        ``plan_build`` / ``c_release_walk``, positions 0-3 of a flow
+        entry and of a payload).  A flow with output deps is
+        ``(flow_name, flow_index, access, deps, flow)``, a dep is
+        ``(guard, kind, payload)`` in declaration order:
+
+        ``_CK_TOTASK``  ``(end, succ_tc, succ_flow_name, succ_write, dep,
+                        edge_dtt)`` with ``edge_dtt`` False: a delivery
+                        both walks make;
+        ``_CK_OBAIL``   the same payload for a delivery of the Python
+                        walk alone: EITHER end declares a ``dtt``
+                        (``edge_dtt`` True: the edge-datatype arm), or
+                        the successor class has no flow of that name;
+        ``_CK_TODESC``  ``(ref_fn, dtt)``: the write-back (``dtt`` a
+                        ``Dtt`` or None);
+        ``_CK_NOCLASS`` ``end``: the taskpool has no such class (an
+                        error where the dep applies).
+
+        ``-> NULL`` hands nothing on and has no entry.  Resolved at the
+        first ask and kept until the pool's set of classes changes
+        (``Taskpool.add_task_class`` drops every class's plan, since
+        they name each other); a class no pool holds yet keeps none."""
+        plan = self._release_plan
+        if plan is not None:
+            return plan
         tp = self.taskpool
+        classes = tp.task_classes if tp is not None else {}
         table = []
         for flow in self._out_flows:
             deps = []
             for dep in flow.outputs:
                 end = dep.end
-                if isinstance(end, Null):
+                if isinstance(end, ToDesc):
+                    deps.append((dep.guard, self._CK_TODESC,
+                                 (end.ref_fn, as_dtt(dep.dtt))))
                     continue
                 if not isinstance(end, ToTask):
-                    deps.append((dep.guard, self._CK_OBAIL, None))
                     continue
-                succ_tc = tp.task_classes.get(end.task_class) \
-                    if tp is not None else None
-                succ_flow = succ_tc._flow_by_name.get(end.flow) \
-                    if succ_tc is not None else None
-                if (succ_tc is None or succ_flow is None
-                        or dep.dtt is not None
-                        or any(d.dtt is not None
-                               for d in succ_flow.inputs)):
-                    deps.append((dep.guard, self._CK_OBAIL, None))
+                succ_tc = classes.get(end.task_class)
+                if succ_tc is None:
+                    deps.append((dep.guard, self._CK_NOCLASS, end))
                     continue
-                deps.append((dep.guard, self._CK_TOTASK,
-                             (end, succ_tc, end.flow,
-                              int(bool(succ_flow.access & ACCESS_WRITE)))))
+                succ_flow = succ_tc._flow_by_name.get(end.flow)
+                edge_dtt = dep.dtt is not None or (
+                    succ_flow is not None and any(
+                        d.dtt is not None for d in succ_flow.inputs))
+                write = succ_flow is not None \
+                    and bool(succ_flow.access & ACCESS_WRITE)
+                deps.append((dep.guard,
+                             self._CK_OBAIL if edge_dtt or succ_flow is None
+                             else self._CK_TOTASK,
+                             (end, succ_tc, end.flow, int(write), dep,
+                              edge_dtt)))
             table.append((flow.name, flow.flow_index, flow.access,
-                          tuple(deps)))
-        return tuple(table)
+                          tuple(deps), flow))
+        plan = tuple(table)
+        if tp is not None:
+            self._release_plan = plan
+        return plan
 
     def native_vt(self):
         """The native per-class vtable (reference: the
@@ -466,7 +517,7 @@ class TaskClass:
                              self._native_in_table() if cchain else (),
                              tuple(self._noin_flow_names)
                              if cchain else (),
-                             self._native_out_table() if cchain else (),
+                             self.release_plan() if cchain else (),
                              tuple(f.name for f in self._write_flows)
                              if cchain else ())
         return self._vt

@@ -51,6 +51,44 @@ def _native_dep_table():
     return _ndep_cls() if _ndep_cls is not None else None
 
 
+class Counters:
+    """A set of named int counters (the subclass's ``__slots__``) that
+    sums with another of its kind and prints as a dict."""
+
+    __slots__ = ()
+
+    def add(self, other: "Counters") -> None:
+        for k in self.__slots__:
+            setattr(self, k, getattr(self, k) + getattr(other, k))
+
+    def as_dict(self) -> Dict[str, int]:
+        return {k: getattr(self, k) for k in self.__slots__}
+
+
+class ReleaseStats(Counters):
+    """Counters of the PTG release walk (``engine.release_deps``): on
+    every pool (``Taskpool.release_stats``), and summed over a context's
+    terminated pools on ``Context.release_stats`` (scraped as
+    ``parsec_release_*_total``).  They say how often the walk's plain arm
+    engages: a release adds to them once, at its end (a plain ``+=`` of
+    an int slot, one bytecode sequence the interpreter does not switch
+    threads inside).  Releases that rode the C chain (classes with a
+    single cpu incarnation, ``schedext.c``) are not counted."""
+
+    __slots__ = ("deliveries", "general_deliveries", "repo_holds")
+
+    def __init__(self):
+        #: successor instances the output deps of completed tasks named
+        self.deliveries = 0
+        #: those that took a general arm: an edge with a ``dtt``, a
+        #: context of several ranks or with a comm engine (the affinity
+        #: is evaluated), a grapher, a replay filter
+        self.general_deliveries = 0
+        #: producers that took a repo entry: those that handed on an
+        #: ARENA copy to a local consumer
+        self.repo_holds = 0
+
+
 class TaskpoolState(IntEnum):
     CREATED = 0
     ATTACHED = 1
@@ -87,6 +125,8 @@ class Taskpool:
         #: records, selected once at construction (engine.deliver_dep)
         self.deps_table = ConcurrentHashTable()
         self._native_deps = _native_dep_table()
+        #: what this pool's release walk met (engine.release_deps)
+        self.release_stats = ReleaseStats()
         #: collection datums whose host copy a writeback replaced; their
         #: user-visible backing re-links at termination (engine._writeback)
         self.dirty_data: set = set()
@@ -171,6 +211,9 @@ class Taskpool:
         tc.taskpool = self
         tc.repo = DataRepo(nb_flows=len(tc.flows), name=tc.name)
         self.task_classes[tc.name] = tc
+        # the classes' release plans name each other: resolve them anew
+        for other in self.task_classes.values():
+            other._release_plan = None
         return tc
 
     def add_arena(self, name: str, arena: Arena) -> None:
@@ -216,6 +259,7 @@ class Taskpool:
         for cb in cbs:
             cb(self)
         if self.context is not None:
+            self.context.release_stats.add(self.release_stats)
             self.context._taskpool_terminated(self)
         self._done_event.set()
 

@@ -38,7 +38,7 @@ from parsec_tpu.core import scheduling
 from parsec_tpu.core.errors import PeerFailedError
 from parsec_tpu.core.task import (Flow, HookReturn, Task, TaskClass,
                                   normalize_body_outputs)
-from parsec_tpu.core.taskpool import Taskpool
+from parsec_tpu.core.taskpool import Counters, Taskpool
 from parsec_tpu.data.arena import Arena
 from parsec_tpu.data.collection import DataCollection, DataRef
 from parsec_tpu.data.data import (ACCESS_READ, ACCESS_RW, ACCESS_WRITE,
@@ -287,7 +287,7 @@ class _DTDState:
 _seq = itertools.count()
 
 
-class DTDStats:
+class DTDStats(Counters):
     """Counters of the discovery front end, at the boundaries of its
     spans (``dtd.insert``, ``dtd.window_wait``): on every pool
     (``DTDTaskpool.stats``), and summed over a context's terminated
@@ -305,13 +305,6 @@ class DTDStats:
         self.tracked_tiles = 0
         #: tiles the pool made itself (tile_new, tile_arena)
         self.new_tiles = 0
-
-    def add(self, other: "DTDStats") -> None:
-        for k in self.__slots__:
-            setattr(self, k, getattr(self, k) + getattr(other, k))
-
-    def as_dict(self) -> Dict[str, int]:
-        return {k: getattr(self, k) for k in self.__slots__}
 
 
 class DTDTaskpool(Taskpool):

@@ -26,6 +26,7 @@ Range bounds by name and to everything else via Python closures.
 from __future__ import annotations
 
 import inspect
+from operator import itemgetter
 from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 import numpy as np
@@ -47,7 +48,9 @@ def _named(fn: Callable) -> Callable[[Dict[str, int]], Any]:
     """Adapt a named-parameter lambda to a locals-dict callable.
 
     Parameters with defaults (the ``lambda k, NB=NT: ...`` capture idiom)
-    keep their defaults when the name is not a task parameter.
+    keep their defaults when the name is not a task parameter.  The
+    adapter is by name until :func:`_positional` has told it its class's
+    parameters (``TaskBuilder._build``).
     """
     if fn is None:
         return None
@@ -67,7 +70,62 @@ def _named(fn: Callable) -> Callable[[Dict[str, int]], Any]:
                     f"params {sorted(locals_)}; capture globals with a "
                     f"default arg (lambda k, {name}={name}: ...)")
         return fn(**kwargs)
+    wrapper.__ptg_fn__ = fn
     return wrapper
+
+
+def _positional(adapter: Optional[Callable], params: Sequence[str]
+                ) -> Optional[Callable]:
+    """``adapter`` (a :func:`_named` wrapper) for a class whose task
+    parameters are ``params``: which of the lambda's parameters are task
+    parameters is settled here, once, and a call is ``fn(*values)`` —
+    no ``kwargs`` dict, no lookup by name.  Possible where the task
+    parameters lead the lambda's positional parameters (``lambda m, k,
+    NT=NT:``; what follows keeps its default); anything else — a
+    keyword-only name, a default ahead of a task parameter, a callable
+    that is no ``_named`` wrapper (the JDF front end's, a hand-built
+    ``Dep``'s) — stays as it is.  Locals that lack one of the names (a
+    caller's own incomplete dict) take the by-name path and its
+    diagnosis."""
+    fn = getattr(adapter, "__ptg_fn__", None)
+    if fn is None:
+        return adapter
+    names = []        # the task parameters that lead the lambda's own
+    closed = False    # a name that keeps its default was met
+    for p in inspect.signature(fn).parameters.values():
+        if p.name in params:
+            if closed or p.kind is not p.POSITIONAL_OR_KEYWORD:
+                return adapter
+            names.append(p.name)
+        elif p.kind is p.VAR_KEYWORD:
+            continue
+        elif p.kind is not p.VAR_POSITIONAL and p.default is p.empty:
+            return adapter      # by name: it raises the KeyError
+        else:
+            closed = True
+    if not names:
+        def call(locals_):
+            return fn()
+    elif len(names) == 1:
+        a, = names
+
+        def call(locals_):
+            try:
+                x = locals_[a]
+            except KeyError:
+                return adapter(locals_)
+            return fn(x)
+    else:
+        get = itemgetter(*names)
+
+        def call(locals_):
+            try:
+                vals = get(locals_)
+            except KeyError:
+                return adapter(locals_)
+            return fn(*vals)
+    call.__ptg_fn__ = fn
+    return call
 
 
 def _resolve(v: Any, globals_: Dict[str, Any], locals_: Dict[str, int]) -> int:
@@ -328,11 +386,33 @@ class TaskBuilder:
         return self
 
     def _build(self) -> TaskClass:
+        # the class's parameters are known here: every expression of the
+        # declaration is called positionally from now on (_positional);
+        # the IN / OUT objects a caller may hold stay as they were
+        params = frozenset(p for p, _ in self._params)
+
+        def pos(fn):
+            return _positional(fn, params)
+
+        def end_of(end):
+            if isinstance(end, (FromTask, ToTask)):
+                return type(end)(end.task_class, end.flow,
+                                 pos(end.params_fn))
+            if isinstance(end, (FromDesc, ToDesc)):
+                return type(end)(pos(end.ref_fn))
+            return end
+
+        def dep_of(dep):
+            return Dep(end_of(dep.end), guard=pos(dep.guard), dtt=dep.dtt,
+                       count=pos(dep.count))
+
+        flows = [Flow(f.name, f.access, [dep_of(d) for d in f.inputs],
+                      [dep_of(d) for d in f.outputs]) for f in self._flows]
         return TaskClass(
-            self.name, params=self._params, affinity=self._affinity,
-            flows=self._flows, incarnations=self._incarnations,
-            priority=self._priority, properties=self._properties,
-            key_fn=self._key_fn)
+            self.name, params=self._params, affinity=pos(self._affinity),
+            flows=flows, incarnations=self._incarnations,
+            priority=pos(self._priority), properties=self._properties,
+            key_fn=pos(self._key_fn))
 
 
 class PTG:

@@ -538,10 +538,13 @@ typedef struct {
      *         per in-flow; kind 0=NULL 1=FROMDESC(ref_fn) 2=NEW(arena)
      *         3=FROMTASK(dep) 4=BAIL (statically ineligible dep)
      * noin:   (flow_name, ...) flows with no input deps (bind None)
-     * outs:   ((flow_name, flow_index, access,
-     *           ((guard|None, kind, payload), ...)), ...) per out-flow;
-     *         kind 10=TOTASK(payload=(end, succ_tc, succ_flow,
-     *         succ_write)) 11=BAIL (ToDesc / reshape / missing class)
+     * outs:   TaskClass.release_plan(), the table engine.release_deps
+     *         walks too: ((flow_name, flow_index, access,
+     *           ((guard|None, kind, payload), ...), flow), ...) per
+     *         out-flow; kind 10=TOTASK(payload=(end, succ_tc, succ_flow,
+     *         succ_write, ...)); every other kind (11 a dtt edge or a
+     *         missing flow, 12 ToDesc, 13 a missing class) is the
+     *         Python walk's alone and bails
      * wflows: (flow_name, ...) write-access flows (version bumps) */
     PyObject *prep, *noin, *outs, *wflows;
     int cchain;
@@ -594,7 +597,7 @@ static PyObject *s_data_attr, *s_device_attr, *s_complete_write,
     *s_native_deps, *s_vt_attr, *s_native_vt, *s_nb_task_inputs,
     *s_deliver_dep, *s_ring_doorbell, *s_record_error, *s_rank,
     *s_ready_stamp, *s_retry_max, *s_grapher, *s_ici,
-    *s_replay_filter, *s_priority_attr;
+    *s_replay_filter, *s_priority_attr, *s_nranks;
 
 /* lazily-bound runtime objects (cached after first use; importing an
  * already-loaded module is a sys.modules dict hit) */
@@ -1187,6 +1190,9 @@ typedef struct {
     int reason_triv;       /* BR_* why FL_TRIV is clear */
     int reason_ext;        /* BR_* why FL_EXT is clear */
     long long myrank;      /* ctx.rank for the cached pool */
+    int one_rank;          /* its context is one rank with no comm engine:
+                            * no successor is remote, the affinity is
+                            * not asked (engine.release_deps' rule) */
     int ready_stamp;       /* ctx._ready_stamp truth, read per quantum */
     int fi_armed;
     /* complete_exec stride gates (__pins_stride__ on the callback,
@@ -1256,6 +1262,7 @@ static int gates_for(quantum_t *qs, TCObject *t, VTObject *vt) {
     Py_XSETREF(qs->last_vt, (PyObject *)vt);
     qs->last_flags = 0;
     qs->myrank = 0;
+    qs->one_rank = 1;
     qs->reason_triv = qs->reason_ext = BR_POOL;
     PyObject *a = PyObject_GetAttr(tp, s_cancelled);
     if (!a)
@@ -1295,6 +1302,8 @@ static int gates_for(quantum_t *qs, TCObject *t, VTObject *vt) {
         return -1;
     if (ctx != Py_None) {
         qs->myrank = attr_ll(ctx, s_rank, 0);
+        qs->one_rank = !attr_not_none(ctx, s_comm) &&
+                       attr_ll(ctx, s_nranks, 1) == 1;
         if (attr_ll(ctx, s_retry_max, 0) > 0) {
             /* write-flow snapshots before first execution: Python */
             flags &= ~FL_EXT;
@@ -1547,8 +1556,8 @@ static int plan_build(quantum_t *qs, TCObject *t, VTObject *vt,
                     goto excbail;
                 }
                 /* rank check (rank_of: affinity-owner placement) */
-                long long rank = 0;
-                if (attr_not_none(succ_tc, s_affinity)) {
+                long long rank = qs->one_rank ? qs->myrank : 0;
+                if (!qs->one_rank && attr_not_none(succ_tc, s_affinity)) {
                     PyObject *rk = PyObject_CallMethodObjArgs(
                         succ_tc, s_rank_of, cl, NULL);
                     if (!rk) {
@@ -1913,6 +1922,17 @@ static int c_release_walk(quantum_t *qs, RQObject *q, TCObject *t,
             copy = Py_None;
         }
         int real = (copy != Py_None);
+        /* a repo entry (and the source a consumer records) exists to
+         * keep an ARENA buffer off the freelist until its last reader
+         * is done: a copy without an arena takes neither */
+        int hold = 0;
+        if (real && count > 0) {
+            PyObject *arena = PyObject_GetAttr(copy, s_arena_attr);
+            if (!arena)
+                goto fail;
+            hold = (arena != Py_None);
+            Py_DECREF(arena);
+        }
         if (!real && count > 0 && plan->outs[fi].access != 0) {
             /* NULL forwarded on a data flow: legal but almost always a
              * graph bug (ptgpp forward_NULL golden behavior) */
@@ -1940,7 +1960,7 @@ static int c_release_walk(quantum_t *qs, RQObject *q, TCObject *t,
                     goto fail;
                 dcopy = owned_dcopy;
             }
-            if (real && !entry) {
+            if (hold && !entry) {
                 repo = PyObject_GetAttr(t->task_class, s_repo);
                 if (!repo) {
                     Py_XDECREF(owned_dcopy);
@@ -1953,7 +1973,7 @@ static int c_release_walk(quantum_t *qs, RQObject *q, TCObject *t,
                     goto fail;
                 }
             }
-            if (real) {
+            if (hold) {
                 /* repo hold: a NEW-flow copy chained through several
                  * tasks lives in every producer's entry, and only the
                  * LAST retirement returns it to the freelist */
@@ -1975,23 +1995,15 @@ static int c_release_walk(quantum_t *qs, RQObject *q, TCObject *t,
                 if (cur != copy) {
                     PyObject *arena = PyObject_GetAttr(copy,
                                                        s_arena_attr);
-                    if (!arena) {
+                    PyObject *r = arena ? PyObject_CallMethodObjArgs(
+                        arena, s_retain_copy, copy, NULL) : NULL;
+                    Py_XDECREF(arena);
+                    if (!r) {
                         Py_DECREF(copies);
                         Py_XDECREF(owned_dcopy);
                         goto fail;
                     }
-                    if (arena != Py_None) {
-                        PyObject *r = PyObject_CallMethodObjArgs(
-                            arena, s_retain_copy, copy, NULL);
-                        if (!r) {
-                            Py_DECREF(arena);
-                            Py_DECREF(copies);
-                            Py_XDECREF(owned_dcopy);
-                            goto fail;
-                        }
-                        Py_DECREF(r);
-                    }
-                    Py_DECREF(arena);
+                    Py_DECREF(r);
                 }
                 Py_INCREF(copy);
                 if (PyList_SetItem(copies, plan->outs[fi].findex,
@@ -2004,7 +2016,7 @@ static int c_release_walk(quantum_t *qs, RQObject *q, TCObject *t,
                 consumers++;
             }
             PyObject *src;
-            if (real) {
+            if (hold) {
                 src = PyTuple_Pack(2, t->task_class, t->key);
                 if (!src) {
                     Py_XDECREF(owned_dcopy);
@@ -2028,8 +2040,7 @@ static int c_release_walk(quantum_t *qs, RQObject *q, TCObject *t,
                     goto fail;
                 }
             }
-            PyObject *newt = c_deliver(qs, t->taskpool, d, dcopy,
-                                       real ? src : Py_None);
+            PyObject *newt = c_deliver(qs, t->taskpool, d, dcopy, src);
             Py_DECREF(src);
             Py_XDECREF(owned_dcopy);
             if (!newt)
@@ -2581,6 +2592,7 @@ PyMODINIT_FUNC PyInit_schedext(void) {
     s_ici = PyUnicode_InternFromString("ici");
     s_replay_filter = PyUnicode_InternFromString("_replay_filter");
     s_priority_attr = PyUnicode_InternFromString("priority");
+    s_nranks = PyUnicode_InternFromString("nranks");
     if (!s_data_attr || !s_device_attr || !s_complete_write || !s_repo ||
         !s_lookup_entry || !s_addto_usage || !s_copies || !s_on_retire ||
         !s_arena_attr || !s_retain_copy || !s_get_copy || !s_arenas ||
@@ -2590,7 +2602,7 @@ PyMODINIT_FUNC PyInit_schedext(void) {
         !s_vt_attr || !s_native_vt || !s_nb_task_inputs ||
         !s_deliver_dep || !s_ring_doorbell || !s_record_error ||
         !s_rank || !s_ready_stamp || !s_retry_max || !s_grapher ||
-        !s_ici || !s_replay_filter || !s_priority_attr)
+        !s_ici || !s_replay_filter || !s_priority_attr || !s_nranks)
         return NULL;
     g_one = PyLong_FromLong(1L);
     g_neg1 = PyLong_FromLong(-1L);

@@ -655,7 +655,8 @@ class RuntimeMetrics:
         out.extend(self._collect_comm())
         out.extend(self._collect_sched())
         out.extend(self._collect_devices())
-        out.extend(self._collect_dtd())
+        out.extend(self._collect_summed("dtd_stats", "parsec_dtd_"))
+        out.extend(self._collect_summed("release_stats", "parsec_release_"))
         out.extend(self._collect_service())
         for fn in list(self._collectors):
             try:
@@ -793,13 +794,15 @@ class RuntimeMetrics:
                     out.append(counter_sample(metric, v, labels))
         return out
 
-    def _collect_dtd(self) -> List[dict]:
-        """The discovery front end's counters, summed over the
-        context's terminated DTD pools (dsl/dtd/insert.py DTDStats)."""
-        st = getattr(self.context, "dtd_stats", None)
+    def _collect_summed(self, attr: str, prefix: str) -> List[dict]:
+        """Counters the context sums over its terminated pools: the
+        discovery front end's (``dtd_stats``, dsl/dtd/insert.py DTDStats)
+        and the release walk's (``release_stats``, core/taskpool.py
+        ReleaseStats)."""
+        st = getattr(self.context, attr, None)
         if st is None:
             return []
-        return [counter_sample(f"parsec_dtd_{k}_total", v)
+        return [counter_sample(f"{prefix}{k}_total", v)
                 for k, v in st.as_dict().items() if v]
 
     def _collect_service(self) -> List[dict]:

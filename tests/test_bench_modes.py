@@ -47,6 +47,27 @@ def test_host_canary_runs_on_cpu_and_names_it(app, metric, monkeypatch,
     assert dev["platform"] == "cpu" and dev["kind"] and dev["count"] >= 1
 
 
+def test_release_canary_counts_a_task_of_the_cell_3_dag(monkeypatch, capsys):
+    """Counts, not a speed (PR 34): no by-name adapter call and no locked
+    repo mutation is left on a task's release path, PTG or DTD, and the
+    PTG's deliveries are the DAG's 16 368 a job, none through a general
+    arm."""
+    monkeypatch.setenv("PARSEC_BENCH_APP", "release")
+    bench.main()
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["metric"] == "ptg_by_name_calls_per_task"
+    assert line["device"]["platform"] == "cpu"
+    ptg, dtd = line["release"]["ptg"], line["release"]["dtd"]
+    assert ptg["tasks"] == dtd["tasks"] == 5984
+    assert ptg["by_name_calls_per_task"] == dtd["by_name_calls_per_task"] == 0
+    assert ptg["repo_mutations_per_task"] == dtd["repo_mutations_per_task"] \
+        == 0
+    assert 10 < ptg["positional_calls_per_task"] < 14
+    assert ptg["release"] == {"deliveries": 16368, "general_deliveries": 0,
+                              "repo_holds": 0}
+    assert dtd["release"]["deliveries"] == 0
+
+
 def test_premerge_names_only_modes_that_exist():
     script = os.path.join(os.path.dirname(os.path.abspath(bench.__file__)),
                           "tools", "premerge_bench.sh")
