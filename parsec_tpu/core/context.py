@@ -24,6 +24,7 @@ from parsec_tpu.core.task import Task
 from parsec_tpu.core.taskpool import (ReleaseStats, Taskpool,
                                       TaskpoolState)
 from parsec_tpu.core import termdet as termdet_mod
+from parsec_tpu.prof.pins import open_span
 from parsec_tpu.sched import create as create_scheduler
 from parsec_tpu.utils.mca import components, params
 from parsec_tpu.utils.output import debug_verbose, inform
@@ -479,9 +480,12 @@ class Context:
                 if not self._pending_start:
                     return
                 tp = self._pending_start.pop(0)
-            ready = tp.startup()
-            if ready:
-                scheduling.schedule(self.streams[0], ready)
+            # the caller's thread on the map: enumerating the start-up
+            # tasks (a DTD pool's whole insert stream) is its work
+            with open_span(self.streams[0], "ctx.startup"):
+                ready = tp.startup()
+                if ready:
+                    scheduling.schedule(self.streams[0], ready)
             tp.ready()
             if self.comm is not None:
                 # activations delayed while this pool counted its tasks
@@ -503,8 +507,13 @@ class Context:
         (reference: parsec_context_wait:776).  Past the
         ``runtime_autopsy_s`` soft deadline a one-shot hang autopsy is
         logged so a stuck run is diagnosable from its log."""
-        import time as _time
         self.start()
+        with open_span(self.streams[0], "ctx.wait"):
+            self._wait_started(timeout)
+
+    def _wait_started(self, timeout: Optional[float]) -> None:
+        """The blocking part of :meth:`wait`, every pool started."""
+        import time as _time
         if self.comm is not None:
             # dynamic pools hold a runtime action until the pool-scoped
             # quiescence round proves every rank drained (see

@@ -6,11 +6,43 @@ callback chains on task lifecycle events SELECT/EXEC/COMPLETE_EXEC/...
 ``task_profiler`` module feeds the binary tracer).  The runtime already
 emits events through ``ExecutionStream.pins`` (core/context.py); modules
 here subscribe to them.
+
+**Thread-state spans** (``open_span`` -> ``TraceMePins``): what a runtime
+thread is doing, on the profiler's clock.  Beside its wall time (the
+event's duration) a span may carry two integers the sink reads off the
+emitting thread's CPU clock (``time.thread_time_ns``), for one span a
+process every 10 ms, the threads taking turns (``TraceMePins``):
+
+- ``cpu_ns``: the time the thread was ON a core between the span's begin
+  and its end — Python executed under the interpreter lock, and C that
+  runs with the lock released (the tail of a jitted call inside PJRT).
+  Children nest in their parents, so a parent's ``cpu_ns`` includes its
+  children's, exactly as its wall time does.
+- ``cpu_end_ns``: the thread clock's absolute reading at the end, so
+  ``cpu_end_ns - cpu_ns`` is its reading at the begin: a reader has the
+  thread's clock at both ends of every span that carries the two, and
+  the CPU a thread burned BETWEEN two readings is known whatever lay
+  between (a worker's task bodies, the client's staging, the spans that
+  went by without their turn).
+
+``wall - cpu`` is everything else: waits for the interpreter lock, for a
+Python lock or condition inside the span, blocking inside PJRT, and
+preemption (small while the host has more cores than runnable threads).
+So the CPU figure is exact for "what would a faster implementation have
+to execute less of", and the wait figure is an UPPER bound of the
+interpreter lock's share: tight for ``fin.release`` (pure Python), looser
+for ``mgr.dispatch`` (the jitted call may block inside PJRT).  A reader
+takes a thread's clock where it finds it: what a thread line ran between
+two readings is exact, a single span's ``cpu_ns`` is a sample.
 """
 
 from __future__ import annotations
 
+import gc
+import threading
 from time import perf_counter as _now
+from time import perf_counter_ns as _now_ns
+from time import thread_time_ns as _thread_cpu_ns
 from typing import Any, Dict, Optional
 
 from parsec_tpu.prof.profiling import EV_END, EV_POINT, EV_START, Profile
@@ -23,27 +55,49 @@ from parsec_tpu.prof.profiling import EV_END, EV_POINT, EV_START, Profile
 #: accelerator-pipeline residency (devices/xla.py, gated on the causal
 #: tracer being installed).
 #: ``span_begin``/``span_end`` bracket what a runtime THREAD is doing
-#: (manager, completer, worker, fuse warmer); the payload is the
-#: :class:`Span`, whose names are listed in ``SPAN_NAMES``.
+#: (manager, completer, worker, fuse warmer, the caller's); the payload
+#: is the :class:`Span`, whose names are listed in ``ALL_SPAN_NAMES``.
 PINS_EVENTS = ("select", "exec_begin", "exec_end", "exec_async",
                "complete_exec", "task_discard",
                "device_dispatch", "device_done",
                "span_begin", "span_end",
                "job_submit", "job_start", "job_done")
 
-#: thread-state spans (PERF.md section 3 says which metric reads each).
-#: ``mgr.*`` are emitted by a device's manager threads, ``fin.*`` by its
-#: completer, ``worker.idle`` by a worker, ``warm.compile`` by the
-#: background fused-width compiler (devices/xla.py, core/scheduling.py).
+#: thread-state spans (PERF.md section 3 says which metric reads each);
+#: every name an ``open_span`` site uses is in one of the three tuples
+#: below (``ALL_SPAN_NAMES``; tests/test_span_cpu.py holds the sites to
+#: it).  Each lands in the trace with its own arguments and, when its
+#: thread's turn at the clock has come, the sink's ``cpu_ns`` /
+#: ``cpu_end_ns`` (the module's docstring: thread CPU time inside the
+#: span, and the thread clock at its end; ``wall - cpu`` is lock waits,
+#: blocking and preemption together).
+#: These are what every context with an XLA device emits: ``mgr.*`` by a
+#: device's manager threads, ``fin.*`` by its completer, ``worker.idle``
+#: by a worker, ``warm.compile`` by the background fused-width compiler
+#: (devices/xla.py, core/scheduling.py); ``ctx.startup`` (one a pool:
+#: its ``startup()`` and the scheduling of what that returned) and
+#: ``ctx.wait`` (the blocking part of ``Context.wait``) by the CALLER's
+#: thread (core/context.py); ``gc.collect`` (``gen``) by whichever thread
+#: tripped the interpreter's collector — every other Python thread stands
+#: still for its length, so its WALL time is the number and it carries
+#: no CPU reading (``TraceMePins._gc``).
 SPAN_NAMES = ("mgr.starved", "mgr.launch", "mgr.pop_wave", "mgr.stage_in",
               "mgr.dispatch", "mgr.inflight_wait", "mgr.warm_wait",
               "fin.idle", "fin.pass", "fin.release", "fin.drain",
-              "worker.idle", "warm.compile")
+              "worker.idle", "warm.compile",
+              "ctx.startup", "ctx.wait", "gc.collect")
+#: the DTD front end's spans (dsl/dtd/insert.py), on the thread that runs
+#: a pool's inserter (inside its ``ctx.startup``): one ``dtd.insert`` a
+#: run of the inserter (``pool``, late ``n``), a ``dtd.window_wait`` a
+#: stall on the insert window (``inflight``), ``dtd.flush`` around
+#: ``data_flush_all``.  Only a DTD pool emits them
+DTD_SPAN_NAMES = ("dtd.insert", "dtd.window_wait", "dtd.flush")
 #: the ICI transport's spans (comm/ici.py), around each data movement
 #: between chips, on whichever thread releases the deps (mostly a
 #: completer, inside its ``fin.release``); arguments ``bytes``, ``ndst``.
 #: Only a context that drives several chips emits them
 ICI_SPAN_NAMES = ("ici.put", "ici.bcast", "ici.permute")
+ALL_SPAN_NAMES = SPAN_NAMES + DTD_SPAN_NAMES + ICI_SPAN_NAMES
 #: what the spans are called in the profiler's trace: ``parsec:mgr.launch``
 SPAN_PREFIX = "parsec:"
 
@@ -55,7 +109,7 @@ class Span:
     only when it closes.  Begin and end happen on ONE thread, properly
     nested with the thread's other spans."""
 
-    __slots__ = ("es", "name", "args", "late", "sink")
+    __slots__ = ("es", "name", "args", "late", "sink", "cpu0")
 
     def __init__(self, es, name: str, args: dict):
         self.es = es
@@ -63,6 +117,7 @@ class Span:
         self.args = args
         self.late = None
         self.sink = None        # the sink's own handle for this pair
+        self.cpu0 = 0           # the sink's: thread CPU clock at the begin
 
     def end(self, **late) -> None:
         if late:
@@ -122,6 +177,14 @@ def no_span_sink() -> bool:
     return False
 
 
+#: the least time between two spans of a process whose thread clock the
+#: sink reads (the threads take turns, ``TraceMePins._due``): the tick of
+#: the chip host's thread clock, which costs a system call of 17-48 us
+#: there (PERF.md section 6, PR 35) — under half a percent of the
+#: interpreter lock, however many threads emit and whatever the host
+_CLOCK_GAP_NS = 10_000_000
+
+
 class TraceMePins:
     """The sink that puts the thread-state spans on the profiler's own
     clock: each :class:`Span` becomes a ``jax.profiler.TraceAnnotation``
@@ -131,33 +194,106 @@ class TraceMePins:
     timeline.  TraceMe is its own gate: the sink makes
     ``TraceAnnotation.is_enabled`` the context's ``_span_live``, so with
     no profiler session a span costs that one probe and nothing is
-    built or recorded.  Installed by every Context with an XLA device."""
+    built or recorded.  Installed by every Context with an XLA device.
+
+    **The thread clock is rationed by time.**  The sink reads the emitting
+    thread's CPU clock for at most one span in ``_CLOCK_GAP_NS``, the
+    threads taking turns (``_due``); that span leaves with ``cpu_ns`` and
+    ``cpu_end_ns`` (the module's docstring), read INSIDE the annotation
+    so that the sink's own cost is outside the figure: the last thing
+    ``_begin`` does, the first thing ``_end`` does.  The spans between
+    leave without the two integers.  A reader so has each thread's clock
+    several times a second, which is what the per-thread figures need
+    (benchmark/metrics/host_cpu_us_per_task.py); a span's own ``cpu_ns``
+    is a sample, not a census.  Reading on EVERY span, as ISSUE 35 first
+    asked, made the chip's traced window 40% slower (PERF.md section 6,
+    PR 35).  Nothing else in the runtime reads that clock."""
+
+    #: the collector's span is one callback on ``gc.callbacks`` a process,
+    #: whatever the number of contexts: how many sinks are installed, the
+    #: callback they share, and the collection under way (annotation,
+    #: thread CPU clock at its start; collections do not nest)
+    _gc_lock = threading.Lock()
+    _gc_users = 0
+    _gc_callback = None
+    _gc_open = None
 
     def __init__(self):
         from jax.profiler import TraceAnnotation
         self._annotation = TraceAnnotation
+        #: per emitting thread, when its clock may next be read
+        self._next_read = {}
 
     def install(self, context) -> None:
         context.pins_register("span_begin", self._begin)
         context.pins_register("span_end", self._end)
         context._span_live = self._annotation.is_enabled
+        cls = TraceMePins
+        with cls._gc_lock:
+            cls._gc_users += 1
+            if cls._gc_callback is None:
+                cls._gc_callback = self._gc
+                gc.callbacks.append(cls._gc_callback)
 
     def uninstall(self, context) -> None:
         context._span_live = no_span_sink
         context.pins_unregister("span_begin", self._begin)
         context.pins_unregister("span_end", self._end)
+        cls = TraceMePins
+        with cls._gc_lock:
+            cls._gc_users -= 1
+            if cls._gc_users <= 0 and cls._gc_callback is not None:
+                gc.callbacks.remove(cls._gc_callback)
+                cls._gc_callback, cls._gc_users = None, 0
+
+    def _due(self) -> bool:
+        """Whether the calling thread may read its clock for the span
+        that begins now; if so, its next turn is a gap away for every
+        thread that takes turns."""
+        now, ident = _now_ns(), threading.get_ident()
+        turns = self._next_read
+        if now < turns.get(ident, 0):
+            return False
+        turns[ident] = now + _CLOCK_GAP_NS * (len(turns) or 1)
+        return True
 
     def _begin(self, es, event, span) -> None:
+        due = self._due()                       # settled outside the span
         ann = span.sink = self._annotation(SPAN_PREFIX + span.name,
                                            **span.args)
         ann.__enter__()
+        span.cpu0 = _thread_cpu_ns() if due else -1
 
     def _end(self, es, event, span) -> None:
+        cpu0 = span.cpu0
+        cpu1 = _thread_cpu_ns() if cpu0 >= 0 else 0
         ann = span.sink
         if ann is not None:
             span.sink = None
-            if span.late:
+            if cpu0 >= 0:
+                ann.set_metadata(cpu_ns=cpu1 - cpu0, cpu_end_ns=cpu1,
+                                 **(span.late or {}))
+            elif span.late:
                 ann.set_metadata(**span.late)
+            ann.__exit__(None, None, None)
+
+    def _gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` entry: the span ``gc.collect`` (``gen``)
+        around every collection of the interpreter's heap, on whichever
+        thread tripped it; its WALL time is the number, so it takes no
+        turn at the thread clock.  Emitted from the sink directly, not
+        through ``open_span``: the collector's thread need not be a
+        runtime thread, and has no execution stream to emit through.
+        Untraced it costs one probe a collection."""
+        cls = TraceMePins
+        if phase == "start":
+            if self._annotation.is_enabled():
+                cls._gc_open = self._annotation(SPAN_PREFIX + "gc.collect",
+                                                gen=info["generation"])
+                cls._gc_open.__enter__()
+            return
+        ann, cls._gc_open = cls._gc_open, None
+        if ann is not None:
             ann.__exit__(None, None, None)
 
 

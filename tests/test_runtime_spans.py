@@ -5,6 +5,7 @@ program names.  A tiny Cholesky (nt = 4, the device path on one CPU
 device) runs under ``jax.profiler``; the trace is read back with
 ``ProfileData`` the way benchmark/runtime_spans.py reads a chip's."""
 
+import gc
 import glob
 import os
 import re
@@ -15,6 +16,7 @@ import pytest
 
 from parsec_tpu.core.context import Context
 from parsec_tpu.data.matrix import TwoDimBlockCyclic
+from parsec_tpu.prof import pins
 from parsec_tpu.prof.pins import SPAN_NAMES, SPAN_PREFIX
 from parsec_tpu.utils.mca import params
 
@@ -46,8 +48,10 @@ def _slow_releases(mp, seconds):
     mp.setattr(scheduling, "complete_execution", slow)
 
 
-def _run_jobs(jobs=JOBS):
-    """``jobs`` factorizations on one Context; the device's counters."""
+def _run_jobs(jobs=JOBS, collect=False):
+    """``jobs`` factorizations on one Context; the device's counters.
+    ``collect`` forces one full collection of the interpreter's heap
+    after the first job, while the Context is open."""
     from parsec_tpu.apps.potrf import potrf_taskpool
     n = MB * NT
     rng = np.random.default_rng(0)
@@ -64,6 +68,9 @@ def _run_jobs(jobs=JOBS):
                 ctx.wait()
                 L = np.tril(A.to_array())
                 assert np.abs(L @ L.T - spd).max() < 1e-3 * np.abs(spd).max()
+                if collect:
+                    gc.collect()
+                    collect = False
             (dev,) = ctx.device_registry.accelerators
             samples = {s["n"]: s["v"]
                        for s in ctx.metrics._collect_devices()}
@@ -95,7 +102,9 @@ def traced(tmp_path_factory):
     try:
         with pytest.MonkeyPatch.context() as mp:
             _slow_releases(mp, 0.001)
-            stats, samples = _run_jobs()
+            # every span is due its turn at the thread's clock
+            mp.setattr(pins, "_CLOCK_GAP_NS", 0)
+            stats, samples = _run_jobs(collect=True)
     finally:
         jax.profiler.stop_trace()
     (path,) = glob.glob(os.path.join(out, "plugins", "profile", "*",
@@ -131,21 +140,87 @@ def test_span_reaches_the_trace(traced, name):
 def test_each_thread_line_holds_one_role(traced):
     """mgr.* only on manager lines (at most device_dispatchers of them),
     fin.* on the one completer line, worker.idle on worker lines with
-    one ``th`` each, warm.compile on the warmer's line."""
+    one ``th`` each, warm.compile on the warmer's line, ctx.* on the
+    caller's; the collector's span lands on whichever thread tripped
+    it."""
     roles = []
     for ln in traced["lines"]:
-        kinds = {ev[0].split(".")[0] for ev in ln}
+        kinds = {ev[0].split(".")[0] for ev in ln} - {"gc"}
+        if not kinds:
+            continue            # a thread that only ever collected
         assert len(kinds) == 1, f"a thread line mixes {kinds}"
         roles.append(kinds.pop())
         if roles[-1] == "worker":
-            assert len({ev[3]["th"] for ev in ln}) == 1
+            assert len({ev[3]["th"] for ev in ln if ev[0] != "gc.collect"}) == 1
     assert 1 <= roles.count("mgr") <= 2
     assert roles.count("fin") == 1
     assert 1 <= roles.count("worker") <= 4
     assert roles.count("warm") == 1
+    assert roles.count("ctx") == 1
     assert all(ev[3]["dev"] == "cpu:0" for name in
                ("mgr.launch", "mgr.starved", "fin.idle")
                for ev in _spans(traced, name))
+
+
+def test_every_span_carries_the_thread_clock(traced):
+    """With no gap between two turns, both integers on every ``parsec:``
+    event but the collector's (whose wall time is the number); a span's
+    CPU time fits in its wall time; along a thread line the clock never
+    runs back."""
+    seen = 0
+    for ln in traced["lines"]:
+        marks = []
+        for name, s, e, a in ln:
+            if name == "gc.collect":
+                assert set(a) == {"gen"}
+                continue
+            seen += 1
+            assert isinstance(a["cpu_ns"], int), (name, a)
+            assert isinstance(a["cpu_end_ns"], int), (name, a)
+            assert 0 <= a["cpu_ns"] <= e - s + 1_000_000, (name, e - s, a)
+            assert a["cpu_end_ns"] >= a["cpu_ns"]
+            marks += [(s, a["cpu_end_ns"] - a["cpu_ns"]),
+                      (e, a["cpu_end_ns"])]
+        marks.sort()
+        assert all(b[1] >= a[1] for a, b in zip(marks, marks[1:]))
+    assert seen
+
+
+def test_a_parent_cpu_time_holds_its_children(traced):
+    checked = 0
+    for ln in traced["lines"]:
+        evs = [ev for ev in ln if ev[0] != "gc.collect"]
+        for name, s, e, a in evs:
+            inside = [c for c in evs if s <= c[1] and c[2] <= e
+                      and c[1:3] != (s, e)]
+            kids = [c for c in inside
+                    if not any(o is not c and o[1] <= c[1] and c[2] <= o[2]
+                               for o in inside)]
+            if kids:
+                checked += 1
+                assert a["cpu_ns"] >= sum(c[3]["cpu_ns"] for c in kids), \
+                    (name, a, [(c[0], c[3]["cpu_ns"]) for c in kids])
+    assert checked
+
+
+def test_the_callers_thread_is_on_the_map(traced):
+    """``ctx.startup`` then ``ctx.wait``, once a job, on one line (the
+    thread that called ``Context.wait``)."""
+    (line,) = [ln for ln in traced["lines"]
+               if any(ev[0].startswith("ctx.") for ev in ln)]
+    mine = [ev for ev in line if ev[0].startswith("ctx.")]
+    assert [ev[0] for ev in mine] == ["ctx.startup", "ctx.wait"] * JOBS
+    for (_n0, _s0, e0, _a0), (_n1, s1, _e1, _a1) in zip(mine[::2], mine[1::2]):
+        assert e0 <= s1
+
+
+def test_a_forced_collection_is_a_span(traced):
+    full = [ev for ev in _spans(traced, "gc.collect") if ev[3]["gen"] == 2]
+    assert full, "the gc.collect() forced inside the session left no span"
+    # the forced one ran on the caller's thread, between two jobs
+    (line,) = [ln for ln in traced["lines"]
+               if any(ev[0].startswith("ctx.") for ev in ln)]
+    assert any(ev in line for ev in full)
 
 
 def test_launch_children_nest_in_their_launch(traced):
@@ -269,8 +344,12 @@ def test_no_profiler_session_same_counts():
     """The same jobs with no session: nothing raised, nothing recorded,
     and the counters that do not depend on how waves met are equal."""
     from jax.profiler import TraceAnnotation
+    from parsec_tpu.prof.pins import TraceMePins
     assert not TraceAnnotation.is_enabled()
-    st, _samples = _run_jobs()
+    st, _samples = _run_jobs(collect=True)
+    # the last context closed: the collector's callback went with it
+    assert not any(getattr(cb, "__func__", None) is TraceMePins._gc
+                   for cb in gc.callbacks)
     assert st["faults"] == 0
     assert st["executed_tasks"] + st["held_tasks"] == JOBS * TASKS
     assert st["held_tasks"] == JOBS * (NT - 1)
