@@ -13,7 +13,7 @@ and ``benchmark/`` are the benchmark (``python -m benchmark.run
 proof that the main path starts on a chip.
 
 ``PARSEC_BENCH_APP`` names the probe (``_AUX_MODES``: tasks, ntasks,
-rtt, bw, aggregate, telemetry, journal, tracer, fabric, release);
+rtt, bw, aggregate, telemetry, journal, tracer, fabric, release, launch);
 anything else, or nothing, is an error.  A probe prints exactly ONE
 JSON line on stdout:
     {"metric": ..., "value": N, "unit": ..., "vs_baseline": N,
@@ -26,6 +26,7 @@ reference publishes no numbers: BASELINE.md).
 import json
 import os
 import sys
+import threading
 import time
 
 import numpy as np
@@ -735,19 +736,25 @@ def run_fabric_bench(n_jobs: int = 0):
     return n_jobs / dt, extras
 
 
-def _call_counts(codes, fn):
+def _call_counts(codes, fn, on_threads=""):
     """Run ``fn()`` and count the calls of each code object in ``codes``
     (name -> code) on every thread: ``sys.monitoring`` local events, so
-    only those functions pay for the count."""
+    only those functions pay for the count.  With ``on_threads`` the
+    answer is a pair: the counts on every thread, and those on the
+    threads whose name starts so."""
     mon = sys.monitoring
     tool = mon.PROFILER_ID
     counts = dict.fromkeys(codes, 0)
+    on = dict.fromkeys(codes, 0)
     names = {code: name for name, code in codes.items()}
 
     def started(code, offset):
         counts[names[code]] += 1    # under the interpreter lock
+        if on_threads and threading.current_thread().name.startswith(
+                on_threads):
+            on[names[code]] += 1
 
-    mon.use_tool_id(tool, "bench-release")
+    mon.use_tool_id(tool, "bench-counts")
     try:
         mon.register_callback(tool, mon.events.PY_START, started)
         for code in names:
@@ -758,7 +765,7 @@ def _call_counts(codes, fn):
             mon.set_local_events(tool, code, 0)
         mon.register_callback(tool, mon.events.PY_START, None)
         mon.free_tool_id(tool)
-    return counts
+    return (counts, on) if on_threads else counts
 
 
 def run_release_bench(nt: int = 32, mb: int = 16):
@@ -830,6 +837,94 @@ def run_release_bench(nt: int = 32, mb: int = 16):
                                                   "host": _host_info()}
 
 
+def run_launch_bench(nt: int = 32, mb: int = 16):
+    """COUNTS a flow and a task on the managers' launch path (PR 36):
+    the nt = 32 tiled Cholesky of the benchmark's host-paced cells with
+    their MCA, every lower tile born on one CPU device, the PTG beside
+    the DTD front end.  On the manager threads: holds of a datum's lock
+    a flow staged (``Data.acquire_on``, ``copy_on``,
+    ``transfer_ownership``, ``attach_copy``, ``detach_copy``: 3 before
+    PR 36, 1 since), holds of the device's ``_mem_lock`` a flow
+    (``_pin_wave``, ``_touch``, ``_account``: 2 before, one a WAVE
+    since), and signatures built there a task (1 before, 0 since); on
+    every thread, signatures a task (1, at ``submit``).  Not a speed: a
+    PR that puts a lock hold a flow or a signature a candidate back is
+    seen here without a chip."""
+    import jax
+    from parsec_tpu.apps import potrf
+    from parsec_tpu.core.context import Context
+    from parsec_tpu.data.data import Data
+    from parsec_tpu.data.matrix import TwoDimBlockCyclic
+    from parsec_tpu.devices.xla import XlaDevice, XlaKernel
+    from parsec_tpu.utils.mca import params
+
+    n = nt * mb
+    rng = np.random.default_rng(0)
+    m = rng.standard_normal((n, n)).astype(np.float32)
+    spd = m @ m.T + n * np.eye(n, dtype=np.float32)
+    datum_lock = ("acquire_on", "copy_on", "transfer_ownership",
+                  "attach_copy", "detach_copy")
+    mem_lock = ("_pin_wave", "_touch", "_account")
+    sigs = ("task_sig", "args_sig")
+    codes = {meth: getattr(owner, meth).__code__
+             for owner, meths in ((Data, datum_lock), (XlaDevice, mem_lock),
+                                  (XlaKernel, sigs)) for meth in meths}
+    mca = {"device_max": 1, "device_fuse": 8, "device_runahead": 48,
+           "device_inflight_depth": 32}
+    out = {}
+    for k, v in mca.items():
+        params.set(k, v)
+    try:
+        with Context(nb_cores=int(os.environ.get("PARSEC_BENCH_CORES",
+                                                 4))) as ctx:
+            (dev,) = ctx.device_registry.accelerators
+            for front, build in (("ptg", potrf.potrf_taskpool),
+                                 ("dtd", potrf.potrf_dtd_taskpool)):
+                A = TwoDimBlockCyclic(mb=mb, nb=mb, lm=n,
+                                      ln=n).from_array(spd.copy())
+                for i in range(nt):
+                    for j in range(i + 1):
+                        A.data_of(i, j).overwrite_on(
+                            dev.space, jax.device_put(
+                                spd[i * mb:(i + 1) * mb,
+                                    j * mb:(j + 1) * mb], dev.jdev))
+                tp = build(A, device="tpu")
+                st0 = dev.stats.as_dict()
+
+                def job():
+                    ctx.add_taskpool(tp)
+                    ctx.wait(timeout=600)
+                everywhere, c = _call_counts(codes, job,
+                                             on_threads="xla-mgr")
+                st = {k: v - st0[k] for k, v in dev.stats.as_dict().items()}
+                L = np.tril(A.to_array())
+                err = np.abs(L @ L.T - spd).max() / np.abs(spd).max()
+                if not err < 1e-3:
+                    raise RuntimeError(
+                        f"launch bench: {front} factor wrong ({err})")
+                tasks = st["executed_tasks"] + st["held_tasks"]
+                flows = st["resident_flows"] + st["staged_flows"]
+                out[front] = {
+                    "tasks": tasks, "flows": flows,
+                    "launches": st["launches"],
+                    "resident_flows": st["resident_flows"],
+                    "staged_flows": st["staged_flows"],
+                    "bytes_in": st["bytes_in"],
+                    "datum_lock_holds_per_flow": round(sum(
+                        c[k] for k in datum_lock) / flows, 3),
+                    "mem_lock_holds_per_flow": round(sum(
+                        c[k] for k in mem_lock) / flows, 3),
+                    "manager_sig_calls_per_task": round(sum(
+                        c[k] for k in sigs) / tasks, 3),
+                    "sig_calls_per_task": round(sum(
+                        everywhere[k] for k in sigs) / tasks, 3)}
+    finally:
+        for k in mca:
+            params.unset(k)
+    return out["ptg"]["datum_lock_holds_per_flow"], {"launch": out,
+                                                     "host": _host_info()}
+
+
 #: mode -> (runner, metric name, unit, self-declared target, "higher is
 #: better").  tools/premerge_bench.sh runs every one but tracer, which
 #: tier-1 runs (tests/test_bench_modes.py).
@@ -850,6 +945,8 @@ _AUX_MODES = {
                10.0, True),
     "release": (run_release_bench, "ptg_by_name_calls_per_task",
                 "calls/task", 1.0, False),
+    "launch": (run_launch_bench, "ptg_datum_lock_holds_per_flow",
+               "holds/flow", 1.0, False),
 }
 
 

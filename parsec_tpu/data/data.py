@@ -153,22 +153,27 @@ class Data:
         """The authoritative valid copy (highest version, OWNED/EXCLUSIVE
         preferred, then prefer_device)."""
         with self._lock:
-            best = None
-            v = self.newest_version()
-            for c in self._copies.values():
-                if c.coherency == Coherency.INVALID or c.version != v:
-                    continue
-                if best is None:
+            return self._newest_locked(prefer_device)
+
+    def _newest_locked(self, prefer_device: Optional[int]) -> Optional[DataCopy]:
+        """:meth:`newest_copy` for a caller that holds the lock."""
+        best = None
+        v = -1
+        for c in self._copies.values():
+            if c.coherency == Coherency.INVALID or c.version < v:
+                continue
+            if c.version > v:
+                v = c.version       # a newer valid copy: start over
+                best = c
+            elif (c.coherency in (Coherency.OWNED, Coherency.EXCLUSIVE)
+                  and best.coherency == Coherency.SHARED):
+                best = c
+            elif prefer_device is not None and c.device == prefer_device \
+                    and best.device != prefer_device:
+                if best.coherency == Coherency.SHARED or \
+                   c.coherency != Coherency.SHARED:
                     best = c
-                elif (c.coherency in (Coherency.OWNED, Coherency.EXCLUSIVE)
-                      and best.coherency == Coherency.SHARED):
-                    best = c
-                elif prefer_device is not None and c.device == prefer_device \
-                        and best.device != prefer_device:
-                    if best.coherency == Coherency.SHARED or \
-                       c.coherency != Coherency.SHARED:
-                        best = c
-            return best
+        return best
 
     def transfer_ownership(self, device: int, access: int) -> Optional[DataCopy]:
         """Update coherency for an upcoming access on ``device``; returns the
@@ -180,26 +185,56 @@ class Data:
             target = self._copies.get(device)
             if target is None:
                 raise KeyError(f"no copy of {self} on device {device}")
-            newest = self.newest_copy(prefer_device=device)
-            source = None
-            # A pull is only needed when the access actually reads the datum
-            # (WRITE-only flows overwrite it entirely).
-            if (access & ACCESS_READ) and (
-                    target.coherency == Coherency.INVALID or
-                    (newest is not None and target.version < newest.version)):
-                source = newest if newest is not target else None
-            if access & ACCESS_WRITE:
-                for c in self._copies.values():
-                    if c is not target:
-                        c.coherency = Coherency.INVALID
-                target.coherency = Coherency.EXCLUSIVE
-            else:
-                if target.coherency == Coherency.INVALID:
-                    target.coherency = Coherency.SHARED
-                    if newest is not None and newest.coherency == Coherency.EXCLUSIVE:
-                        newest.coherency = Coherency.OWNED
-                # valid copies stay as they are on read
-            return source
+            return self._transfer_locked(target, access)
+
+    def _transfer_locked(self, target: DataCopy,
+                         access: int) -> Optional[DataCopy]:
+        """The coherency transition of :meth:`transfer_ownership` toward
+        ``target``, an attached copy; the caller holds the lock."""
+        newest = self._newest_locked(target.device)
+        source = None
+        # A pull is only needed when the access actually reads the datum
+        # (WRITE-only flows overwrite it entirely).
+        if (access & ACCESS_READ) and (
+                target.coherency == Coherency.INVALID or
+                (newest is not None and target.version < newest.version)):
+            source = newest if newest is not target else None
+        if access & ACCESS_WRITE:
+            for c in self._copies.values():
+                if c is not target:
+                    c.coherency = Coherency.INVALID
+            target.coherency = Coherency.EXCLUSIVE
+        else:
+            if target.coherency == Coherency.INVALID:
+                target.coherency = Coherency.SHARED
+                if newest is not None and newest.coherency == Coherency.EXCLUSIVE:
+                    newest.coherency = Coherency.OWNED
+            # valid copies stay as they are on read
+        return source
+
+    def acquire_on(self, device: int, access: int, bound: DataCopy,
+                   pinned: bool = False):
+        """What a device's stage-in asks of a datum, under ONE hold of
+        its lock: ``(copy, snapshot, source)``.
+
+        ``snapshot`` is ``bound.is_pinned_snapshot(pinned)`` — the task's
+        bound copy has to be read as it stands, the datum has moved on —
+        and nothing else is touched then.  Otherwise ``copy`` is the
+        copy on ``device`` and, where there is one, the coherency of the
+        upcoming ``access`` has been applied as
+        :meth:`transfer_ownership` applies it and ``source`` is the copy
+        a transfer must pull from (None: the local copy is valid).
+        Where ``device`` has no copy yet, ``copy`` is None and nothing
+        moved: the caller creates one and transfers ownership to it."""
+        with self._lock:
+            if bound.payload is not None and (
+                    self._copies.get(bound.device) is not bound or (
+                        pinned and bound.coherency == Coherency.INVALID)):
+                return self._copies.get(device), True, None
+            target = self._copies.get(device)
+            if target is None:
+                return None, False, None
+            return target, False, self._transfer_locked(target, access)
 
     def complete_write(self, device: int) -> None:
         """Version bump after a write completes on ``device``.  Uses the

@@ -30,7 +30,7 @@ class DeviceStats:
                  "launches",
                  "held_tasks", "defused_waves", "starved_waits",
                  "inflight_waits", "compiles", "warm_waits",
-                 "release_passes",
+                 "release_passes", "resident_flows", "staged_flows",
                  "replicas_adopted", "replicas_released",
                  "replica_bytes_peak")
 
@@ -74,6 +74,15 @@ class DeviceStats:
         self.compiles = 0
         self.warm_waits = 0
         self.release_passes = 0
+        #: flows the managers staged (devices/xla.py _stage_in), by the
+        #: branch they took: the device's copy was there and valid, one
+        #: hold of the datum's lock and no transfer (resident), or
+        #: anything else — a pull from the host or another chip, a
+        #: NEW-arena scratch, a COW alias, a pinned snapshot, an evicted
+        #: payload (staged).  Their sum is every flow of every task the
+        #: device launched or held
+        self.resident_flows = 0
+        self.staged_flows = 0
         #: SHARED copies this chip held for counted consumers of another
         #: chip's tile (comm/ici.py expect; pushed over ICI or pulled by
         #: a stage-in), how many of them left again at their last

@@ -28,18 +28,17 @@ from __future__ import annotations
 
 import re
 import threading
+import time as _time
 import weakref
 from collections import OrderedDict, deque
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from parsec_tpu.core.task import HookReturn, Task
-from parsec_tpu.data.data import (ACCESS_READ, ACCESS_WRITE, Coherency,
-                                  DataCopy, FLAG_COW, FLAG_REPLICA,
-                                  FLAG_SCRATCH)
+from parsec_tpu.core.task import HookReturn, Task, normalize_body_outputs
+from parsec_tpu.data.data import (ACCESS_WRITE, Coherency, Data, DataCopy,
+                                  FLAG_COW, FLAG_REPLICA, FLAG_SCRATCH)
 from parsec_tpu.devices.device import Device
-from parsec_tpu.core.task import ToDesc
 from parsec_tpu.prof.pins import SPAN_OFF, open_span, spans_live
 from parsec_tpu.utils import faultinject as _fi
 from parsec_tpu.utils.mca import params
@@ -151,6 +150,17 @@ class XlaKernel:
         self.arg_names = list(arg_names)
         self.flow_names = set(flow_names)
         self.writable = list(writable_flows)   # flow declaration order
+        #: what a launch asks of every argument, settled once: (name,
+        #: bound from a flow's payload?) in call order, and the
+        #: positions whose buffers a donating call hands to XLA (flows
+        #: the kernel writes)
+        self.arg_plan = tuple((a, a in self.flow_names)
+                              for a in self.arg_names)
+        self.donate_pos = tuple(
+            i for i, a in enumerate(self.arg_names)
+            if a in self.flow_names and a in self.writable)
+        self._keep_pos = tuple(i for i in range(len(self.arg_names))
+                               if i not in self.donate_pos)
         #: per-instance fast path: donate-flag -> jitted callable, dodging
         #: the lock + tuple rebuild on every launch (hot path)
         self._fast: Dict[bool, Any] = {}
@@ -205,11 +215,9 @@ class XlaKernel:
 
     def _jitted_slow(self, donate: bool, n: int = 1):
         k = len(self.arg_names)
-        static1 = tuple(i for i, a in enumerate(self.arg_names)
-                        if a not in self.flow_names)
-        dn1 = tuple(i for i, a in enumerate(self.arg_names)
-                    if a in self.flow_names and a in self.writable) \
-            if donate else ()
+        static1 = tuple(i for i, (_a, is_flow) in enumerate(self.arg_plan)
+                        if not is_flow)
+        dn1 = self.donate_pos if donate else ()
         static = tuple(t * k + i for t in range(n) for i in static1)
         dn = tuple(t * k + i for t in range(n) for i in dn1)
         # the class is part of the key: two classes sharing one kernel
@@ -240,11 +248,50 @@ class XlaKernel:
             return jf
 
     def bind_outputs(self, result: Any) -> Dict[str, Any]:
-        from parsec_tpu.core.task import normalize_body_outputs
         return normalize_body_outputs(result, self.writable, what="kernel")
 
+    def task_sig(self, task: Task):
+        """What specializes this kernel's program for ``task``: the
+        values of its non-flow arguments (static argnums) and the shape
+        and dtype of each flow's payload, in call order — objects that
+        hash and compare cheaply, no strings.  Two tasks of one spec may
+        ride one fused launch exactly when their signatures are equal.
+        None: not fusable (a flow unbound, a static that does not hash).
+        A device computes it once a task, at ``submit``."""
+        sig = []
+        data = task.data
+        try:
+            for a, is_flow in self.arg_plan:
+                if is_flow:
+                    copy = data.get(a)
+                    p = copy.payload if copy is not None else None
+                    if p is None:
+                        return None
+                    sig.append(p.shape)
+                    sig.append(p.dtype)
+                else:
+                    sig.append(task.locals.get(
+                        a, task.taskpool.globals.get(a)))
+            sig = tuple(sig)
+            hash(sig)
+        except Exception:
+            return None
+        return sig
+
+    def args_sig(self, args: Sequence[Any]):
+        """:meth:`task_sig` read off one task's staged arguments."""
+        sig = []
+        for a, (_name, is_flow) in zip(args, self.arg_plan):
+            if is_flow and hasattr(a, "shape"):
+                sig.append(a.shape)
+                sig.append(a.dtype)
+            else:
+                sig.append(a)
+        return tuple(sig)
+
     def fuse_ready(self, donate: bool, n: int, flat: Sequence[Any],
-                   device: Optional["XlaDevice"] = None) -> bool:
+                   device: Optional["XlaDevice"] = None,
+                   sig: Any = None) -> bool:
         """Whether the width-``n`` fused program may be dispatched NOW.
 
         First use of a fused width triggers a full XLA compile — tens of
@@ -260,12 +307,15 @@ class XlaKernel:
         A width's state belongs to the PROGRAM, not to the taskpool: it
         sits beside the jitted callables on the kernel function, under a
         key that names the program the jit call will ask for — class,
-        donation, width, the static values and (shape, dtype) of the
-        arguments, and the device — so every later taskpool over the
-        same kernel function finds the width ready with one lookup: no
-        compile submitted, nothing waited for.  The states: absent
-        (never asked for), ``("warming", deadline)``, ``True``,
-        ``("failed", when, reason)``.
+        donation, width, the wave's signature (``sig``: the ONE
+        ``task_sig`` its members were queued under; read off the first
+        member's arguments where the caller has none) and the device —
+        so every later taskpool over the same kernel function finds the
+        width ready with one lookup: no compile submitted, nothing
+        waited for, and the arguments are looked at only by the call
+        that submits the warm compile.  The states: absent (never asked
+        for), ``("warming", deadline)``, ``True``, ``("failed", when,
+        reason)``.
 
         Whoever meets a warming width — the manager that asked for it
         or any other — waits on the condition the warmer notifies when
@@ -287,13 +337,13 @@ class XlaKernel:
             return True
         if not int(params.get("device_fuse_bg", 1)):
             return True    # kill-switch: compile widths synchronously
-        key = ("w", self.cls, donate, n, tuple(
-            (tuple(a.shape), str(a.dtype)) if hasattr(a, "shape") else a
-            for a in flat), device.name if device is not None else None)
+        if sig is None:
+            sig = self.args_sig(flat[:len(self.arg_names)])
+        key = ("w", self.cls, donate, n, sig,
+               device.name if device is not None else None)
         state = self._cache
         if state.get(key) is True:
             return True
-        import time as _time
         specs = None
         with XlaKernel._jit_cv:
             st = state.get(key)
@@ -389,7 +439,7 @@ class _FuseWarmer:
                 self._thread.start()
             self._cv.notify_all()
 
-    def cover(self, spec, donate, n, flat, device, chips) -> None:
+    def cover(self, spec, donate, n, flat, device, chips, sig=None) -> None:
         """A context that drives several chips met a wave of a kernel on
         one of them: which power-of-two wave widths meet on which chip
         is timing, so what one chip asks for every chip will — queue
@@ -401,17 +451,15 @@ class _FuseWarmer:
         persistent cache and is marked ready where
         ``XlaKernel.fuse_ready`` looks; ``prime`` then makes the jitted
         call itself on the chips that have not."""
-        k = len(spec.arg_names)
-        args = list(flat[:k])
-        sig = tuple((tuple(a.shape), str(a.dtype)) if hasattr(a, "shape")
-                    else a for a in args)
+        args = list(flat[:len(spec.arg_names)])
+        if sig is None:
+            sig = spec.args_sig(args)
         seen = ("cover", spec.cls, donate, sig)
         state = spec._cache
         if seen in state:
             return
         import jax
         from jax.sharding import SingleDeviceSharding
-        import time as _time
         limit = max(1, int(params.get("device_fuse", 8)))
         widths = [1 << i for i in range(limit.bit_length())]
         todo = []
@@ -426,7 +474,7 @@ class _FuseWarmer:
                 one = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
                        if hasattr(a, "shape") else a for a in args]
                 for w in sorted(widths, key=lambda w: w != n):
-                    key = ("w", spec.cls, donate, w, sig * w, dev.name)
+                    key = ("w", spec.cls, donate, w, sig, dev.name)
                     if key not in state:
                         state[key] = ("warming", deadline)
                         todo.append((key, w, one * w, dev))
@@ -437,7 +485,6 @@ class _FuseWarmer:
         """Block until every queued width compile has finished — the
         bench-warmup hook: a timed rep must not run de-fused because
         its widths are still warming (see xla.wait_fuse_warm)."""
-        import time as _time
         deadline = _time.monotonic() + timeout
         with self._cv:
             while self._q or self._busy:
@@ -472,7 +519,6 @@ class _FuseWarmer:
                     jf.lower(*arg_specs).compile()
             except Exception as exc:
                 reason = f"{type(exc).__name__}: {exc}"
-            import time as _time
             with XlaKernel._jit_cv:
                 # failure memoization with backoff: a persistently
                 # failing width must not make every wave re-pay the
@@ -909,11 +955,18 @@ class XlaDevice(Device):
         flops = task.task_class.properties.get("flops", 1.0)
         load = float(flops(task.locals)) if callable(flops) else float(flops)
         self.load_add(load)
+        # the fusion signature rides the queued item: computed ONCE a
+        # task, here on the submitting worker's thread and outside
+        # ``_cond``, and only compared by the managers' wave scan.  A
+        # panel-chain link (POTRF, GEQRT, TSQRT) has none: it goes alone
+        # (see _pop_wave_locked)
+        sig = None if task.task_class.properties.get("fuse_chain") \
+            else spec.task_sig(task)
         with self._cond:
             if self.es is None:
                 from parsec_tpu.core.context import ExecutionStream
                 self.es = ExecutionStream(es.context, th_id=900 + self.space)
-            self._pending.append((task, spec, load))
+            self._pending.append((task, spec, load, sig))
             self._cond.notify_all()
         return HookReturn.ASYNC
 
@@ -922,7 +975,6 @@ class XlaDevice(Device):
     # submit phases of the manager state machine)
     # ------------------------------------------------------------------
     def _manager_loop(self):
-        import time as _time
         while True:
             with self._cond:
                 first = self._take_first_locked()
@@ -947,8 +999,8 @@ class XlaDevice(Device):
                     late = {"pool": task0.taskpool.taskpool_id,
                             "cls": task0.task_class.name,
                             "n": len(batch), "held": 0}
-                    ready = [t.ready_at for t, _s, _l in batch
-                             if t.ready_at is not None]
+                    ready = [item[0].ready_at for item in batch
+                             if item[0].ready_at is not None]
                     if ready:
                         late["wait_us"] = int(
                             (_time.perf_counter() - min(ready)) * 1e6)
@@ -962,13 +1014,14 @@ class XlaDevice(Device):
             except Exception as exc:   # stage-in/compile failure
                 from parsec_tpu.core import scheduling
                 self.stats.faults += 1
-                for _task, _spec, qload in batch:
-                    self.load_sub(qload)
-                rescued = self._degrade([t for t, _s, _l in batch], exc)
+                for item in batch:
+                    self.load_sub(item[2])
+                rescued = self._degrade([item[0] for item in batch], exc)
                 if not rescued:
-                    for t, _s, _l in batch:
-                        self.es.context.record_error(exc, t)
-                        scheduling.complete_execution(self.es, t, failed=True)
+                    for item in batch:
+                        self.es.context.record_error(exc, item[0])
+                        scheduling.complete_execution(self.es, item[0],
+                                                      failed=True)
             finally:
                 launch.end()
                 with self._cond:
@@ -985,7 +1038,6 @@ class XlaDevice(Device):
         if not self._held or self._stop:
             return False
         name = task.task_class.name
-        import time as _time
         now = _time.monotonic()
         for copy in task.data.values():
             p = copy.payload if copy is not None else None
@@ -1017,33 +1069,29 @@ class XlaDevice(Device):
 
     def _pop_wave_locked(self, first):
         """``first`` plus every queued same-class sibling it can fuse
-        with (same kernel spec, equal non-flow args, matching payload
-        shapes), up to ``device_fuse`` (wavefront launch fusion;
-        reference analog: the GPU manager draining its pending FIFO into
-        the exec streams, device_cuda_module.c:2697 — here the drain
-        fuses the wave into one XLA program).  Non-matching entries keep
-        their queue order.  Caller holds ``_cond``."""
+        with (same kernel spec, equal signature — ``XlaKernel.task_sig``:
+        equal non-flow args, matching payload shapes and dtypes — as
+        ``submit`` queued it), up to ``device_fuse`` (wavefront launch
+        fusion; reference analog: the GPU manager draining its pending
+        FIFO into the exec streams, device_cuda_module.c:2697 — here the
+        drain fuses the wave into one XLA program).  Non-matching entries
+        keep their queue order.  Caller holds ``_cond``."""
         limit = int(params.get("device_fuse", 8))
-        if limit <= 1:
-            return [first]
-        task, spec, _load = first
-        if task.task_class.properties.get("fuse_chain"):
-            # a panel-chain link (POTRF, GEQRT, TSQRT) goes alone: its
-            # queued siblings sit on OTHER panels' serial chains, which
-            # links meet is timing, and a wave of them costs the
-            # heaviest kernel's compile once more per width (seen on a
-            # v5e: a two-wide TSQRT wave at mb=6144, ~250 s and a
-            # 180 MB executable, PERF.md PR 21)
-            return [first]
-        sig = self._fuse_sig(task, spec)
-        if sig is None:
+        spec, sig = first[1], first[3]
+        if limit <= 1 or sig is None:
+            # no signature: a flow unbound, a static that does not hash,
+            # or a panel-chain link (POTRF, GEQRT, TSQRT), which goes
+            # alone: its queued siblings sit on OTHER panels' serial
+            # chains, which links meet is timing, and a wave of them
+            # costs the heaviest kernel's compile once more per width
+            # (seen on a v5e: a two-wide TSQRT wave at mb=6144, ~250 s
+            # and a 180 MB executable, PERF.md PR 21)
             return [first]
         window = float(params.get("device_fuse_window_ms", 0.0)) * 1e-3
         if not self._pending and window <= 0:
             return [first]
         batch = [first]
         rest = []
-        import time as _time
         deadline = _time.monotonic() + window
         while True:
             # bound each scan at a small multiple of the fuse width: the
@@ -1054,8 +1102,7 @@ class XlaDevice(Device):
                     and scan_budget > 0:
                 scan_budget -= 1
                 cand = self._pending.popleft()
-                if cand[1] is spec and \
-                        self._fuse_sig(cand[0], spec) == sig \
+                if cand[1] is spec and cand[3] == sig \
                         and not self._awaits_successor(cand[0]):
                     batch.append(cand)
                 else:
@@ -1092,29 +1139,6 @@ class XlaDevice(Device):
         batch = batch[:quant]
         return batch
 
-    @staticmethod
-    def _fuse_sig(task: Task, spec: XlaKernel):
-        """Fusion compatibility signature: the values of non-flow kernel
-        args (static argnums — they specialize the compile) and the
-        shape/dtype of each flow payload.  None = not fusable (unbound
-        or unhashable)."""
-        sig = []
-        try:
-            for a in spec.arg_names:
-                if a in spec.flow_names:
-                    copy = task.data.get(a)
-                    p = copy.payload if copy is not None else None
-                    if p is None:
-                        return None
-                    sig.append((a, tuple(p.shape), str(p.dtype)))
-                else:
-                    v = task.locals.get(a, task.taskpool.globals.get(a))
-                    hash(v)
-                    sig.append((a, v))
-        except Exception:
-            return None
-        return tuple(sig)
-
     def _degrade(self, tasks: List[Task], exc: Exception) -> bool:
         """Degraded mode (the reference's ONLY fault tolerance: device
         errors disable the device and push tasks back to the CPU
@@ -1140,7 +1164,7 @@ class XlaDevice(Device):
                         self.stats.faults, exc)
             if not self.enabled:
                 while self._pending:
-                    qtask, _spec, qload = self._pending.popleft()
+                    qtask, _spec, qload, _sig = self._pending.popleft()
                     self.load_sub(qload)
                     rescued.append(qtask)
         for t in rescued:
@@ -1149,10 +1173,11 @@ class XlaDevice(Device):
         return True
 
     def _launch(self, batch, seq: int = 0) -> bool:
-        """Stage and dispatch one wave: a list of (task, spec, load) with
-        a shared kernel spec (len 1 = the plain single-task launch).  The
-        whole wave rides ONE jitted call (XlaKernel.jitted_fused), so a
-        k-wide TRSM/SYRK/GEMM wavefront costs one dispatch round trip.
+        """Stage and dispatch one wave: a list of (task, spec, load, sig)
+        with a shared kernel spec and ONE signature (len 1 = the plain
+        single-task launch).  The whole wave rides ONE jitted call
+        (XlaKernel.jitted_fused), so a k-wide TRSM/SYRK/GEMM wavefront
+        costs one dispatch round trip.
         ``seq`` is the launch's number on this device; returns True
         where the wave was a chain head that was held, not dispatched."""
         spec: XlaKernel = batch[0][1]
@@ -1165,38 +1190,45 @@ class XlaDevice(Device):
         pinned_per: List[List[Any]] = []
         release_per: List[List[DataCopy]] = []
         flat: List[Any] = []
+        chained = False
         try:
             stage = open_span(self.es, "mgr.stage_in")
             bytes0 = self.stats.bytes_in
-            for task, _spec, _load in batch:
-                tc = task.task_class
+            # pin every datum the wave touches before any eviction
+            # decision, and freshen it in the LRU
+            self._pin_wave(batch, pinned_per)
+            for item in batch:
+                task = item[0]
+                data = task.data
+                pinned_flows = task.pinned_flows
                 staged: Dict[str, Any] = {}
-                pinned: List[Any] = []
                 release_after: List[DataCopy] = []
-                pinned_per.append(pinned)
                 release_per.append(release_after)
-                # pin every datum this task touches before any eviction
-                # decision
-                for flow in tc.flows:
-                    copy = task.data.get(flow.name)
-                    if copy is not None and copy.data is not None:
-                        self._pin(copy.data)
-                        pinned.append(copy.data)
-                for flow in tc.flows:
-                    copy = task.data.get(flow.name)
+                for flow in task.task_class.flows:
+                    name = flow.name
+                    copy = data.get(name)
                     if copy is None:
                         continue
                     dc = self._stage_in(copy, flow.access,
-                                        pinned=flow.name in task.pinned_flows)
-                    if dc is not copy and copy.device == 0 \
-                            and copy.arena is not None:
-                        # host arena temp fully superseded by the device
-                        # copy: return it to the freelist once the kernel
-                        # completes (the H2D transfer may still read it)
-                        copy.data.detach_copy(0)
-                        release_after.append(copy)
-                    task.data[flow.name] = dc
-                    staged[flow.name] = dc.payload
+                                        name in pinned_flows)
+                    if dc is not copy:
+                        if copy.device == 0 and copy.arena is not None:
+                            # host arena temp fully superseded by the
+                            # device copy: return it to the freelist once
+                            # the kernel completes (the H2D transfer may
+                            # still read it)
+                            copy.data.detach_copy(0)
+                            release_after.append(copy)
+                        data[name] = dc
+                    p = dc.payload
+                    if p.__class__ is Deferred:
+                        # an already-resolved chain placeholder
+                        # substitutes transparently
+                        if p.array is not None:
+                            p = p.array
+                        else:
+                            chained = True
+                    staged[name] = p
                 for a in spec.arg_names:
                     if a in staged:
                         flat.append(staged[a])
@@ -1204,13 +1236,9 @@ class XlaDevice(Device):
                         flat.append(task.locals[a])
                     else:
                         flat.append(task.taskpool.globals.get(a))
-            # already-resolved chain placeholders substitute transparently
-            flat = [a.array if isinstance(a, Deferred)
-                    and a.array is not None else a for a in flat]
             # (with two managers the delta can hold the other's bytes)
             stage.end(bytes_in=self.stats.bytes_in - bytes0)
             stage = SPAN_OFF
-            chained = any(isinstance(a, Deferred) for a in flat)
             if n == 1 and spec.writable and not chained \
                     and self._chain_eligible(batch[0][0], spec):
                 # chain head (POTRF(k), TSQRT(m,k)...): hold instead of
@@ -1228,11 +1256,12 @@ class XlaDevice(Device):
             cover = not batch[0][0].task_class.properties.get("fuse_chain")
             if chained:
                 outs_per_task = self._dispatch_chained(
-                    spec, n, flat, cover, batch[0][0].task_class.name)
+                    spec, n, flat, cover, batch[0][0].task_class.name,
+                    batch[0][3])
                 fused = False
             else:
-                fused, outs_per_task = self._dispatch_plain(spec, n, flat,
-                                                            cover)
+                fused, outs_per_task = self._dispatch_plain(
+                    spec, n, flat, cover, batch[0][3])
             if fused:
                 # count only waves the fused program actually executed —
                 # a de-fused n>1 wave (fuse_ready False) ran singles
@@ -1240,9 +1269,7 @@ class XlaDevice(Device):
                 self.stats.fused_tasks += n
         except Exception:
             stage.end()
-            for pinned in pinned_per:
-                for d in pinned:
-                    self._unpin(d)
+            self._unpin_all(d for pinned in pinned_per for d in pinned)
             # arena copies already detached for deferred release would
             # otherwise leak on the failure path (ADVICE r1 low);
             # release_unheld: a chained NEW-flow buffer a predecessor's
@@ -1260,14 +1287,14 @@ class XlaDevice(Device):
             # the flight recorder's incident ring).  The gate is
             # maintained by Context._recompute_ready_stamp, so a
             # recorder whose classes exclude 'device' costs nothing
-            for task, _spec2, _load2 in batch:
-                self.es.pins("device_dispatch", task)
+            for item in batch:
+                self.es.pins("device_dispatch", item[0])
         with self._cond:
             self._wait_room_locked(n)
-            for i, (task, _spec, load) in enumerate(batch):
+            for i, item in enumerate(batch):
                 self._inflight.append(
-                    _Inflight(self.es, task, spec, outs_per_task[i],
-                              pinned_per[i], load, release_per[i], seq))
+                    _Inflight(self.es, item[0], spec, outs_per_task[i],
+                              pinned_per[i], item[2], release_per[i], seq))
             self._cond.notify_all()
         return False
 
@@ -1302,22 +1329,24 @@ class XlaDevice(Device):
             return jf(*args)
 
     def _dispatch_plain(self, spec: XlaKernel, n: int, flat: List[Any],
-                        cover: bool = False):
+                        cover: bool = False, sig: Any = None):
         """The pre-existing dispatch path: one (possibly width-fused)
         jitted call over real arrays.  Returns (fused, bound outputs per
         task).  ``cover``: the class is one whose widths may be warmed
-        on every chip (``_FuseWarmer.cover``)."""
+        on every chip (``_FuseWarmer.cover``); ``sig``: the signature
+        the wave's members were queued under (``XlaKernel.task_sig``),
+        where the caller has it."""
         donate = self._donate and not self._donation_hazard(spec, flat)
         ici = self.es.context.ici if cover and n > 1 else None
         if ici is not None and int(params.get("device_fuse_bg", 1)):
             # several chips, and a class that meets in waves: what this
             # chip runs of it, every chip will
             _fuse_warmer.cover(spec, donate, n, flat, self,
-                               ici.xla_devices)
+                               ici.xla_devices, sig)
 
         if n == 1:
             fused, results = False, [self._call(spec.jitted(donate), flat)]
-        elif not spec.fuse_ready(donate, n, flat, self):
+        elif not spec.fuse_ready(donate, n, flat, self, sig):
             # the fused width is still compiling in the background
             # (Cholesky-class programs take tens of seconds), or its
             # compile failed (fuse_failures says why): dispatch singles
@@ -1399,7 +1428,7 @@ class XlaDevice(Device):
         the already-staged copies, and the task completes eagerly
         through the normal completer path (deps release, successors
         instantiate) without any dispatch."""
-        task, spec, load = item
+        task, spec, load = item[:3]
         h = _Hold()
         h.device = self
         h.task = task
@@ -1409,7 +1438,6 @@ class XlaDevice(Device):
         # (flow, successor class); a bare flow name takes any consumer
         h.succ = _declared_successor(
             task.task_class.properties["fuse_chain"])
-        import time as _time
         h.deadline = _time.monotonic() + _HOLD_PATIENCE_S
         h.outputs = {}
         for fl in spec.writable:
@@ -1548,7 +1576,8 @@ class XlaDevice(Device):
             self._chain_cv.notify_all()
 
     def _dispatch_chained(self, spec: XlaKernel, n: int, flat: List[Any],
-                          cover: bool, cls: str) -> List[Dict[str, Any]]:
+                          cover: bool, cls: str,
+                          sig: Any = None) -> List[Dict[str, Any]]:
         """Launch a wave of class ``cls`` whose inputs include unresolved
         chain placeholders.  ONE head held for this class is traced in
         front of the wave in one program (``jit_parsec_chain_<HEAD>__
@@ -1574,7 +1603,7 @@ class XlaDevice(Device):
             if not claimed:
                 if any(isinstance(a, Deferred) for a in flat):
                     continue          # raced a fresh hold: look again
-                _f, outs = self._dispatch_plain(spec, n, flat, cover)
+                _f, outs = self._dispatch_plain(spec, n, flat, cover, sig)
                 return outs
             try:
                 head_outs, wave_outs = self._run_chain(head, spec, n, flat)
@@ -1601,7 +1630,6 @@ class XlaDevice(Device):
         """Force every remaining hold (sync/teardown): consumers that
         never reached this device must not leave a panel chain
         undispatched."""
-        import time as _time
         deadline = _time.monotonic() + 60.0
         while True:
             with self._chain_cv:
@@ -1625,25 +1653,34 @@ class XlaDevice(Device):
         argument of the same (possibly fused) call: two wave tasks
         sharing an operand where one donates it would hand XLA the same
         buffer as both alias-donated and live input.  Falling back to
-        no-donation for the launch is always safe."""
-        k = len(spec.arg_names)
-        donatable = [i for i, a in enumerate(spec.arg_names)
-                     if a in spec.flow_names and a in spec.writable]
-        if not donatable:
+        no-donation for the launch is always safe.  One pass over the
+        wave's arguments, the donated positions (``spec.donate_pos``)
+        first."""
+        dpos = spec.donate_pos
+        if not dpos:
             return False
-        donated_ids = set()
-        for t in range(len(flat) // k):
-            for i in donatable:
-                donated_ids.add(id(flat[t * k + i]))
-        seen = {}
-        for j, v in enumerate(flat):
-            seen[id(v)] = seen.get(id(v), 0) + 1
-        return any(seen.get(d, 0) > 1 for d in donated_ids)
+        k = len(spec.arg_names)
+        bases = range(0, len(flat), k)
+        donated = {id(flat[b + i]) for b in bases for i in dpos}
+        if len(donated) < len(bases) * len(dpos):
+            return True         # one buffer at two donated positions
+        keep = spec._keep_pos
+        return any(id(flat[b + i]) in donated for b in bases for i in keep)
 
     def _stage_in(self, copy: DataCopy, access: int,
                   pinned: bool = False) -> DataCopy:
         """Ensure a valid copy of ``copy``'s datum on this device
         (reference: parsec_gpu_data_stage_in, device_cuda_module.c:1261).
+        The caller has pinned the datum and freshened it in the LRU
+        (``_pin_wave``).
+
+        The resident case comes first: the device's copy is there, valid
+        at the newest version, and the bound copy is neither a snapshot
+        nor a COW alias nor NEW-arena scratch — one hold of the datum's
+        lock (``Data.acquire_on``) says so and applies the access's
+        coherency transition, and the copy goes out as it is
+        (``resident_flows``).  Everything else is staged
+        (``staged_flows``) by the code below.
 
         A bound copy that a writeback replacement detached — or, for a
         task-fed (pinned) input, invalidated in place — is a
@@ -1651,23 +1688,41 @@ class XlaDevice(Device):
         device copy without consulting the datum's coherency, which has
         moved on.  (A detached copy with payload None was merely evicted
         and re-stages from the datum's newest valid copy below.)"""
-        import jax
         datum = copy.data
         p0 = copy.payload
-        if isinstance(p0, Deferred):
+        held_here = False
+        if p0.__class__ is Deferred:
             if p0.array is not None:
                 copy.payload = p0.array     # resolved: unwrap in place
             elif p0.hold.device is not self:
                 # produced by a chain held on ANOTHER device: force that
                 # chain there, then stage the real array normally (D2D)
                 copy.payload = p0.force()
-            elif copy.flags & FLAG_COW or copy.is_pinned_snapshot(pinned):
-                # snapshot/COW paths materialize private buffers from
-                # the payload — they need the real array
-                copy.payload = p0.force()
-            # else: leave the placeholder — this device's launch traces
-            # the chain into the consuming program (_dispatch_chained)
-        if copy.flags & FLAG_SCRATCH and copy.version == 0 \
+            else:
+                # this device's launch traces the chain into the
+                # consuming program (_dispatch_chained): the placeholder
+                # stays, unless a private buffer has to be made from it
+                held_here = True
+        flags = copy.flags
+        acquired = not flags & (FLAG_COW | FLAG_SCRATCH)
+        if acquired:
+            dc, snapshot, src = datum.acquire_on(self.space, access, copy,
+                                                 pinned)
+            if dc is not None and src is None and not snapshot \
+                    and dc.payload is not None:
+                self.stats.resident_flows += 1
+                return dc
+        else:
+            dc = src = None
+            snapshot = not flags & FLAG_COW \
+                and copy.is_pinned_snapshot(pinned)
+        self.stats.staged_flows += 1
+        if held_here and (flags & FLAG_COW or snapshot):
+            # snapshot/COW paths materialize private buffers from
+            # the payload — they need the real array
+            copy.payload = p0.force()
+        import jax
+        if flags & FLAG_SCRATCH and copy.version == 0 \
                 and access & ACCESS_WRITE and copy.arena is not None:
             # NEW-flow scratch straight from the arena: the np.empty host
             # buffer's content is undefined, so materialize the copy
@@ -1689,8 +1744,7 @@ class XlaDevice(Device):
             self._account(datum, dc, nbytes, off)
             self._touch(datum)
             return dc
-        if (copy.flags & FLAG_COW) == 0 and copy.is_pinned_snapshot(pinned):
-            from parsec_tpu.data.data import Data
+        if snapshot:
             payload = copy.payload
             nbytes = getattr(payload, "nbytes", 0)
             off = self._reserve(nbytes)
@@ -1710,11 +1764,13 @@ class XlaDevice(Device):
             self.stats.bytes_in += nbytes
             self._account(snap, dc, nbytes, off)
             return dc
-        dc = datum.copy_on(self.space)
+        if not acquired:
+            dc = datum.copy_on(self.space)
         fresh = dc is None
         if fresh:
             dc = datum.create_copy(self.space)
-        src = datum.transfer_ownership(self.space, access)
+        if fresh or not acquired:
+            src = datum.transfer_ownership(self.space, access)
         if src is not None or dc.payload is None:
             payload = src.payload if src is not None else copy.payload
             nbytes = getattr(payload, "nbytes", 0)
@@ -1745,7 +1801,7 @@ class XlaDevice(Device):
                 self._account(datum, dc, nbytes, off)
             if not access & ACCESS_WRITE:
                 self._note_replica(datum, dc)
-        if copy.flags & FLAG_COW and copy is not dc:
+        if flags & FLAG_COW and copy is not dc:
             # The COW alias's payload aliases the producer's buffer (for
             # DATA-fed fan-outs: the collection's backing array).  The
             # device copy above is private, so drop the alias from the
@@ -2117,12 +2173,28 @@ class XlaDevice(Device):
     # ------------------------------------------------------------------
     # device memory cache management (reference: gpu_mem_lru / zone_malloc)
     # ------------------------------------------------------------------
-    def _pin(self, datum) -> None:
+    def _pin_wave(self, batch, pinned_per: List[List[Any]]) -> None:
+        """Pin every datum the tasks of ``batch`` touch — one list a
+        task appended to ``pinned_per``, what its inflight entry gives
+        back — and freshen each in the LRU, under ONE hold of
+        ``_mem_lock``: no ``_reserve`` of the wave's own stage-ins, nor
+        a concurrent dispatcher's, finds one of them a victim."""
         with self._mem_lock:
-            self._pins[id(datum)] = self._pins.get(id(datum), 0) + 1
-
-    def _unpin(self, datum) -> None:
-        self._unpin_all((datum,))
+            pins, lru = self._pins, self._lru
+            for item in batch:
+                task = item[0]
+                data = task.data
+                pinned: List[Any] = []
+                pinned_per.append(pinned)
+                for flow in task.task_class.flows:
+                    copy = data.get(flow.name)
+                    if copy is not None and copy.data is not None:
+                        datum = copy.data
+                        key = id(datum)
+                        pins[key] = pins.get(key, 0) + 1
+                        if key in lru:
+                            lru.move_to_end(key)
+                        pinned.append(datum)
 
     def _unpin_all(self, data) -> None:
         with self._mem_lock:
@@ -2175,7 +2247,6 @@ class XlaDevice(Device):
         fresh."""
         if self._zone is None:
             return None
-        import time as _time
         deadline = _time.monotonic() + 30.0
         while True:
             with self._mem_lock:

@@ -788,7 +788,11 @@ class RuntimeMetrics:
                     ("compiles", "parsec_device_compiles_total"),
                     ("warm_waits", "parsec_device_warm_waits_total"),
                     ("release_passes",
-                     "parsec_device_release_passes_total")):
+                     "parsec_device_release_passes_total"),
+                    ("resident_flows",
+                     "parsec_device_resident_flows_total"),
+                    ("staged_flows",
+                     "parsec_device_staged_flows_total")):
                 v = getattr(st, key, None)
                 if isinstance(v, (int, float)) and v:
                     out.append(counter_sample(metric, v, labels))

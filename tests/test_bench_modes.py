@@ -68,6 +68,34 @@ def test_release_canary_counts_a_task_of_the_cell_3_dag(monkeypatch, capsys):
     assert dtd["release"]["deliveries"] == 0
 
 
+def test_launch_canary_counts_a_flow_of_the_cell_3_dag(monkeypatch, capsys):
+    """Counts, not a speed (PR 36): a manager takes a datum's lock once
+    a flow it stages (the parent took it three times) and the device's
+    memory lock once a WAVE (twice a flow), builds no signature itself
+    (one a task, at ``submit``; a chain link has none), and every
+    operand of the job is resident but its nt - 1 NEW-arena ``W``
+    panels, PTG and DTD alike."""
+    monkeypatch.setenv("PARSEC_BENCH_APP", "launch")
+    bench.main()
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["metric"] == "ptg_datum_lock_holds_per_flow"
+    assert line["device"]["platform"] == "cpu"
+    for front in ("ptg", "dtd"):
+        got = line["launch"][front]
+        assert got["tasks"] == 5984
+        # POTRF T W, POTRFL T, TRSM W C, SYRK T R, GEMM C L R
+        assert got["flows"] == 2 * 31 + 1 + 2 * 496 * 2 + 3 * 4960 == 16927
+        assert got["staged_flows"] == 31 and got["bytes_in"] == 0
+        assert got["resident_flows"] == got["flows"] - 31
+        # one hold a resident flow; a NEW panel's copy is made and
+        # handed over under a few more
+        assert 1.0 <= got["datum_lock_holds_per_flow"] < 1.02
+        # one hold a wave (5-7 tasks of 2-3 flows), one more a panel
+        assert got["mem_lock_holds_per_flow"] < 0.2
+        assert got["manager_sig_calls_per_task"] == 0
+        assert got["sig_calls_per_task"] == round(5952 / 5984, 3)
+
+
 def test_premerge_names_only_modes_that_exist():
     script = os.path.join(os.path.dirname(os.path.abspath(bench.__file__)),
                           "tools", "premerge_bench.sh")
