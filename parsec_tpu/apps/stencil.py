@@ -2,12 +2,23 @@
 
 Rebuild of the reference's stencil mini-app (reference:
 tests/apps/stencil/testing_stencil_1D.c + stencil_1D.jdf — a radius-R 1D
-stencil iterated T times, each tile exchanging halos with its neighbors
-every step; the wavefront pipeline is the canonical PTG pattern).  Here
-the exchange is whole-tile (periodic boundaries) and each S(t, i) task
-consumes its own tile plus both neighbors from step t-1 — the producer's
-copy fans out to one writer and two readers, exercising the engine's
-copy-on-write fan-out semantics.
+stencil iterated T times, each tile exchanging ghost regions with its
+neighbors every step; the wavefront pipeline is the canonical PTG
+pattern).  The stencil runs along the rows of the grid: a tile is
+``mb`` rows of a 1-D vector, or ``mb`` rows x ``nb`` lanes of a matrix
+one tile wide, every lane an independent 1-D problem (upstream's rows of
+the matrix; on a TPU the lanes are what fills a vector register).
+
+What crosses a tile boundary is the halo alone, as upstream's ``displ``
+partial tiles: every ``S(t, i)`` writes, beside its tile ``C``, the top
+and the bottom ``H`` rows of the new tile into two arena buffers
+(``TOP``, ``BOT``), and reads its neighbours' as ``HL`` / ``HR``.  So a
+tile has ONE consumer — the next sweep's writer of the same tile — and
+is updated in place (the device module donates it); nothing is snapshot,
+nothing copied but 2 x H rows a task.  ``H`` is the number of sweeps a
+task runs (``fuse``): with ``fuse`` > 1 a task runs ``fuse`` sweeps in
+one kernel over its tile widened by ``fuse`` rows either side, the
+S-deep-halo trade for an overhead-bound fine-grained pipeline.
 
 The same computation lowers to one shard_map program on a mesh
 (parallel/spmd.halo_stencil_fn) — the task graph is the irregular/
@@ -16,199 +27,155 @@ multi-pool form, the SPMD schedule the regular one.
 
 from __future__ import annotations
 
-from typing import Optional
-
 import numpy as np
 
 from parsec_tpu.core.taskpool import ParameterizedTaskpool
 from parsec_tpu.data.matrix import TiledMatrix
-from parsec_tpu.dsl.ptg.api import DATA, IN, OUT, PTG, Range, TASK
+from parsec_tpu.dsl.ptg.api import DATA, IN, NEW, OUT, PTG, Range, TASK
 
 _kernels = {}
 
 
-def _k_step():
-    fn = _kernels.get("step")
+def _sweeps(xp, HL, C, HR, ns: int):
+    """``ns`` sweeps of the 3-point mean over ``C`` between its halos, in
+    ``xp`` (numpy or jax.numpy): the widened tile loses a row either
+    side a sweep, so after ``ns`` <= H sweeps its middle ``mb`` rows are
+    the new tile.  Returns the written flows in their declaration order:
+    the new tile, its top H rows, its bottom H rows."""
+    H, mb = HL.shape[0], C.shape[0]
+    u = xp.concatenate([HL, C, HR])
+    for _ in range(ns):
+        u = (u[:-2] + u[2:] + u[1:-1]) / 3.0
+    new = u[H - ns:H - ns + mb]
+    return new, new[:H], new[mb - H:]
+
+
+def _k_sweep():
+    fn = _kernels.get("sweep")
     if fn is None:
-        def fn(L, C, R):
-            import jax.numpy as jnp
-            ext = jnp.concatenate([L[-1:], C, R[:1]])
-            return (ext[:-2] + ext[2:] + C) / 3.0
-        _kernels["step"] = fn
+        import jax
+        import jax.numpy as jnp
+        from parsec_tpu.apps.pallas_kernels import pallas_sweep_tile
+        in_place = pallas_sweep_tile(
+            lambda HL, C, HR: _sweeps(jnp, HL, C, HR, 1))
+
+        def fn(HL, C, HR, ns):
+            # ns is a task local -> static argnum: at most two distinct
+            # programs a halo depth (full blocks + the remainder)
+            if ns == HL.shape[0] == 1 and C.ndim == 2 \
+                    and jax.default_backend() == "tpu":
+                # one sweep of a (rows x lanes) tile on the chip: in
+                # place, where XLA's own form costs a copy of the tile
+                return in_place(HL, C, HR)
+            return _sweeps(jnp, HL, C, HR, ns)
+        _kernels["sweep"] = fn
     return fn
 
 
-def _k_fused():
-    fn = _kernels.get("fused")
+def _k_halos(H: int):
+    fn = _kernels.get(("halos", H))
     if fn is None:
-        def fn(L, C, R, ns):
-            # ns consecutive sweeps in ONE kernel over the concatenated
-            # [L C R] array: the array's outer edges evolve with wrapped
-            # garbage, but wrongness propagates one element per sweep
-            # and never reaches the center tile for ns <= mb — the
-            # S-deep-halo trade (VERDICT r4 #4, the GEMM k-chain trick
-            # applied to sweeps).  ns is a task local -> static argnum:
-            # at most two distinct programs (full blocks + remainder).
-            import jax.numpy as jnp
-            from jax import lax
-            ext = jnp.concatenate([L, C, R])
-
-            def one(_, u):
-                e = jnp.concatenate([u[-1:], u, u[:1]])
-                return (e[:-2] + e[2:] + u) / 3.0
-            out = lax.fori_loop(0, ns, one, ext)
-            mb = C.shape[0]
-            return out[mb:2 * mb]
-        _kernels["fused"] = fn
+        def fn(X):
+            return X[:H], X[X.shape[0] - H:]
+        _kernels[("halos", H)] = fn
     return fn
 
 
 def stencil_taskpool(V: TiledMatrix, steps: int,
                      device: str = "tpu",
                      fuse: int = 1) -> ParameterizedTaskpool:
-    """Iterate the 3-point periodic mean stencil ``steps`` times over the
-    tile vector V (in place).
+    """Iterate the 3-point periodic mean stencil ``steps`` times along
+    the rows of V, a vector of tiles or a matrix one tile wide (in
+    place).
 
-    ``fuse``: sweeps fused per task (S-deep halo; requires
-    ``fuse <= V.mb``).  Each task runs ``fuse`` sweeps in one kernel
-    over its 3-tile neighborhood, cutting the per-point runtime
-    overhead by the fusion depth at 3x the element updates — the right
-    trade for an overhead-bound fine-grained pipeline (reference
-    harness: tests/apps/stencil/testing_stencil_1D.c)."""
-    if fuse > 1:
-        if fuse > V.mb:
-            raise ValueError(f"fuse depth {fuse} exceeds tile size {V.mb}")
-        return _stencil_taskpool_fused(V, steps, device, fuse)
-    NT = V.mt
+    ``fuse``: sweeps per task = rows of halo a task trades with each
+    neighbour (requires ``fuse <= V.mb``).  ``-(-steps // fuse)`` blocks
+    of one ``S`` task a tile; the last block carries the remainder as
+    its ``ns`` local (reference harness:
+    tests/apps/stencil/testing_stencil_1D.c)."""
+    NT, mb, H = V.mt, V.mb, int(fuse)
+    if H < 1 or H > mb:
+        raise ValueError(f"fuse depth {fuse} must lie in 1..{mb}, the tile")
     if NT < 2:
         raise ValueError("stencil needs at least 2 tiles")
+    if V.nt != 1 or V.lm % mb:
+        raise ValueError("stencil needs one column of whole tiles")
+    NB = -(-steps // H)          # blocks of H sweeps, the last ragged
+    halos = _k_halos(H)
 
-    def cpu_step(L, C, R):
-        ext = np.concatenate([np.asarray(L)[-1:], np.asarray(C),
-                              np.asarray(R)[:1]])
-        return (ext[:-2] + ext[2:] + np.asarray(C)) / 3.0
+    def ns_of(globals_, locals_):
+        return [min(H, steps - locals_["t"] * H)]
+
+    def cpu_sweep(HL, C, HR, ns):
+        return _sweeps(np, np.asarray(HL), np.asarray(C), np.asarray(HR),
+                       int(ns))
 
     p = PTG("stencil", NT=NT, T=steps)
-    # INIT(i) reads each tile once and broadcasts it to the three t=0
-    # consumers — reading AND writing a collection tile at the same
-    # wavefront without a dep edge would be a DAG race (and remote reads
-    # are not allowed anyway); the fan-out then rides the engine's
-    # copy-on-write semantics.
-    p.task("INIT", i=Range(0, NT - 1)) \
+    p.arena("halo", (H,) + tuple(V.tile_shape(0, 0)[1:]), dtype=V.dtype)
+    # INIT(i) reads each tile once, cuts its two halos for the t=0
+    # neighbours and hands the tile on to its own S(0, i) — reading AND
+    # writing a collection tile at the same wavefront without a dep edge
+    # would be a DAG race (and remote reads are not allowed anyway).
+    tb = p.task("INIT", i=Range(0, NT - 1)) \
         .affinity(lambda i, V=V: V(i)) \
         .flow("X", "READ",
               IN(DATA(lambda i, V=V: V(i))),
-              OUT(TASK("S", "C", lambda i: dict(t=0, i=i))),
-              OUT(TASK("S", "L", lambda i, NT=NT: dict(t=0,
-                                                       i=(i + 1) % NT))),
-              OUT(TASK("S", "R", lambda i, NT=NT: dict(t=0,
-                                                       i=(i - 1) % NT)))) \
-        .body(lambda: None)
-    tb = p.task("S", t=Range(0, steps - 1), i=Range(0, NT - 1)) \
+              OUT(TASK("S", "C", lambda i: dict(t=0, i=i)))) \
+        .flow("TOP", "WRITE", IN(NEW("halo")),
+              OUT(TASK("S", "HR", lambda i, NT=NT: dict(t=0,
+                                                        i=(i - 1) % NT)))) \
+        .flow("BOT", "WRITE", IN(NEW("halo")),
+              OUT(TASK("S", "HL", lambda i, NT=NT: dict(t=0,
+                                                        i=(i + 1) % NT))))
+    if device in ("tpu", "xla", "gpu"):
+        tb.body(halos, device=device)
+    tb.body(lambda X: halos(np.asarray(X)))
+
+    def last(t, NB=NB):
+        return t == NB - 1
+
+    def inner(t, NB=NB):
+        return t < NB - 1
+
+    def halo_in(flow, side):
+        """The halo a task reads from its neighbour ``side`` (-1: the
+        tile above, whose BOTTOM rows; +1: below, its TOP rows)."""
+        def at(i, NT=NT):
+            return (i + side) % NT
+        return (IN(TASK("INIT", flow, lambda i: dict(i=at(i))),
+                   when=lambda t: t == 0),
+                IN(TASK("S", flow, lambda t, i: dict(t=t - 1, i=at(i))),
+                   when=lambda t: t > 0))
+
+    tb = p.task("S", t=Range(0, NB - 1), i=Range(0, NT - 1), ns=ns_of) \
         .affinity(lambda i, V=V: V(i)) \
-        .priority(lambda t, T=steps: T - t) \
-        .flow("L", "READ",
-              IN(TASK("INIT", "X", lambda i, NT=NT: dict(i=(i - 1) % NT)),
-                 when=lambda t: t == 0),
-              IN(TASK("S", "C", lambda t, i, NT=NT: dict(t=t - 1,
-                                                         i=(i - 1) % NT)),
-                 when=lambda t: t > 0)) \
-        .flow("R", "READ",
-              IN(TASK("INIT", "X", lambda i, NT=NT: dict(i=(i + 1) % NT)),
-                 when=lambda t: t == 0),
-              IN(TASK("S", "C", lambda t, i, NT=NT: dict(t=t - 1,
-                                                         i=(i + 1) % NT)),
-                 when=lambda t: t > 0)) \
+        .priority(lambda t, NB=NB: NB - t) \
+        .flow("HL", "READ", *halo_in("BOT", -1)) \
+        .flow("HR", "READ", *halo_in("TOP", +1)) \
         .flow("C", "RW",
               IN(TASK("INIT", "X", lambda i: dict(i=i)),
                  when=lambda t: t == 0),
               IN(TASK("S", "C", lambda t, i: dict(t=t - 1, i=i)),
                  when=lambda t: t > 0),
               OUT(TASK("S", "C", lambda t, i: dict(t=t + 1, i=i)),
-                  when=lambda t, T=steps: t < T - 1),
-              OUT(TASK("S", "L", lambda t, i, NT=NT: dict(t=t + 1,
-                                                          i=(i + 1) % NT)),
-                  when=lambda t, T=steps: t < T - 1),
-              OUT(TASK("S", "R", lambda t, i, NT=NT: dict(t=t + 1,
-                                                          i=(i - 1) % NT)),
-                  when=lambda t, T=steps: t < T - 1),
-              OUT(DATA(lambda i, V=V: V(i)),
-                  when=lambda t, T=steps: t == T - 1))
+                  when=inner),
+              OUT(DATA(lambda i, V=V: V(i)), when=last)) \
+        .flow("TOP", "WRITE", IN(NEW("halo")),
+              OUT(TASK("S", "HR", lambda t, i, NT=NT: dict(t=t + 1,
+                                                           i=(i - 1) % NT)),
+                  when=inner)) \
+        .flow("BOT", "WRITE", IN(NEW("halo")),
+              OUT(TASK("S", "HL", lambda t, i, NT=NT: dict(t=t + 1,
+                                                           i=(i + 1) % NT)),
+                  when=inner))
     if device in ("tpu", "xla", "gpu"):
-        tb.body(_k_step(), device=device)
-    tb.body(cpu_step)
-    return p.build()
-
-
-def _stencil_taskpool_fused(V: TiledMatrix, steps: int, device: str,
-                            fuse: int) -> ParameterizedTaskpool:
-    """The fused-sweep variant: blocks of ``fuse`` sweeps per task; the
-    last block carries the remainder as its ``ns`` local."""
-    NT = V.mt
-    if NT < 2:
-        raise ValueError("stencil needs at least 2 tiles")
-    NB = -(-steps // fuse)          # ceil
-
-    def ns_of(globals_, locals_):
-        return [min(fuse, steps - locals_["b"] * fuse)]
-
-    def cpu_fused(L, C, R, ns):
-        u = np.concatenate([np.asarray(L), np.asarray(C), np.asarray(R)])
-        for _ in range(int(ns)):
-            e = np.concatenate([u[-1:], u, u[:1]])
-            u = (e[:-2] + e[2:] + u) / 3.0
-        mb = np.asarray(C).shape[0]
-        return u[mb:2 * mb]
-
-    p = PTG("stencil", NT=NT, T=steps)
-    p.task("INIT", i=Range(0, NT - 1)) \
-        .affinity(lambda i, V=V: V(i)) \
-        .flow("X", "READ",
-              IN(DATA(lambda i, V=V: V(i))),
-              OUT(TASK("S", "C", lambda i: dict(b=0, i=i))),
-              OUT(TASK("S", "L", lambda i, NT=NT: dict(b=0,
-                                                       i=(i + 1) % NT))),
-              OUT(TASK("S", "R", lambda i, NT=NT: dict(b=0,
-                                                       i=(i - 1) % NT)))) \
-        .body(lambda: None)
-    tb = p.task("S", b=Range(0, NB - 1), i=Range(0, NT - 1), ns=ns_of) \
-        .affinity(lambda i, V=V: V(i)) \
-        .priority(lambda b, NB=NB: NB - b) \
-        .flow("L", "READ",
-              IN(TASK("INIT", "X", lambda i, NT=NT: dict(i=(i - 1) % NT)),
-                 when=lambda b: b == 0),
-              IN(TASK("S", "C", lambda b, i, NT=NT: dict(b=b - 1,
-                                                         i=(i - 1) % NT)),
-                 when=lambda b: b > 0)) \
-        .flow("R", "READ",
-              IN(TASK("INIT", "X", lambda i, NT=NT: dict(i=(i + 1) % NT)),
-                 when=lambda b: b == 0),
-              IN(TASK("S", "C", lambda b, i, NT=NT: dict(b=b - 1,
-                                                         i=(i + 1) % NT)),
-                 when=lambda b: b > 0)) \
-        .flow("C", "RW",
-              IN(TASK("INIT", "X", lambda i: dict(i=i)),
-                 when=lambda b: b == 0),
-              IN(TASK("S", "C", lambda b, i: dict(b=b - 1, i=i)),
-                 when=lambda b: b > 0),
-              OUT(TASK("S", "C", lambda b, i: dict(b=b + 1, i=i)),
-                  when=lambda b, NB=NB: b < NB - 1),
-              OUT(TASK("S", "L", lambda b, i, NT=NT: dict(b=b + 1,
-                                                          i=(i + 1) % NT)),
-                  when=lambda b, NB=NB: b < NB - 1),
-              OUT(TASK("S", "R", lambda b, i, NT=NT: dict(b=b + 1,
-                                                          i=(i - 1) % NT)),
-                  when=lambda b, NB=NB: b < NB - 1),
-              OUT(DATA(lambda i, V=V: V(i)),
-                  when=lambda b, NB=NB: b == NB - 1))
-    if device in ("tpu", "xla", "gpu"):
-        tb.body(_k_fused(), device=device)
-    tb.body(cpu_fused)
+        tb.body(_k_sweep(), device=device)
+    tb.body(cpu_sweep)
     return p.build()
 
 
 def stencil_reference(x: np.ndarray, steps: int) -> np.ndarray:
-    """Serial reference of the same periodic stencil."""
+    """Serial reference of the same periodic stencil (along axis 0)."""
     u = x.astype(np.float64)
     for _ in range(steps):
         ext = np.concatenate([u[-1:], u, u[:1]])

@@ -31,6 +31,7 @@ class DeviceStats:
                  "held_tasks", "defused_waves", "starved_waits",
                  "inflight_waits", "compiles", "warm_waits",
                  "release_passes", "resident_flows", "staged_flows",
+                 "snapshot_flows", "snapshot_bytes",
                  "replicas_adopted", "replicas_released",
                  "replica_bytes_peak")
 
@@ -83,6 +84,14 @@ class DeviceStats:
         #: device launched or held
         self.resident_flows = 0
         self.staged_flows = 0
+        #: of the staged flows, those for which the device module made a
+        #: private copy of a payload that was there already — a
+        #: version-pinned snapshot, or the alias a copy-on-write fan-out
+        #: hands a writer beside its readers — and the bytes of those
+        #: copies: on the chip each is a program of its own that reads
+        #: and writes the whole payload once more (also in bytes_in)
+        self.snapshot_flows = 0
+        self.snapshot_bytes = 0
         #: SHARED copies this chip held for counted consumers of another
         #: chip's tile (comm/ici.py expect; pushed over ICI or pulled by
         #: a stage-in), how many of them left again at their last

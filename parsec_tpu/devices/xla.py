@@ -156,6 +156,10 @@ class XlaKernel:
         #: the kernel writes)
         self.arg_plan = tuple((a, a in self.flow_names)
                               for a in self.arg_names)
+        #: the flows whose payloads the kernel is handed; a flow it only
+        #: returns (a halo, a norm) is staged without a buffer of its own
+        self.flow_args = frozenset(a for a, is_flow in self.arg_plan
+                                   if is_flow)
         self.donate_pos = tuple(
             i for i, a in enumerate(self.arg_names)
             if a in self.flow_names and a in self.writable)
@@ -910,6 +914,14 @@ class XlaDevice(Device):
         #: drops the accounting when the copy dies with its datum.
         self._lru: "OrderedDict[int, Tuple[Any, int, Any]]" = OrderedDict()
         self._pins: Dict[int, int] = {}
+        #: (shape, dtype) -> the one zeros buffer that stands in, until
+        #: the kernel's output lands, for every NEW flow no kernel is
+        #: handed (_stage_in): never read, never donated, so shared.
+        #: The bytes a flow reserves are its OUTPUT's, which takes the
+        #: blank's place in the same copy when the launch returns; the
+        #: blank itself (one a shape) goes with the scratch it served
+        #: (discard_scratch, fini)
+        self._blanks: Dict[Tuple, Any] = {}
         # a Condition so adopt() can WAIT for a concurrent claim on the
         # same datum to resolve instead of polling (notified whenever a
         # placeholder resolves); plain `with self._mem_lock:` still works
@@ -1210,7 +1222,8 @@ class XlaDevice(Device):
                     if copy is None:
                         continue
                     dc = self._stage_in(copy, flow.access,
-                                        name in pinned_flows)
+                                        name in pinned_flows,
+                                        name in spec.flow_args)
                     if dc is not copy:
                         if copy.device == 0 and copy.arena is not None:
                             # host arena temp fully superseded by the
@@ -1668,11 +1681,12 @@ class XlaDevice(Device):
         return any(id(flat[b + i]) in donated for b in bases for i in keep)
 
     def _stage_in(self, copy: DataCopy, access: int,
-                  pinned: bool = False) -> DataCopy:
+                  pinned: bool = False, handed: bool = True) -> DataCopy:
         """Ensure a valid copy of ``copy``'s datum on this device
         (reference: parsec_gpu_data_stage_in, device_cuda_module.c:1261).
         The caller has pinned the datum and freshened it in the LRU
-        (``_pin_wave``).
+        (``_pin_wave``); ``handed`` says whether the kernel is handed the
+        flow's payload or only returns it.
 
         The resident case comes first: the device's copy is there, valid
         at the newest version, and the bound copy is neither a snapshot
@@ -1736,9 +1750,18 @@ class XlaDevice(Device):
                 dc = datum.create_copy(self.space)
             shape = copy.payload.shape
             dtype = copy.payload.dtype
-            dc.payload = jax.device_put(   # lint: private-ok (a fresh
-                # jnp.zeros has no host-side owner to alias)
-                jnp.zeros(shape, dtype=dtype), self.jdev)
+            blank = None if handed else self._blanks.get((shape, dtype))
+            if blank is None:
+                blank = jax.device_put(   # lint: private-ok (a fresh
+                    # jnp.zeros has no host-side owner to alias)
+                    jnp.zeros(shape, dtype=dtype), self.jdev)
+                if not handed:
+                    # a flow the kernel only returns: nothing reads or
+                    # donates what stands here until the output lands,
+                    # so every such flow of this shape shares one
+                    # buffer and costs no dispatch of its own
+                    self._blanks[(shape, dtype)] = blank
+            dc.payload = blank
             dc.version = copy.version
             datum.transfer_ownership(self.space, access)
             self._account(datum, dc, nbytes, off)
@@ -1762,6 +1785,8 @@ class XlaDevice(Device):
                                   coherency=Coherency.SHARED,
                                   version=copy.version)
             self.stats.bytes_in += nbytes
+            self.stats.snapshot_flows += 1
+            self.stats.snapshot_bytes += nbytes
             self._account(snap, dc, nbytes, off)
             return dc
         if not acquired:
@@ -1797,6 +1822,9 @@ class XlaDevice(Device):
                     dc.payload.block_until_ready()
             dc.version = src.version if src is not None else copy.version
             self.stats.bytes_in += nbytes
+            if flags & FLAG_COW:
+                self.stats.snapshot_flows += 1
+                self.stats.snapshot_bytes += nbytes
             if fresh:
                 self._account(datum, dc, nbytes, off)
             if not access & ACCESS_WRITE:
@@ -2321,6 +2349,7 @@ class XlaDevice(Device):
         flush does not D2H gigabytes of dead QR panels / potrf
         inverses through a slow link."""
         with self._mem_lock:
+            self._blanks.clear()
             for key in list(self._lru.keys()):
                 dcref, sz, voff = self._lru[key]
                 dc = dcref()
@@ -2382,4 +2411,5 @@ class XlaDevice(Device):
                     self.name, exc)
         self._completer.join(timeout=5)
         self.flush()
+        self._blanks.clear()
         debug_verbose(5, "device %s: %s", self.name, self.stats.as_dict())

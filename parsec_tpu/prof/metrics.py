@@ -792,7 +792,11 @@ class RuntimeMetrics:
                     ("resident_flows",
                      "parsec_device_resident_flows_total"),
                     ("staged_flows",
-                     "parsec_device_staged_flows_total")):
+                     "parsec_device_staged_flows_total"),
+                    ("snapshot_flows",
+                     "parsec_device_snapshot_flows_total"),
+                    ("snapshot_bytes",
+                     "parsec_device_snapshot_bytes_total")):
                 v = getattr(st, key, None)
                 if isinstance(v, (int, float)) and v:
                     out.append(counter_sample(metric, v, labels))

@@ -211,6 +211,30 @@ def test_pallas_blocked_gram_compiles_to_mosaic(spec):
         _compile(fn, spec((MB, 512), jnp.bfloat16))
 
 
+def test_stencil_sweep_wave_is_in_place(spec):
+    """The stencil cell's program (PR 37): an eight-wide wave of the
+    in-place Pallas sweep over 4096 x 8192 float32 tiles with every
+    ``C`` donated.  The compiler aliases the eight tiles and plans no
+    temporary: a sweep moves each point once in and once out.  (The XLA
+    form of the same wave plans 512 MiB of temporaries and a copy a
+    tile; PERF.md, PR 37.)"""
+    import jax.numpy as jnp
+    from parsec_tpu.apps.pallas_kernels import PALLAS, pallas_sweep_tile
+    mb, nb, w = 4096, 8192, 8
+    fn = pallas_sweep_tile(xla=None)
+
+    def wave(*flat):
+        return tuple(fn(*flat[3 * t:3 * t + 3]) for t in range(w))
+    tile, halo = spec((mb, nb), jnp.float32), spec((1, nb), jnp.float32)
+    c = _compile(wave, *([halo, tile, halo] * w),
+                 donate_argnums=tuple(3 * t + 1 for t in range(w)))
+    assert fn.selected == {(mb, nb): PALLAS}
+    assert c.as_text().count("tpu_custom_call") >= w
+    ma = c.memory_analysis()
+    assert ma.alias_size_in_bytes == w * mb * nb * 4
+    assert ma.temp_size_in_bytes < 2 ** 20
+
+
 @pytest.fixture(scope="module")
 def mesh(topo):
     from jax.sharding import Mesh
