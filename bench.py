@@ -736,23 +736,25 @@ def run_fabric_bench(n_jobs: int = 0):
     return n_jobs / dt, extras
 
 
-def _call_counts(codes, fn, on_threads=""):
+def _call_counts(codes, fn, on_threads=()):
     """Run ``fn()`` and count the calls of each code object in ``codes``
     (name -> code) on every thread: ``sys.monitoring`` local events, so
-    only those functions pay for the count.  With ``on_threads`` the
-    answer is a pair: the counts on every thread, and those on the
-    threads whose name starts so."""
+    only those functions pay for the count.  With ``on_threads`` (thread
+    name prefixes) the answer is a pair: the counts on every thread, and
+    prefix -> the counts on the threads whose name starts so."""
     mon = sys.monitoring
     tool = mon.PROFILER_ID
     counts = dict.fromkeys(codes, 0)
-    on = dict.fromkeys(codes, 0)
+    on = {p: dict.fromkeys(codes, 0) for p in on_threads}
     names = {code: name for name, code in codes.items()}
 
     def started(code, offset):
         counts[names[code]] += 1    # under the interpreter lock
-        if on_threads and threading.current_thread().name.startswith(
-                on_threads):
-            on[names[code]] += 1
+        if on:
+            thread = threading.current_thread().name
+            for p, got in on.items():
+                if thread.startswith(p):
+                    got[names[code]] += 1
 
     mon.use_tool_id(tool, "bench-counts")
     try:
@@ -847,9 +849,13 @@ def run_launch_bench(nt: int = 32, mb: int = 16):
     PR 36, 1 since), holds of the device's ``_mem_lock`` a flow
     (``_pin_wave``, ``_touch``, ``_account``: 2 before, one a WAVE
     since), and signatures built there a task (1 before, 0 since); on
-    every thread, signatures a task (1, at ``submit``).  Not a speed: a
-    PR that puts a lock hold a flow or a signature a candidate back is
-    seen here without a chip."""
+    every thread, signatures a task (1, at ``submit``).  Where a ready
+    task reaches the device: ``DeviceStats.direct_submits`` a
+    task (handed in by the thread that released it: all but the pool's
+    start-up tasks) and ``XlaDevice.submit`` calls on the workers a
+    task (1 before, about 0 since).  Not a speed: a PR that puts a lock
+    hold a flow, a signature a candidate or a worker a task back is seen
+    here without a chip."""
     import jax
     from parsec_tpu.apps import potrf
     from parsec_tpu.core.context import Context
@@ -867,7 +873,8 @@ def run_launch_bench(nt: int = 32, mb: int = 16):
     mem_lock = ("_pin_wave", "_touch", "_account")
     sigs = ("task_sig", "args_sig")
     codes = {meth: getattr(owner, meth).__code__
-             for owner, meths in ((Data, datum_lock), (XlaDevice, mem_lock),
+             for owner, meths in ((Data, datum_lock),
+                                  (XlaDevice, mem_lock + ("submit",)),
                                   (XlaKernel, sigs)) for meth in meths}
     mca = {"device_max": 1, "device_fuse": 8, "device_runahead": 48,
            "device_inflight_depth": 32}
@@ -894,8 +901,9 @@ def run_launch_bench(nt: int = 32, mb: int = 16):
                 def job():
                     ctx.add_taskpool(tp)
                     ctx.wait(timeout=600)
-                everywhere, c = _call_counts(codes, job,
-                                             on_threads="xla-mgr")
+                everywhere, on = _call_counts(
+                    codes, job, on_threads=("xla-mgr", "parsec-worker"))
+                c = on["xla-mgr"]
                 st = {k: v - st0[k] for k, v in dev.stats.as_dict().items()}
                 L = np.tril(A.to_array())
                 err = np.abs(L @ L.T - spd).max() / np.abs(spd).max()
@@ -917,7 +925,12 @@ def run_launch_bench(nt: int = 32, mb: int = 16):
                     "manager_sig_calls_per_task": round(sum(
                         c[k] for k in sigs) / tasks, 3),
                     "sig_calls_per_task": round(sum(
-                        everywhere[k] for k in sigs) / tasks, 3)}
+                        everywhere[k] for k in sigs) / tasks, 3),
+                    "direct_submits": st["direct_submits"],
+                    "direct_submits_per_task": round(
+                        st["direct_submits"] / tasks, 3),
+                    "worker_submits_per_task": round(
+                        on["parsec-worker"]["submit"] / tasks, 3)}
     finally:
         for k in mca:
             params.unset(k)

@@ -128,6 +128,12 @@ class ExecutionStream:
         #: thread mutates it, off-thread completers take the locked path
         self._td_acc = None
         self._td_tid = 0
+        #: the one thread that owns this stream to hand the ready device
+        #: tasks it releases straight to the chip (a device's completer,
+        #: a DTD inserter: core/scheduling.schedule), or 0; and the items
+        #: such a hand-in collects until it queues them in one hold
+        self.releaser = 0
+        self.hand_in = None
         self._pins_cbs = {}
         #: the context's event->callbacks dict, aliased so the per-task
         #: dispatch reads one attribute (pins_register mutates the dict
@@ -231,6 +237,18 @@ class Context:
             ici = IciEngine(self.device_registry)
             if ici.ndev >= 2:
                 self.ici = ici
+        #: the accelerator of a one-rank context that drives exactly one:
+        #: a ready task whose first incarnation is this device goes from
+        #: the thread that released it straight to its queue
+        #: (core/scheduling.schedule).  None on several chips, where
+        #: placement is owner-computes and idle workers drive the ICI
+        #: engine's deferred placements
+        accs = self.device_registry.accelerators
+        self.direct_device = accs[0] if (
+            len(accs) == 1 and self.ici is None and nranks == 1) else None
+        #: thread id -> the stream of a thread that is no worker and
+        #: makes tasks ready (releasing_stream)
+        self._releasing = {}
 
         # full cyclic-GC collections scanning the static import graph
         # were 30% of the tasks probe; freeze it out once per process —
@@ -385,6 +403,21 @@ class Context:
         per-tenant device subsets from.  Space 0 (host) never appears:
         carving governs accelerator placement only."""
         return [d.space for d in self.device_registry.accelerators]
+
+    def releasing_stream(self) -> ExecutionStream:
+        """The calling thread's own execution stream, made at its first
+        call.  A thread that is no worker and makes tasks ready (a DTD
+        pool's inserter) schedules them on it, so that a direct hand-in
+        (core/scheduling.schedule) never runs on a stream another thread
+        owns."""
+        tid = threading.get_ident()
+        es = self._releasing.get(tid)
+        if es is None:
+            with self._lock:
+                es = self._releasing[tid] = ExecutionStream(
+                    self, th_id=800 + len(self._releasing))
+            es.releaser = tid
+        return es
 
     def flush_ici(self) -> None:
         """Drain deferred wavefront placements (comm/ici.py defer_place)

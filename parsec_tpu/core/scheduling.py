@@ -5,7 +5,10 @@ Rebuild of the reference's scheduling core (reference: parsec/scheduling.c):
 complete), ``execute`` iterates incarnations like __parsec_execute:124, and
 ``worker_loop`` is the hot loop of __parsec_context_wait:537-676 with
 exponential backoff on scheduler misses.  ``schedule`` is __parsec_schedule,
-entering tasks through the pluggable scheduler and ringing the doorbell.
+entering tasks through the pluggable scheduler and ringing the doorbell —
+except, on a context that drives one accelerator, the device tasks a
+completer or an inserter makes ready: those it progresses itself
+(``_hand_in``).
 """
 
 from __future__ import annotations
@@ -37,10 +40,20 @@ _DISABLE = HookReturn.DISABLE
 
 
 def schedule(es, tasks: List[Task], distance: int = 0) -> None:
-    """Enter ready tasks into the scheduler (reference: __parsec_schedule)."""
+    """Enter ready tasks into the scheduler (reference: __parsec_schedule).
+
+    Called by the thread that owns ``es`` as its releaser (a device's
+    completer, a DTD inserter: ``es.releaser``), the ready tasks bound
+    for a one-chip context's accelerator are handed to it here instead
+    (``_hand_in``); retries and reschedules (``distance`` > 0) and
+    everything else take the scheduler and a worker."""
     if not tasks:
         return
     ctx = es.context
+    if es.releaser and not distance:
+        tasks = _hand_in(es, tasks)
+        if not tasks:
+            return
     sched = ctx.scheduler
     if sched.NATIVE_BATCH:
         # native ready queue (sched/native.py): READY transition,
@@ -49,12 +62,18 @@ def schedule(es, tasks: List[Task], distance: int = 0) -> None:
         sched.schedule(es, tasks, distance)
         ctx.ring_doorbell(len(tasks))
         return
+    _mark_ready(ctx, tasks)
+    sched.schedule(es, tasks, distance)
+    ctx.ring_doorbell(len(tasks))
+
+
+def _mark_ready(ctx, tasks: List[Task]) -> None:
+    """READY, and one ``ready_at`` stamp for the batch: the tasks became
+    ready at this same moment; the causal tracer closes select -
+    ready_at into a queue-wait span and the metrics registry samples it
+    into the queue-wait histogram.  Gated (Context._ready_stamp) so a
+    telemetry-disabled hot path stays free."""
     if ctx._ready_stamp:
-        # one stamp for the batch: the tasks became ready at this same
-        # moment; the causal tracer closes select - ready_at into a
-        # queue-wait span and the metrics registry samples it into the
-        # queue-wait histogram.  Gated (Context._ready_stamp) so a
-        # telemetry-disabled hot path stays free
         now = time.perf_counter()
         for t in tasks:
             t.status = _READY
@@ -62,8 +81,73 @@ def schedule(es, tasks: List[Task], distance: int = 0) -> None:
     else:
         for t in tasks:
             t.status = _READY
-    sched.schedule(es, tasks, distance)
-    ctx.ring_doorbell(len(tasks))
+
+
+def _hand_in(es, tasks: List[Task]) -> List[Task]:
+    """Progress on the calling thread each ready task whose first
+    incarnation places it on the context's one accelerator, and queue
+    what they submit under one hold of the device's lock
+    (``XlaDevice.enqueue``, in the ready queue's priority order); return
+    the rest, for the scheduler.  What is saved is the scheduler's push,
+    the doorbell and a worker's wake-up and pop; what runs is the
+    ``task_progress`` a worker runs, so the recovery fence, the
+    cancelled pool, the PINS, ``prepare_input``, the device hook and
+    placement all hold.
+
+    The tasks are stamped as the ready queue stamps them.  Where the
+    caller opened the stream's hand-in itself (a completer's pass), what
+    is submitted joins it and is queued when the caller closes it.  Only
+    on the stream's own thread, and never inside a task's progress on
+    it: a task whose device hook declined and whose body ran here
+    schedules what it releases the ordinary way."""
+    ctx = es.context
+    dev = ctx.direct_device
+    if dev is None or not dev.enabled or ctx.comm is not None \
+            or es.running_task is not None or es.releaser != get_ident():
+        return tasks
+    mine: List[Task] = []
+    rest: List[Task] = []
+    for t in tasks:
+        (mine if _bound_for(t, dev) else rest).append(t)
+    if not mine:
+        return rest
+    _mark_ready(ctx, mine)
+    own = es.hand_in is None
+    if own:
+        es.hand_in = []
+    try:
+        select = es._pins_map.get("select")
+        for t in mine:
+            if select:
+                for cb in select:
+                    cb(es, "select", t)
+            task_progress(es, t)
+    finally:
+        if own:
+            batch, es.hand_in = es.hand_in, None
+            if batch:
+                dev.enqueue(es, batch)
+    return rest
+
+
+#: incarnation types whose hook hands the task to an accelerator
+#: (dsl/ptg/api.py TaskBuilder.body, dsl/dtd/insert.py)
+_DEVICE_TYPES = frozenset(("tpu", "xla", "gpu"))
+
+
+def _bound_for(task: Task, dev) -> bool:
+    """Whether the first incarnation ``task`` may take is a device one
+    that can place it on ``dev``: enabled for the task and its class,
+    and ``dev`` inside its pool's carve where the pool has one."""
+    tc = task.task_class
+    mask = task.chore_mask & ~tc.chore_disabled_mask
+    for idx, (dev_type, _hook) in enumerate(tc.incarnations):
+        if mask & (1 << idx):
+            if dev_type not in _DEVICE_TYPES:
+                return False
+            spaces = getattr(task.taskpool, "device_spaces", None)
+            return spaces is None or dev.space in spaces
+    return False
 
 
 def execute(es, task: Task) -> HookReturn:

@@ -33,7 +33,7 @@ class DeviceStats:
                  "release_passes", "resident_flows", "staged_flows",
                  "snapshot_flows", "snapshot_bytes",
                  "replicas_adopted", "replicas_released",
-                 "replica_bytes_peak")
+                 "replica_bytes_peak", "direct_submits")
 
     def __init__(self):
         self.executed_tasks = 0
@@ -100,6 +100,11 @@ class DeviceStats:
         self.replicas_adopted = 0
         self.replicas_released = 0
         self.replica_bytes_peak = 0
+        #: tasks handed in by the thread that made them ready (a
+        #: completer's release, a DTD inserter) without a worker: the
+        #: direct hand-in of core/scheduling.schedule on a one-chip
+        #: context; every other task came through a worker
+        self.direct_submits = 0
 
     def as_dict(self) -> Dict[str, int]:
         return {k: getattr(self, k) for k in self.__slots__}

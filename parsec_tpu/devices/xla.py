@@ -840,6 +840,11 @@ def device_put_replicated_private(payload, sharding):   # lint: alias-wrapper
 _PLACEHOLDER = object()
 
 
+def _item_priority(item) -> int:
+    """A queued ``(task, spec, load, sig)`` item's task priority."""
+    return item[0].priority
+
+
 class _Inflight:
     __slots__ = ("es", "task", "spec", "outputs", "pinned", "load",
                  "release_after", "prepublished", "seq")
@@ -974,13 +979,42 @@ class XlaDevice(Device):
         # (see _pop_wave_locked)
         sig = None if task.task_class.properties.get("fuse_chain") \
             else spec.task_sig(task)
+        item = (task, spec, load, sig)
+        batch = es.hand_in
+        if batch is not None:
+            # a releasing thread's direct hand-in (core/scheduling.
+            # schedule): queued with the rest of its call or pass by
+            # enqueue
+            batch.append(item)
+            return HookReturn.ASYNC
         with self._cond:
             if self.es is None:
-                from parsec_tpu.core.context import ExecutionStream
-                self.es = ExecutionStream(es.context, th_id=900 + self.space)
-            self._pending.append((task, spec, load, sig))
+                self._make_stream_locked(es.context)
+            self._pending.append(item)
             self._cond.notify_all()
         return HookReturn.ASYNC
+
+    def enqueue(self, es, items: List[Tuple]) -> None:
+        """Queue what a releasing thread handed in (``submit`` items: one
+        ``schedule()`` call's, or a completer pass's) in the order the
+        ready queue would have popped them — priority, first come first
+        among equals — under ONE hold of ``_cond`` and one wake-up."""
+        items.sort(key=_item_priority, reverse=True)   # stable
+        with self._cond:
+            if self.es is None:
+                self._make_stream_locked(es.context)
+            self._pending.extend(items)
+            self.stats.direct_submits += len(items)
+            self._cond.notify_all()
+
+    def _make_stream_locked(self, ctx) -> None:
+        """The device's execution stream: the managers' spans and the
+        completer's releases run on it.  The completer owns it for the
+        direct hand-in of the tasks its releases make ready.  Caller
+        holds ``_cond``."""
+        from parsec_tpu.core.context import ExecutionStream
+        self.es = ExecutionStream(ctx, th_id=900 + self.space)
+        self.es.releaser = self._completer.ident
 
     # ------------------------------------------------------------------
     # manager: stage-in + dispatch (reference: parsec_cuda_kernel_push /
@@ -1897,9 +1931,22 @@ class XlaDevice(Device):
                     self._cond.notify_all()   # room was made
             if batch:
                 self.stats.release_passes += 1
-                with open_span(self.es, "fin.pass", n=take):
-                    for inf in batch:
-                        self._release(inf, scheduling)
+                es = self.es
+                with open_span(es, "fin.pass", n=take):
+                    # one chip: the device tasks the pass's releases make
+                    # ready are handed in here (core/scheduling.schedule)
+                    # and queued together at its end, in the priority
+                    # order the ready queue would have popped them
+                    handed = [] if es.context.direct_device is self \
+                        else None
+                    es.hand_in = handed
+                    try:
+                        for inf in batch:
+                            self._release(inf, scheduling)
+                    finally:
+                        es.hand_in = None
+                        if handed:
+                            self.enqueue(es, handed)
                     with self._cond:
                         self._retire.extend(batch)
                         self._completing -= take

@@ -1246,7 +1246,10 @@ class DTDTaskpool(Taskpool):
                 to_schedule.append(task)
         if to_schedule:
             self._bind(to_schedule)
-            scheduling.schedule(self.context.streams[0], to_schedule)
+            # the inserting thread's own stream, not worker 0's: a
+            # direct hand-in runs the tasks' progress on it
+            scheduling.schedule(self.context.releasing_stream(),
+                                to_schedule)
         return task
 
     @staticmethod

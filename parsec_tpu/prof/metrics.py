@@ -796,7 +796,9 @@ class RuntimeMetrics:
                     ("snapshot_flows",
                      "parsec_device_snapshot_flows_total"),
                     ("snapshot_bytes",
-                     "parsec_device_snapshot_bytes_total")):
+                     "parsec_device_snapshot_bytes_total"),
+                    ("direct_submits",
+                     "parsec_device_direct_submits_total")):
                 v = getattr(st, key, None)
                 if isinstance(v, (int, float)) and v:
                     out.append(counter_sample(metric, v, labels))

@@ -74,7 +74,9 @@ def test_launch_canary_counts_a_flow_of_the_cell_3_dag(monkeypatch, capsys):
     memory lock once a WAVE (twice a flow), builds no signature itself
     (one a task, at ``submit``; a chain link has none), and every
     operand of the job is resident but its nt - 1 NEW-arena ``W``
-    panels, PTG and DTD alike."""
+    panels, PTG and DTD alike.  A ready task reaches the device
+    handed in by the thread that released it, all but the PTG
+    pool's one start-up task, and no worker submits (1 a task before)."""
     monkeypatch.setenv("PARSEC_BENCH_APP", "launch")
     bench.main()
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
@@ -94,6 +96,9 @@ def test_launch_canary_counts_a_flow_of_the_cell_3_dag(monkeypatch, capsys):
         assert got["mem_lock_holds_per_flow"] < 0.2
         assert got["manager_sig_calls_per_task"] == 0
         assert got["sig_calls_per_task"] == round(5952 / 5984, 3)
+        roots = 1 if front == "ptg" else 0   # POTRF(0) from the start-up
+        assert got["direct_submits"] == 5984 - roots
+        assert got["worker_submits_per_task"] == round(roots / 5984, 3)
 
 
 def test_premerge_names_only_modes_that_exist():

@@ -610,7 +610,11 @@ def _tiles(mt, mb=8):
 def _hand_over_behind_src(ctx, dev, rel, A, successors=False):
     """Start the fan with the completer held at the end of SRC's release:
     on return its first pass (SRC alone) is still open and every MUL
-    sits in ``_inflight``, handed over by the managers."""
+    sits in ``_inflight``, handed over by the managers.  The MULs go the
+    workers' way: handed in by the completer itself (the one-chip direct
+    path, tests/test_direct_submit.py), they would reach the managers
+    only when the held pass ends."""
+    ctx.direct_device = None
     ctx.add_taskpool(_fan_pool(A, PASS_MT, successors))
     ctx.start()
     _until(rel.held[0].is_set, "the completer at the end of SRC's release")

@@ -677,7 +677,7 @@ def test_a_chain_link_is_queued_without_a_signature(pair):
     new, _old = pair
     spec = _sig_spec()
     ctx = types.SimpleNamespace()
-    es = types.SimpleNamespace(context=ctx)
+    es = types.SimpleNamespace(context=ctx, hand_in=None)
     link = _sig_task(properties={"fuse_chain": ("C", "UPD"), "flops": 2.0})
     plain = _sig_task(properties={"flops": lambda loc: 3.0})
     held = []
