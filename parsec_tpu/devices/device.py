@@ -33,7 +33,7 @@ class DeviceStats:
                  "release_passes", "resident_flows", "staged_flows",
                  "snapshot_flows", "snapshot_bytes",
                  "replicas_adopted", "replicas_released",
-                 "replica_bytes_peak", "direct_submits")
+                 "replica_bytes_peak", "direct_submits", "heads_forced")
 
     def __init__(self):
         self.executed_tasks = 0
@@ -105,6 +105,11 @@ class DeviceStats:
         #: direct hand-in of core/scheduling.schedule on a one-chip
         #: context; every other task came through a worker
         self.direct_submits = 0
+        #: held chain heads that went out ALONE (``_force_hold``): no
+        #: launch of a declared successor carried them — a reader of a
+        #: class they do not declare ran out of patience, a foreign
+        #: consumer forced them, or a sync found them still held
+        self.heads_forced = 0
 
     def as_dict(self) -> Dict[str, int]:
         return {k: getattr(self, k) for k in self.__slots__}

@@ -92,7 +92,8 @@ params.register("device_fuse", 8,
 params.register("device_fuse_panel", 1,
                 "cross-panel chain fusion: a task class carrying a "
                 "'fuse_chain' property (flow, successor class: "
-                "POTRF->TRSM, GEQRT/TSQRT->TSQRT) is HELD at dispatch — "
+                "POTRF->TRSM, GEQRT/TSQRT->TSQRT, GETRF->TRSMR) is HELD "
+                "at dispatch — "
                 "its outputs become deferred placeholders, its deps "
                 "release eagerly as usual — and its kernel is traced "
                 "INTO the launch of its declared successor, ONE head a "
@@ -1663,7 +1664,7 @@ class XlaDevice(Device):
     def _force_hold(self, hd: _Hold) -> None:
         """Dispatch a held head alone, without its successor (a consumer
         of another class whose patience ran out, foreign-device/CPU
-        consumers, sync, teardown)."""
+        consumers, sync, teardown); counted in ``heads_forced``."""
         if not self._claim(hd):
             return                    # resolved concurrently
         try:
@@ -1671,6 +1672,7 @@ class XlaDevice(Device):
         except Exception:
             self._unclaim(hd)
             raise
+        self.stats.heads_forced += 1
         self._resolve_hold(hd, outs)
 
     def _resolve_all_held(self) -> None:

@@ -798,7 +798,9 @@ class RuntimeMetrics:
                     ("snapshot_bytes",
                      "parsec_device_snapshot_bytes_total"),
                     ("direct_submits",
-                     "parsec_device_direct_submits_total")):
+                     "parsec_device_direct_submits_total"),
+                    ("heads_forced",
+                     "parsec_device_heads_forced_total")):
                 v = getattr(st, key, None)
                 if isinstance(v, (int, float)) and v:
                     out.append(counter_sample(metric, v, labels))

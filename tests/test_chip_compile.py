@@ -18,6 +18,7 @@ sandbox's CPU, compiles only:
     POTRF diagonal (cholesky + tri_inv), 6144 bf16      31 s   (here 2048)
     GEQRT ib=512, 6144 bf16                             9.6 s   (here 2048)
     TSQRT ib=512, 6144 bf16                            14.4 s   (here 2048)
+    GETRF diagonal (recursive LU + L^-1, U^-T), 6144    16 s   (here 2048)
     chained POTRF + 8-wide TRSM wave, 6144 bf16          29 s   (not here)
 
 PR 28's compiles of the last read 29 / 39 / 60 s and the parent's
@@ -143,6 +144,39 @@ def test_potrf_diagonal_kernel_2048(spec):
     mb = 2048
     _compile(potrf._k_potrf(None), spec((mb, mb), jnp.bfloat16),
              spec((mb, mb), jnp.float32))
+
+
+@pytest.mark.parametrize("kernel", ["trsmr", "gemm"])
+def test_lu_update_kernels_6144_bf16(spec, kernel):
+    """The LU cell's row-panel solve (by GETRF's f32 inverse, blocks
+    above the triangle left out) and trailing update; the column panel
+    is potrf's TRSM, compiled above."""
+    import jax.numpy as jnp
+    from parsec_tpu.apps import getrf
+    t = (MB, MB)
+    bf = spec(t, jnp.bfloat16)
+    fn, args, written = {
+        "trsmr": (getrf._k_trsmr(None), (spec(t, jnp.float32), bf), 1),
+        "gemm": (getrf._k_gemm(None), (bf, bf, bf), 0),
+    }[kernel]
+    c = _compile(fn, *args, donate_argnums=(written,))
+    flops = c.cost_analysis()["flops"] / (2.0 * MB ** 3)
+    assert flops == pytest.approx(1.0, rel=0.01) if kernel == "gemm" \
+        else flops <= 0.70
+    ma = c.memory_analysis()
+    assert ma.alias_size_in_bytes == MB * MB * 2
+    assert ma.temp_size_in_bytes < MB * MB * 2
+
+
+def test_lu_diagonal_kernel_2048(spec):
+    """The recursive LU with both inverses; full width compiled by hand
+    (module docstring)."""
+    import jax.numpy as jnp
+    from parsec_tpu.apps import getrf
+    mb = 2048
+    f32 = spec((mb, mb), jnp.float32)
+    _compile(getrf._k_getrf(None), spec((mb, mb), jnp.bfloat16), f32, f32,
+             donate_argnums=(0, 1, 2))
 
 
 def test_qr_tsmqr_6144_bf16(spec):
