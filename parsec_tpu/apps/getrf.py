@@ -55,7 +55,7 @@ from parsec_tpu.dsl.ptg.api import DATA, IN, NEW, OUT, PTG, Range, TASK
 _kernels = {}
 
 #: a recursion node of this order or less is a leaf, factored directly
-#: (potrf.tri_inv's leaf order: its triangles are inverted by Newton)
+#: (its triangles are inverted by ``potrf.tri_inv``)
 _LU_LEAF = 512
 #: the recursion splits an order at a multiple of this where it can, so
 #: that the halves' products keep MXU-aligned shapes

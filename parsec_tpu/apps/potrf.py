@@ -60,9 +60,11 @@ from parsec_tpu.dsl.ptg.api import DATA, IN, NEW, OUT, PTG, Range, TASK
 
 _kernels = {}
 
-#: recursive-inversion leaf: below this order the Newton iteration runs
-#: directly (log2(leaf) matmuls of leaf x leaf — MXU noise)
-_INV_LEAF = 512
+#: the widest leaf ``tri_inv`` inverts by Newton: the MXU's edge.  A
+#: Newton step at order n executes 4 n^3 flop for an inverse worth
+#: n^3 / 3, ceil(log2 n) + 1 times, so the leaves are kept as narrow
+#: as the array is wide and the block recursion does the rest
+_INV_LEAF = 128
 
 #: the number of blocks b an edge of the tile was cut into for every
 #: traced (class, tile order), 1 = the full product: what says the
@@ -97,36 +99,63 @@ def potrf_executed_flops(cls: str, mb: int) -> float:
             "GEMM": 2.0 * mb ** 3}[cls]
 
 
-def tri_inv(L, precision=None):
-    """Lower-triangular inverse as pure matmuls (jax-traceable).
+def inv_leaf(n: int) -> int:
+    """Order of the diagonal blocks ``tri_inv`` inverts by Newton at
+    order n: n halved while it is even and wider than ``_INV_LEAF``
+    (128 at n = 512 and 2048, 96 at 384 and 6144 = 3 x 2^11)."""
+    while n % 2 == 0 and n > _INV_LEAF:
+        n //= 2
+    return n
 
-    Recursive 2x2 block inversion
+
+def tri_inv(L, precision=None):
+    """Lower-triangular inverse as pure matmuls (jax-traceable), with
+    exact zeros above the diagonal (TRSM leaves out its products with
+    them).
+
+    The 2x2 block inversion
         [[L11, 0], [L21, L22]]^-1 =
         [[X11, 0], [-X22 @ L21 @ X11, X22]]
-    with Newton--Schulz leaves: X <- X(2I - LX) starting from
-    X0 = diag(L)^-1 converges EXACTLY in ceil(log2(n)) steps because the
-    initial residual I - LX0 is strictly triangular (nilpotent) and each
-    step squares it.  No triangular solve anywhere: everything lowers to
-    the systolic array.
+    is applied down to diagonal leaves of order ``inv_leaf(n)`` and
+    evaluated bottom up in batches: every leaf at once, then the pairs
+    of each level at once (two batched products a level).  A leaf is
+    inverted by Newton--Schulz, X <- X(2I - DX) from X0 = diag(D)^-1,
+    which is exact after ceil(log2(leaf)) steps because the residual
+    I - DX0 is strictly triangular (nilpotent) and each step squares it.
+    No Newton step runs at a width over the MXU's: at n = 512 the
+    compiler counts 1/15 of the flop that Newton on the whole block
+    executed, in as many dependent products.  No triangular solve
+    anywhere: everything lowers to the systolic array.
     """
     import jax.numpy as jnp
     n = L.shape[0]
-    if n <= _INV_LEAF:
-        X = jnp.diag(1.0 / jnp.diag(L))
-        I = jnp.eye(n, dtype=L.dtype)
-        for _ in range(int(math.ceil(math.log2(max(n, 2)))) + 1):
-            X = jnp.matmul(X, 2.0 * I - jnp.matmul(L, X,
-                                                   precision=precision),
-                           precision=precision)
-        return X
-    h = n // 2
-    X11 = tri_inv(L[:h, :h], precision)
-    X22 = tri_inv(L[h:, h:], precision)
-    X21 = -jnp.matmul(X22, jnp.matmul(L[h:, :h], X11, precision=precision),
-                      precision=precision)
-    top = jnp.concatenate([X11, jnp.zeros((h, n - h), L.dtype)], axis=1)
-    return jnp.concatenate([top, jnp.concatenate([X21, X22], axis=1)],
-                           axis=0)
+    s = selected[("INV", n)] = inv_leaf(n)
+
+    def mm(a, b):
+        return jnp.matmul(a, b, precision=precision)
+
+    def blocks(rows, cols):
+        # the s x s blocks at block coordinates (rows[p], cols[p]),
+        # stacked by static slices
+        return jnp.stack([L[r * s:(r + 1) * s, c * s:(c + 1) * s]
+                          for r, c in zip(rows, cols)])
+
+    b = n // s
+    D = blocks(range(b), range(b))
+    eye = jnp.eye(s, dtype=L.dtype)
+    X = jnp.where(eye > 0, 1.0 / D, 0.0)
+    for _ in range(int(math.ceil(math.log2(max(s, 2)))) + 1):
+        X = mm(X, 2.0 * eye - mm(D, X))
+    while b > 1:
+        b //= 2
+        X11, X22 = (X.reshape(b, 2, s, s)[:, i] for i in (0, 1))
+        L21 = blocks(range(1, 2 * b, 2), range(0, 2 * b, 2))
+        X21 = -mm(X22, mm(L21, X11))
+        X = jnp.concatenate(
+            [jnp.concatenate([X11, jnp.zeros_like(X11)], axis=2),
+             jnp.concatenate([X21, X22], axis=2)], axis=1)
+        s *= 2
+    return X[0]
 
 
 def _k_potrf(precision):

@@ -29,7 +29,7 @@ so the 2mb x 2mb orthogonal transform is Phi^T = I - [I;V] T^T [I;V]^T
 (annihilation AND orthogonality hold identically — the general inverse
 in the textbook T^T = S (R + V^T B)^-1 collapses to triangular ones via
 M = -S^-T R'^T S).  TSQRT is then one mb-sized Cholesky + two
-triangular inverses (recursive Newton, apps/potrf.tri_inv) + matmuls,
+triangular inverses (batched block recursion, apps/potrf.tri_inv) + matmuls,
 and TSMQR three mb^3-class products, 6 mb^3 flop for the 4 that LAPACK
 counts:
 
@@ -476,7 +476,12 @@ def _wy_from_L(R, B, L, xp, ti, precision=None):
     d = xp.where(xp.diagonal(R) >= 0, -1.0, 1.0).astype(R.dtype)
     Rp = d[:, None] * L.T
     S = R - Rp
-    Sinv = ti(S.T).T                  # S upper-tri -> invert transpose
+    # S is upper triangular, so J S J is lower (J reverses the order):
+    # S^-1 = J (J S J)^-1 J.  S^T would do as well, but the inverse
+    # slicing a transpose into its blocks sets the compiler copying
+    # TSQRT's running f32 tile into the transposed layout and back
+    # (tests/test_chip_compile.py)
+    Sinv = ti(S[::-1, ::-1])[::-1, ::-1]
     V = mm(B, Sinv)
     Linv = ti(L)
     # R'^-T = (R'^T)^-1 = (L d)^-1 ... with the sign fold:

@@ -146,6 +146,27 @@ def test_potrf_diagonal_kernel_2048(spec):
              spec((mb, mb), jnp.float32))
 
 
+@pytest.mark.parametrize("n,precision", [(512, "HIGHEST"), (6144, "DEFAULT")])
+def test_tri_inv_runs_mxu_wide_leaves(spec, n, precision):
+    """The triangular inverse at the QR panel's ib block (HIGHEST) and
+    at the Cholesky tile (DEFAULT): Newton on a batch of leaves of 128
+    or less, not on the whole block.  Newton on one 512 leaf executed
+    5.38 GFLOP where the batch executes 0.36; the recursion down to 512
+    leaves ran 350 products at 6144 where the batch runs 28."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from parsec_tpu.apps import potrf
+    prec = getattr(jax.lax.Precision, precision)
+    c = _compile(lambda L: potrf.tri_inv(L, prec),
+                 spec((n, n), jnp.float32))
+    if n == 512:
+        assert c.cost_analysis()["flops"] <= 0.5e9
+    else:
+        assert len(re.findall(r"\bconvolution\(", c.as_text())) <= 32
+    assert potrf.selected[("INV", n)] <= 128
+
+
 @pytest.mark.parametrize("kernel", ["trsmr", "gemm"])
 def test_lu_update_kernels_6144_bf16(spec, kernel):
     """The LU cell's row-panel solve (by GETRF's f32 inverse, blocks
@@ -205,6 +226,7 @@ def test_qr_panel_kernels_ib512_2048(spec, kernel):
     body, a branch a block for what follows the block's place; TSQRT's
     reflector in two groups of 1024); full width compiled by hand
     (module docstring)."""
+    import re
     import jax.numpy as jnp
     from parsec_tpu.apps import qr
     mb, bf = 2048, jnp.bfloat16
@@ -217,6 +239,9 @@ def test_qr_panel_kernels_ib512_2048(spec, kernel):
     # one loop over the blocks, and no Householder expander behind it
     hlo = c.as_text()
     assert "while" in hlo and "householder" not in hlo.lower()
+    # the running f32 tile keeps its layout: no transposing copy of it
+    # in or around the loop (an inverse taken of a transpose turned it)
+    assert not re.findall(r"= f32\[%d,%d\]\S* copy\(" % (mb, mb), hlo)
 
 
 def test_pallas_blocked_matmul_compiles_to_mosaic(spec):

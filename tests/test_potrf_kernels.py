@@ -8,6 +8,8 @@ block edge cut to ``EDGE`` for the test, so that both forms are driven
 through the real factories; each case jits a wrapper of its own, so no
 trace made under the test's edge is found by another test."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -158,3 +160,43 @@ def test_taskpool_with_nan_above_the_diagonal(small_edge, mb, b):
     assert potrf.selected[("TRSM", mb)] == b
     assert clean < 1e-5
     assert factor(True) <= 1.5 * clean
+
+
+#: order -> the leaf tri_inv inverts by Newton: the order halved while
+#: it is even and wider than the MXU's 128; 96 at 3 x 2^k (384, 768, and
+#: the 6144 tile)
+INV_LEAVES = {96: 96, 128: 128, 384: 96, 512: 128, 768: 96, 2048: 128}
+
+
+@functools.lru_cache(maxsize=None)
+def _cholesky_factor(n, cond):
+    """float32 lower factor L of Q diag(lam) Q^T, lam from 1 to cond^-2:
+    cond(L) = cond."""
+    rng = np.random.default_rng(n)
+    Q, _ = np.linalg.qr(rng.standard_normal((n, n)))
+    A = (Q * np.geomspace(1.0, cond ** -2.0, n)) @ Q.T
+    return np.linalg.cholesky((A + A.T) / 2).astype(np.float32)
+
+
+@pytest.mark.parametrize("cond", [2.0, 1e3])
+@pytest.mark.parametrize("precision", ["DEFAULT", "HIGHEST"])
+@pytest.mark.parametrize("n", sorted(INV_LEAVES))
+def test_tri_inv_batched_leaves(n, precision, cond):
+    """The inverse from a batch of leaves: L X = I and X = inv(L) to
+    float32 working accuracy (the CPU runs every product in float32
+    whatever the precision asked), exact zeros above the diagonal, and
+    the leaf recorded for the order."""
+    import jax
+    from parsec_tpu.apps import potrf
+    L = _cholesky_factor(n, cond)
+    potrf.selected.pop(("INV", n), None)
+    prec = getattr(jax.lax.Precision, precision)
+    X = np.asarray(jax.jit(lambda a: potrf.tri_inv(a, prec))(L))
+    assert potrf.selected[("INV", n)] == potrf.inv_leaf(n) == INV_LEAVES[n]
+    assert X.dtype == np.float32
+    assert not np.triu(X, 1).any()
+    L64, X64 = L.astype(np.float64), X.astype(np.float64)
+    tol = 4 * np.finfo(np.float32).eps * max(1.0, cond / 100)
+    assert np.abs(L64 @ X64 - np.eye(n)).max() <= tol
+    ref = np.linalg.inv(L64)
+    assert np.abs(X64 - ref).max() <= tol * np.abs(ref).max()
