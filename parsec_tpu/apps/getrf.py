@@ -47,12 +47,17 @@ from typing import Optional
 
 import numpy as np
 
+from parsec_tpu.apps.pallas_kernels import PALLAS, pallas_update, update_form
 from parsec_tpu.apps.potrf import _cpu_trsm, _k_trsm, tri_blocks, tri_inv
 from parsec_tpu.core.taskpool import ParameterizedTaskpool
 from parsec_tpu.data.matrix import TiledMatrix
 from parsec_tpu.dsl.ptg.api import DATA, IN, NEW, OUT, PTG, Range, TASK
 
 _kernels = {}
+
+#: which form ran the update for every traced ("GEMM", tile order):
+#: "pallas" / "xla" (potrf.selected's rule)
+selected = {}
 
 #: a recursion node of this order or less is a leaf, factored directly
 #: (its triangles are inverted by ``potrf.tri_inv``)
@@ -213,6 +218,11 @@ def _k_gemm(precision):
     if fn is None:
         def fn(C, L, R):
             import jax.numpy as jnp
+            # potrf's rule: a large tile streams its own operand blocks
+            form = selected[("GEMM", C.shape[0])] = \
+                update_form(C, L, R, precision)
+            if form == PALLAS:
+                return pallas_update(C, L, R, nt=False)
             acc = jnp.matmul(L, R, precision=precision,
                              preferred_element_type=jnp.float32)
             return (C.astype(jnp.float32) - acc).astype(C.dtype)

@@ -11,7 +11,10 @@ computations"):
 - a blocked ``Ci + alpha * Ai @ Bi`` with a VMEM f32 accumulator and a
   K-innermost grid, bf16/f32 inputs straight onto the MXU;
 - selection via ``--mca gemm_pallas 1`` (apps/gemm.py consults it), or
-  call :func:`pallas_gemm_tile` directly as a PTG/DTD device body.
+  call :func:`pallas_gemm_tile` directly as a PTG/DTD device body;
+- the same program as the factorizations' trailing update
+  ``C - L op(R)``, in place on C (:func:`pallas_update`), which potrf's
+  and getrf's GEMM class take by shape (:func:`update_form`).
 
 Interpret mode is the CALLER's choice (``interpret=True`` — the CPU
 tests pass it); the runtime path never sets it, so on the runtime path a
@@ -23,7 +26,9 @@ Measured (v5e, 4096-tile GEMM through the runtime): the Pallas blocked
 kernel sustains ~36 TFLOP/s vs ~48 for the fused XLA matmul — XLA's MXU
 pipeline wins for plain GEMM, so it stays the default; the Pallas path
 is the seam for ops XLA does NOT fuse well (custom epilogues, quantized
-accumulation), selected per-kernel.
+accumulation), selected per-kernel.  Inside a fused wave of 6144 updates
+the streamed product wins: every product runs at the pace XLA's lone
+product gets only where its operand is prefetched (see update_form).
 """
 
 from __future__ import annotations
@@ -44,45 +49,78 @@ PALLAS, XLA = "pallas", "xla"
 
 @functools.lru_cache(maxsize=None)
 def _blocked_matmul(alpha: float, bm: int, bn: int, bk: int,
-                    interpret: bool):
+                    interpret: bool, nt: bool = False,
+                    in_place: bool = False):
+    """``run(A, B, C) -> C + alpha * A @ op(B)``, rounded to C's dtype:
+    op(B) = B, or B^T where ``nt`` (B is then n x k and its blocks are
+    read as they lie).  A block of C is loaded into an f32 VMEM
+    accumulator at the first step of the K-innermost grid, every block
+    product is summed into it, and it is rounded to C's dtype once, at
+    the last step, as XLA's ``(C - A @ B).astype`` rounds it (loading C
+    first leaves the last step one conversion: 0.5% faster at 6144 on a
+    v5e than adding C there).  ``in_place`` writes the result
+    into C's buffer (``input_output_aliases``): an output block is
+    written back after the only read of its own C block, so a donated C
+    costs no second tile."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
+    contract = (((1,), (1,)), ((), ())) if nt else (((1,), (0,)), ((), ()))
 
     def kernel(a_ref, b_ref, c_ref, o_ref, acc_ref):
         k = pl.program_id(2)
-        nk = pl.num_programs(2)
 
         @pl.when(k == 0)
         def _init():
             acc_ref[:, :] = c_ref[:, :].astype(jnp.float32)
 
-        prod = jax.lax.dot_general(
-            a_ref[:, :], b_ref[:, :], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_ref[:, :] += prod if alpha == 1.0 else alpha * prod
+        # summed into the accumulator in straight-line code (a product
+        # held across a branch cost 5% at 6144 on a v5e); the update's
+        # alpha = -1 subtracts it with no multiply
+        prod = jax.lax.dot_general(a_ref[:, :], b_ref[:, :], contract,
+                                   preferred_element_type=jnp.float32)
+        if alpha == -1.0:
+            acc_ref[:, :] -= prod
+        else:
+            acc_ref[:, :] += prod if alpha == 1.0 else alpha * prod
 
-        @pl.when(k == nk - 1)
+        @pl.when(k == pl.num_programs(2) - 1)
         def _fin():
             o_ref[:, :] = acc_ref[:, :].astype(o_ref.dtype)
 
     def run(Ai, Bi, Ci):
         m, kk = Ai.shape
-        _, n = Bi.shape
-        grid = (m // bm, n // bn, kk // bk)
+        n = Bi.shape[0] if nt else Bi.shape[1]
+        b_spec = pl.BlockSpec((bn, bk), lambda i, j, k: (j, k)) if nt \
+            else pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))
+        # two buffers of every block, and three f32 blocks: the
+        # accumulator, the product and their sum (what the TPU's compiler
+        # asks for at 1024 x 1024 x 1024 up to 2048 x 2048 x 512, within
+        # 2 MiB); its default scope is 16 MiB
+        item = Ai.dtype.itemsize
+        vmem = (2 * (bm + bn) * bk * item
+                + 4 * bm * bn * Ci.dtype.itemsize + 3 * bm * bn * 4)
         return pl.pallas_call(
             kernel,
-            grid=grid,
+            grid=(m // bm, n // bn, kk // bk),
             in_specs=[
                 pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-                pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
+                b_spec,
                 pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
             ],
             out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
             out_shape=jax.ShapeDtypeStruct(Ci.shape, Ci.dtype),
             scratch_shapes=scratch,
+            input_output_aliases={2: 0} if in_place else {},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=max(32 * 2 ** 20, vmem + 8 * 2 ** 20)),
+            cost_estimate=pl.CostEstimate(
+                flops=2 * m * n * kk, transcendentals=0,
+                bytes_accessed=(m * kk + kk * n) * item
+                + 2 * m * n * Ci.dtype.itemsize),
             interpret=interpret,
         )(Ai, Bi, Ci)
 
@@ -128,6 +166,55 @@ def use_pallas_gemm() -> bool:
         return bool(int(params.get("gemm_pallas", 0)))
     except (TypeError, ValueError):
         return False
+
+
+# ---------------------------------------------------------------------------
+# the factorizations' trailing update, C - L op(R), in place
+# ---------------------------------------------------------------------------
+
+#: the block of C and the depth of one step of the update's grid
+UPDATE_BLOCKS = (2048, 1024, 1024)
+#: the smallest tile order whose update streams its own blocks
+UPDATE_MIN_ORDER = 4096
+
+
+def update_form(C, L, R, precision=None) -> str:
+    """Which form computes ``C - L @ op(R)`` for these operands (the GEMM
+    class of apps/potrf.py and apps/getrf.py): PALLAS on the chip for
+    bf16 tiles at default precision whose order is ``UPDATE_MIN_ORDER``
+    or more and a multiple of ``UPDATE_BLOCKS``, else XLA.
+
+    Why: inside a fused wave XLA's own product reads its operands where
+    the program-wide memory-space assignment put them, and that has room
+    for one 6144 tile at a time in the core's fast memory, so the eight
+    products of one 8-wide program ran up to 7% apart on a v5e, those
+    that read both operands from HBM the slowest.  The Pallas product
+    streams its own blocks whatever the program around it does: at 6144
+    every product of the wave ran 2.44-2.45 ms where XLA's ran 2.42-2.63.
+    At 2048 XLA's product was the faster alone (0.093 ms against 0.106
+    at best) and in the wave, at every block shape tried, so
+    ``UPDATE_MIN_ORDER`` leaves such tiles to XLA (PERF.md §6)."""
+    import jax
+    import jax.numpy as jnp
+    m, n = C.shape
+    bm, bn, bk = UPDATE_BLOCKS
+    if (jax.default_backend() == "tpu" and precision is None
+            and all(x.dtype == jnp.bfloat16 for x in (C, L, R))
+            and min(m, n) >= UPDATE_MIN_ORDER
+            and not (m % bm or n % bn or L.shape[1] % bk)):
+        return PALLAS
+    return XLA
+
+
+def pallas_update(C, L, R, nt: bool, blocks=UPDATE_BLOCKS,
+                  interpret: bool = False):
+    """``C - L @ R^T`` (``nt``) or ``C - L @ R`` as the blocked Pallas
+    program, IN PLACE: the result takes C's buffer, so a donated C costs
+    no temporary.  ``blocks`` (bm, bn, bk) are clamped to the operands;
+    whether they fit is the caller's (:func:`update_form`)."""
+    m, n = C.shape
+    bm, bn, bk = (min(b, d) for b, d in zip(blocks, (m, n, L.shape[1])))
+    return _blocked_matmul(-1.0, bm, bn, bk, interpret, nt, True)(L, R, C)
 
 
 # ---------------------------------------------------------------------------

@@ -54,6 +54,7 @@ from typing import Optional
 
 import numpy as np
 
+from parsec_tpu.apps.pallas_kernels import PALLAS, pallas_update, update_form
 from parsec_tpu.core.taskpool import ParameterizedTaskpool
 from parsec_tpu.data.matrix import TiledMatrix
 from parsec_tpu.dsl.ptg.api import DATA, IN, NEW, OUT, PTG, Range, TASK
@@ -66,10 +67,11 @@ _kernels = {}
 #: as the array is wide and the block recursion does the rest
 _INV_LEAF = 128
 
-#: the number of blocks b an edge of the tile was cut into for every
-#: traced (class, tile order), 1 = the full product: what says the
-#: block-triangular form engaged (apps/pallas_kernels.py keeps such a
-#: map on its factories)
+#: for every traced (class, tile order): the number of blocks b an edge
+#: of the tile was cut into (SYRK, TRSM; 1 = the full product), the
+#: order of the Newton leaves (INV), or which form ran the update (GEMM:
+#: "pallas" / "xla") — what says each form engaged (apps/pallas_kernels.py
+#: keeps such a map on its factories)
 selected = {}
 
 #: blocks an edge, and the smallest block edge at which the blocked form
@@ -253,6 +255,12 @@ def _k_gemm(precision):
     if fn is None:
         def fn(C, L, R):
             import jax.numpy as jnp
+            # on the chip a large tile streams its own operand blocks
+            # (pallas_kernels.update_form says where and why)
+            form = selected[("GEMM", C.shape[0])] = \
+                update_form(C, L, R, precision)
+            if form == PALLAS:
+                return pallas_update(C, L, R, nt=True)
             acc = jnp.matmul(L, R.T, precision=precision,
                              preferred_element_type=jnp.float32)
             return (C.astype(jnp.float32) - acc).astype(C.dtype)

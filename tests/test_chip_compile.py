@@ -122,18 +122,39 @@ def test_potrf_update_kernels_6144_bf16(spec, kernel):
     assert ma.temp_size_in_bytes < MB * MB * 2
 
 
-def test_potrf_fused_gemm_wave_width8(spec):
+def _gemm_wave_width8(spec, mod, monkeypatch):
     """The width-8 trailing-update wave as the device layer builds it
-    (XlaKernel.jitted_fused, written flows donated)."""
+    (XlaKernel.jitted_fused, written flows donated), traced as on the
+    chip (the backend made to read "tpu"): all eight products are the
+    streamed Pallas program, each in place on its C, and the program
+    plans less than a tile beside them."""
+    import jax
     import jax.numpy as jnp
-    from parsec_tpu.apps import potrf
+    from parsec_tpu.apps.pallas_kernels import PALLAS
     from parsec_tpu.devices.xla import XlaKernel
-    k = XlaKernel(potrf._k_gemm(None), ["C", "L", "R"], ["C", "L", "R"],
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    k = XlaKernel(mod._k_gemm(None), ["C", "L", "R"], ["C", "L", "R"],
                   ["C"])
     bf = spec((MB, MB), jnp.bfloat16)
     c = k.jitted_fused(True, 8).lower(*[bf] * 24).compile()
+    assert mod.selected[("GEMM", MB)] == PALLAS
+    assert c.as_text().count('custom_call_target="tpu_custom_call"') == 8
     # 8 donated C tiles: the wave's outputs alias its inputs
-    assert c.memory_analysis().alias_size_in_bytes >= 8 * MB * MB * 2
+    ma = c.memory_analysis()
+    assert ma.alias_size_in_bytes >= 8 * MB * MB * 2
+    assert ma.temp_size_in_bytes < MB * MB * 2
+
+
+def test_potrf_fused_gemm_wave_width8(spec, monkeypatch):
+    """potrf's wave, C - L R^T."""
+    from parsec_tpu.apps import potrf
+    _gemm_wave_width8(spec, potrf, monkeypatch)
+
+
+def test_getrf_fused_gemm_wave_width8(spec, monkeypatch):
+    """getrf's wave, C - L U."""
+    from parsec_tpu.apps import getrf
+    _gemm_wave_width8(spec, getrf, monkeypatch)
 
 
 def test_potrf_diagonal_kernel_2048(spec):

@@ -149,3 +149,72 @@ def test_blocked_geqrt_with_pallas_gram():
     Q = np.asarray(out["Q"], np.float64)
     assert np.abs(Q.T @ Q - np.eye(mb)).max() < 5e-5
     assert np.abs(Q @ R - T).max() / np.abs(T).max() < 1e-5
+
+
+def _bf16(rng, n, scale=1.0):
+    import ml_dtypes
+    return (scale * rng.standard_normal((n, n))).astype(ml_dtypes.bfloat16)
+
+
+@pytest.mark.parametrize("app", ["potrf", "getrf"])
+def test_update_kernel_matches_both_gemm_classes(app):
+    """The factorizations' trailing update ``C - L R^T`` (potrf) and
+    ``C - L U`` (getrf) as the in-place Pallas program, interpreted on a
+    2 x 2 x 2 grid of 128 blocks, against the XLA form of the app's own
+    GEMM kernel (what it runs off the chip), bf16 in and out: equal but
+    for a rounding of the last bf16 place where the f32 sums differ in
+    order.  The product's result takes C's buffer."""
+    import importlib
+    import jax
+    from parsec_tpu.apps.pallas_kernels import pallas_update
+    mod = importlib.import_module(f"parsec_tpu.apps.{app}")
+    nt = app == "potrf"
+    rng = np.random.default_rng(5)
+    C, L, R = _bf16(rng, 256), _bf16(rng, 256, 0.1), _bf16(rng, 256, 0.1)
+
+    def streamed(C, L, R):
+        return pallas_update(C, L, R, nt, blocks=(128, 128, 128),
+                             interpret=True)
+    got = np.asarray(jax.jit(streamed)(C, L, R), np.float32)
+    ref = np.asarray(jax.jit(mod._k_gemm(None))(C, L, R), np.float32)
+    assert mod.selected[("GEMM", 256)] == XLA
+    f32 = [np.asarray(x, np.float32) for x in (C, L, R)]
+    exact = f32[0] - f32[1] @ (f32[2].T if nt else f32[2])
+    # one bf16 rounding of the exact result, on either side
+    assert np.abs(got - exact).max() <= 2 ** -7 * np.abs(exact).max()
+    assert np.abs(got - ref).max() <= 2 ** -7 * np.abs(ref).max()
+    assert np.mean(got == ref) > 0.95
+    eqn, = jax.make_jaxpr(streamed)(C, L, R).eqns
+    assert eqn.primitive.name == "pallas_call"
+    assert eqn.params["input_output_aliases"] == ((2, 0),)
+
+
+@pytest.mark.parametrize("app", ["potrf", "getrf"])
+def test_update_form_by_shape(app, monkeypatch):
+    """Which form each GEMM tile takes, traced as on the chip (the
+    backend is made to read "tpu"; nothing runs): the streamed Pallas
+    product for a bf16 6144 tile; XLA's for a tile under the smallest
+    order, one that the blocks do not divide, f32 tiles and a stated
+    precision — and off the chip whatever the shape.  ``selected`` says
+    which."""
+    import importlib
+    import jax
+    import jax.numpy as jnp
+    from parsec_tpu.apps.pallas_kernels import (UPDATE_BLOCKS,
+                                                UPDATE_MIN_ORDER)
+    mod = importlib.import_module(f"parsec_tpu.apps.{app}")
+
+    def form(n, dtype=jnp.bfloat16, precision=None):
+        t = jax.ShapeDtypeStruct((n, n), dtype)
+        # a fresh function each time: JAX keeps the trace of one
+        out = jax.eval_shape(lambda *a: mod._k_gemm(precision)(*a), t, t, t)
+        assert out.shape == (n, n) and out.dtype == dtype
+        return mod.selected[("GEMM", n)]
+    assert form(6144) == XLA                   # the CPU backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert form(6144) == PALLAS
+    assert form(UPDATE_MIN_ORDER) == PALLAS
+    assert form(2048) == XLA
+    assert form(6144 + UPDATE_BLOCKS[0] // 2) == XLA
+    assert form(6144, jnp.float32) == XLA
+    assert form(6144, precision="highest") == XLA
