@@ -2,19 +2,21 @@
 
 The runtime's device bodies are ordinarily single fused XLA ops (jnp
 matmul & friends) — XLA already schedules those onto the MXU well.  This
-module provides hand-written Pallas alternatives for the hottest tile
-op, the GEMM accumulate step, demonstrating the kernel seam the
+module provides hand-written Pallas kernels for the tile ops where a
+kernel of our own wins or XLA cannot work in place, the kernel seam the
 reference fills with cuBLAS/user CUDA kernels (reference: the BODY
 [type=CUDA] incarnations; SURVEY §7 "tile kernels as Pallas/XLA
 computations"):
 
 - a blocked ``Ci + alpha * Ai @ Bi`` with a VMEM f32 accumulator and a
-  K-innermost grid, bf16/f32 inputs straight onto the MXU;
-- selection via ``--mca gemm_pallas 1`` (apps/gemm.py consults it), or
-  call :func:`pallas_gemm_tile` directly as a PTG/DTD device body;
+  K-innermost grid, bf16/f32 inputs straight onto the MXU, selected via
+  ``--mca gemm_pallas 1`` (apps/gemm.py consults it), or called as
+  :func:`pallas_gemm_tile` directly as a PTG/DTD device body;
 - the same program as the factorizations' trailing update
   ``C - L op(R)``, in place on C (:func:`pallas_update`), which potrf's
-  and getrf's GEMM class take by shape (:func:`update_form`).
+  and getrf's GEMM class take by shape (:func:`update_form`);
+- the 1-D stencil's sweep, in place on its tile
+  (:func:`pallas_sweep_tile`).
 
 Interpret mode is the CALLER's choice (``interpret=True`` — the CPU
 tests pass it); the runtime path never sets it, so on the runtime path a
@@ -215,109 +217,6 @@ def pallas_update(C, L, R, nt: bool, blocks=UPDATE_BLOCKS,
     m, n = C.shape
     bm, bn, bk = (min(b, d) for b, d in zip(blocks, (m, n, L.shape[1])))
     return _blocked_matmul(-1.0, bm, bn, bk, interpret, nt, True)(L, R, C)
-
-
-# ---------------------------------------------------------------------------
-# blocked Gram kernel: the HIGHEST-precision hot spot of the
-# inner-blocked QR panels (apps/qr.py _cholqr2 — G = X^T X of an
-# mb x ib column block, computed per ib-block of every GEQRT/TSQRT)
-# ---------------------------------------------------------------------------
-
-params.register("qr_pallas_gram", 0,
-                "use the hand-written Pallas blocked Gram kernel for "
-                "the inner-blocked QR panel construction (apps/qr.py) "
-                "instead of the fused XLA matmul")
-
-
-@functools.lru_cache(maxsize=None)
-def _blocked_gram(bn: int, bk: int, interpret: bool):
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    scratch = [pltpu.VMEM((bn, bn), jnp.float32)]
-
-    def kernel(xi_ref, xj_ref, o_ref, acc_ref):
-        k = pl.program_id(2)
-        nk = pl.num_programs(2)
-
-        @pl.when(k == 0)
-        def _init():
-            acc_ref[:, :] = jnp.zeros_like(acc_ref)
-
-        # X_i^T X_j with f32 accumulation; HIGHEST so the Gram matrix —
-        # the cond^2-sensitive input of the panel Cholesky — never rides
-        # the MXU's bf16 passes (apps/qr.py precision discipline)
-        acc_ref[:, :] += jax.lax.dot_general(
-            xi_ref[:, :], xj_ref[:, :], (((0,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
-
-        @pl.when(k == nk - 1)
-        def _fin():
-            o_ref[:, :] = acc_ref[:, :].astype(o_ref.dtype)
-
-    def run(X):
-        m, n = X.shape
-        grid = (n // bn, n // bn, m // bk)
-        return pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((bk, bn), lambda i, j, k: (k, i)),
-                pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            ],
-            out_specs=pl.BlockSpec((bn, bn), lambda i, j, k: (i, j)),
-            out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),
-            scratch_shapes=scratch,
-            interpret=interpret,
-        )(X, X)
-
-    return run
-
-
-def pallas_gram_tile(bn: int = 256, bk: int = 512,
-                     interpret: bool = False):
-    """``fn(X) -> X^T X`` (f32, HIGHEST) as a blocked Pallas program:
-    K-innermost grid over X's rows with an f32 VMEM accumulator, the
-    same shape discipline (and ``selected`` map, keyed by ``(m, n)``)
-    as :func:`pallas_gemm_tile`.
-
-    f32 input only: Mosaic refuses the HIGHEST-precision product of
-    bf16 operands ("Bad lhs type" on ``tpu.matmul`` with
-    ``contract_precision<fp32>``, compiled for a v5e), and the QR panel
-    construction this kernel serves runs in f32 anyway (apps/qr.py
-    upcasts the tile first) — so any other dtype is a TypeError here,
-    not a Mosaic failure at the first launch."""
-
-    def fn(X):
-        import jax
-        import jax.numpy as jnp
-        if X.dtype != jnp.float32:
-            raise TypeError(
-                f"pallas_gram_tile takes float32 input, got {X.dtype}: "
-                "Mosaic refuses the HIGHEST-precision tpu.matmul of "
-                "narrower operands (cast at the call)")
-        m, n = X.shape
-        cbn, cbk = min(bn, n), min(bk, m)
-        aligned = m % 128 == 0 and n % 128 == 0
-        if not aligned or m % cbk or n % cbn:
-            fn.selected[(m, n)] = XLA
-            return jnp.matmul(X.T, X,
-                              precision=jax.lax.Precision.HIGHEST,
-                              preferred_element_type=jnp.float32)
-        fn.selected[(m, n)] = PALLAS
-        return _blocked_gram(cbn, cbk, interpret)(X)
-
-    fn.selected = {}
-    return fn
-
-
-def use_pallas_qr_gram() -> bool:
-    try:
-        return bool(int(params.get("qr_pallas_gram", 0)))
-    except (TypeError, ValueError):
-        return False
 
 
 # ---------------------------------------------------------------------------

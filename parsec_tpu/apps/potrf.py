@@ -434,8 +434,6 @@ def potrf_taskpool(A: TiledMatrix, device: str = "tpu",
     # panel chain costs ONE dispatch round trip instead of two plus the
     # Python scheduling latency between them.  TRSM co-locates on the
     # diagonal tile's device so the whole panel is one wave there.
-    # A/B knob: PARSEC_MCA_DEVICE_FUSE_PANEL=0 restores the per-kernel
-    # panel path.
     tp.task_classes["POTRF"].properties["fuse_chain"] = ("W", "TRSM")
     tp.task_classes["TRSM"].properties["coaffinity"] = \
         lambda loc, A=A: A(loc["k"], loc["k"])

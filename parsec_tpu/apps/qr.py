@@ -267,16 +267,14 @@ def _cholqr_passes(blocks, jnp, hi, gram):
     return X, R
 
 
-def _cholqr2(cols, jnp, hi, gram=None):
+def _cholqr2(cols, jnp, hi):
     """Cholesky-QR of one mb x ib column block at HIGHEST precision:
     returns (Q, R) with Q orthonormal and R upper triangular
     (``_cholqr_passes``: two passes on a well-conditioned block — one
     loses orthogonality as cond^2*eps — shifted ones first on a block
-    whose Gram matrix is not positive definite in f32).  ``gram`` swaps
-    the Gram products for a hand-written kernel
-    (apps/pallas_kernels.pallas_gram_tile)."""
-    gram = gram or (lambda X: jnp.matmul(X.T, X, precision=hi))
-    (Q,), R = _cholqr_passes((cols,), jnp, hi, lambda X: gram(X[0]))
+    whose Gram matrix is not positive definite in f32)."""
+    (Q,), R = _cholqr_passes(
+        (cols,), jnp, hi, lambda X: jnp.matmul(X[0].T, X[0], precision=hi))
     return Q, R
 
 
@@ -324,7 +322,7 @@ def _put(Mb, X, k):
     return lax.dynamic_update_index_in_dim(Mb, X, k, 0)
 
 
-def _geqrt_blocked(Tf, ib, jnp, hi, up, gram=None):
+def _geqrt_blocked(Tf, ib, jnp, hi, up):
     """Inner-blocked GEQRT (module docstring): per-block CholeskyQR2 +
     one re-projection against the accumulated basis at HIGHEST
     (O(mb^2*ib) total), trailing columns updated at ``up`` precision
@@ -354,8 +352,7 @@ def _geqrt_blocked(Tf, ib, jnp, hi, up, gram=None):
         def project(k, c):
             return c - mm(blk(Q, k), lax.dynamic_slice(
                 prj, (k * ib, 0), (ib, ib)), hi)
-        Qj, Rjj = _cholqr2(lax.fori_loop(0, j, project, cols), jnp, hi,
-                           gram=gram)
+        Qj, Rjj = _cholqr2(lax.fori_loop(0, j, project, cols), jnp, hi)
 
         # the blocks after this one: A_k -= Qj (Qj^T A_k), the
         # coefficients are R[J, K]
@@ -391,10 +388,7 @@ def _k(name, maker):
     return fn
 
 
-def _mk_geqrt(ib: int = 0, gram=None):
-    """``gram``: replacement for the panel blocks' Gram products
-    (apps/pallas_kernels.pallas_gram_tile); None = the fused XLA
-    matmul."""
+def _mk_geqrt(ib: int = 0):
     def fn(T, Q):
         import jax
         import jax.numpy as jnp
@@ -415,8 +409,7 @@ def _mk_geqrt(ib: int = 0, gram=None):
         if 0 < ib < mb and mb % ib == 0:
             # inner-blocked panel: the blocks run as one traced body; an
             # ill-conditioned block takes shifted passes (_cholqr_passes)
-            R, Qm = _geqrt_blocked(Tf, ib, jnp, hi, _update_precision(),
-                                   gram=gram)
+            R, Qm = _geqrt_blocked(Tf, ib, jnp, hi, _update_precision())
             return {"T": R.astype(T.dtype), "Q": Qm.astype(T.dtype)}
 
         Ls, _Gs, dg = _scaled_chol(jnp.matmul(Tf.T, Tf, precision=hi), jnp)
@@ -682,9 +675,6 @@ def qr_taskpool(A: TiledMatrix, device: str = "tpu") -> ParameterizedTaskpool:
     # keep the panel-wide factor (float64 products have no ridge to miss)
     W = group_width(mb, ib) if use_device else mb
     upd = str(params.get("qr_update_precision", "default")).lower()
-    from parsec_tpu.apps.pallas_kernels import (pallas_gram_tile,
-                                                use_pallas_qr_gram)
-    pg = use_pallas_qr_gram()
     # Owner-computes discipline for the final R tiles: the LAST TSQRT of
     # column k (and the last TSMQR of each row-k tile) runs where
     # A(NT-1, k) lives, but its R output belongs home at A(k, *).  On
@@ -729,9 +719,7 @@ def qr_taskpool(A: TiledMatrix, device: str = "tpu") -> ParameterizedTaskpool:
     def cpu_geqrt(T, Q):
         q, r = np.linalg.qr(np.asarray(T), mode="complete")
         return {"T": r, "Q": q}
-    bodies(tb, _k(("geqrt", ib, upd, pg),
-                  lambda: _mk_geqrt(ib, pallas_gram_tile() if pg else None)),
-           cpu_geqrt)
+    bodies(tb, _k(("geqrt", ib, upd), lambda: _mk_geqrt(ib)), cpu_geqrt)
 
     # UNMQR(k, n): apply Q1^T across the k-th block row
     tb = p.task("UNMQR", k=Range(0, NT - 2), n=Range(lambda k: k + 1,

@@ -650,7 +650,7 @@ class IciEngine:
             if datum.preferred_device in self._jdev:
                 return datum.preferred_device
             coaff = tc.properties.get("coaffinity")
-            if coaff is not None and int(params.get("device_fuse_panel", 1)):
+            if coaff is not None:
                 sp = self._home(coaff(locals_).resolve())
                 if sp is not None:
                     return sp

@@ -47,7 +47,7 @@ class DeviceStats:
         self.fused_tasks = 0
         #: cross-panel chain fusion counters: launches that traced a held
         #: panel chain into a consumer wave, and how many tasks (held +
-        #: wave) rode them (devices/xla.py device_fuse_panel)
+        #: wave) rode them (devices/xla.py _run_chain)
         self.chained_launches = 0
         self.chained_tasks = 0
         #: chain programs this device built: a (head, successor wave)
@@ -252,16 +252,12 @@ class DeviceRegistry:
         property (locals -> data ref) prefers the device holding that
         datum — e.g. TRSM(m,k)/TSQRT(m,k) follow their panel's diagonal
         tile A(k,k), so the POTRF->TRSM / TSQRT column chain stays on
-        ONE device and cross-panel chain fusion (devices/xla.py
-        device_fuse_panel, which also gates this hint) can trace it into
-        a single launch."""
+        ONE device and cross-panel chain fusion (devices/xla.py) can
+        trace it into a single launch."""
         coaff = task.task_class.properties.get("coaffinity")
         if coaff is None:
             return None
-        from parsec_tpu.utils.mca import params
         try:
-            if not int(params.get("device_fuse_panel", 1)):
-                return None
             datum = coaff(task.locals).resolve()
         except Exception:
             return None

@@ -47,13 +47,6 @@ from parsec_tpu.utils.output import debug_verbose, warning
 
 params.register("device_inflight_depth", 8,
                 "max in-flight device tasks per XLA device")
-params.register("device_fuse_bg", 1,
-                "compile a fused-width program in a background thread "
-                "the first time this process asks for it on a device: "
-                "its waves wait for the compile's signal up to "
-                "device_fuse_warm_wait_ms and run as singles past that; "
-                "every later taskpool finds the width ready (0 = compile "
-                "synchronously on first use, stalling the wave)")
 params.register("device_fuse_warm_wait_ms", 3000.0,
                 "how long, from the moment a fused-width program's "
                 "background compile was asked for, its waves wait for "
@@ -77,8 +70,6 @@ params.register("device_runahead", 256,
                 "so keep this well above the DAG's width)")
 params.register("device_mem_mb", 0,
                 "device copy-cache capacity in MiB (0 = unlimited)")
-params.register("device_donate", 1,
-                "donate written-flow input buffers to XLA (TPU/GPU only)")
 params.register("device_max_faults", 0,
                 "disable a device after this many launch faults and fall "
                 "back to other incarnations (0 = fail the context, like "
@@ -89,29 +80,6 @@ params.register("device_fuse", 8,
                 "SYRK/GEMM trailing-update wave of a dense factorization "
                 "rides a single dispatch, amortizing per-launch latency; "
                 "1 disables)")
-params.register("device_fuse_panel", 1,
-                "cross-panel chain fusion: a task class carrying a "
-                "'fuse_chain' property (flow, successor class: "
-                "POTRF->TRSM, GEQRT/TSQRT->TSQRT, GETRF->TRSMR) is HELD "
-                "at dispatch — "
-                "its outputs become deferred placeholders, its deps "
-                "release eagerly as usual — and its kernel is traced "
-                "INTO the launch of its declared successor, ONE head a "
-                "program, so a panel link costs no dispatch of its own "
-                "and the set of chain programs follows from the classes "
-                "and the fused widths alone.  0 restores the per-kernel "
-                "panel path (the A/B attribution knob)")
-params.register("device_fuse_donate", 1,
-                "allow input-buffer donation inside CHAINED launches "
-                "(device_fuse_panel programs).  Default ON since the "
-                "ROADMAP-mandated soak (the slow "
-                "test_fused_chain_donation_soak: 50+ fused-chain "
-                "geqrf/potrf iterations under delay_dispatch load, 0 "
-                "wrong results) — the r8 wrong-R aliasing was "
-                "root-caused and fixed at the zero-copy device_put "
-                "stage-in (device_put_private).  0 is the off-switch "
-                "regression guard.  Plain launches donate regardless "
-                "(device_donate)")
 params.register("device_dispatchers", 2,
                 "manager (launch) threads per XLA device: a dispatch "
                 "blocks its thread through stage-in and any first-use "
@@ -340,8 +308,6 @@ class XlaKernel:
         ``mgr.warm_wait`` span and in ``warm_waits``."""
         if n <= 1:
             return True
-        if not int(params.get("device_fuse_bg", 1)):
-            return True    # kill-switch: compile widths synchronously
         if sig is None:
             sig = self.args_sig(flat[:len(self.arg_names)])
         key = ("w", self.cls, donate, n, sig,
@@ -879,10 +845,9 @@ class XlaDevice(Device):
         self.platform = jdev.platform
         self.weight = weight
         self.kind = "tpu" if self.platform == "tpu" else "xla"
-        self._donate = (bool(params.get("device_donate", 1))
-                        and self.platform in ("tpu", "gpu", "cuda", "rocm"))
-        self._chain_donate = self._donate and \
-            bool(int(params.get("device_fuse_donate", 1)))
+        #: whether this platform donates input buffers to XLA: plain,
+        #: chained and head-alone launches all read it
+        self._donate = self.platform in ("tpu", "gpu", "cuda", "rocm")
         self._depth = max(1, int(params.get("device_inflight_depth", 8)))
         self._runahead = max(self._depth,
                              int(params.get("device_runahead", 256)))
@@ -1386,7 +1351,7 @@ class XlaDevice(Device):
         where the caller has it."""
         donate = self._donate and not self._donation_hazard(spec, flat)
         ici = self.es.context.ici if cover and n > 1 else None
-        if ici is not None and int(params.get("device_fuse_bg", 1)):
+        if ici is not None:
             # several chips, and a class that meets in waves: what this
             # chip runs of it, every chip will
             _fuse_warmer.cover(spec, donate, n, flat, self,
@@ -1410,21 +1375,16 @@ class XlaDevice(Device):
         return fused, [spec.bind_outputs(r) for r in results]
 
     # ------------------------------------------------------------------
-    # cross-panel chain fusion (device_fuse_panel): hold chain heads,
-    # trace them into their consumer wave's launch
+    # cross-panel chain fusion: hold chain heads, trace them into their
+    # consumer wave's launch
     # ------------------------------------------------------------------
     def _chain_eligible(self, task: Task, spec: XlaKernel) -> bool:
-        """Whether this task may be chain-held: the knob is on, its
-        class names a 'fuse_chain' (flow, successor class), the run is
-        single-rank (remote activations must never see a Deferred
-        payload), and the chain flow has at least one task successor to
-        force the eventual launch — all of them, where the context
-        drives several chips, expected on this one."""
-        try:
-            if not int(params.get("device_fuse_panel", 1)):
-                return False
-        except (TypeError, ValueError):
-            return False
+        """Whether this task may be chain-held: its class names a
+        'fuse_chain' (flow, successor class), the run is single-rank
+        (remote activations must never see a Deferred payload), and the
+        chain flow has at least one task successor to force the eventual
+        launch — all of them, where the context drives several chips,
+        expected on this one."""
         fc = task.task_class.properties.get("fuse_chain")
         if not fc:
             return False
@@ -1560,18 +1520,11 @@ class XlaDevice(Device):
         k = len(wave_spec.arg_names)
         wave_descs = tuple(spec_descs(wave_spec, flat[t * k:(t + 1) * k])
                            for t in range(n))
-        # REGRESSION GUARD (r8, the geqrf wrong-R flake): chained
-        # launches donate NOTHING by default.  A/B under load +
-        # delay_dispatch fault plans attributed the intermittent wrong
-        # R to donation in chained programs (fuse=1/donate=1: 2 wrong
-        # in 22 runs; fuse=1/donate=0 and fuse=0: 0 in 46) — a chain's
-        # leaves were staged at HOLD time, long before this launch, and
-        # the leaf-used-once rule cannot see every later reference the
-        # way the plain path's same-instant _donation_hazard can.
-        # device_fuse_donate=1 re-enables it for root-cause work.
+        # a donated buffer may be overwritten by its first reader, so a
+        # chain donates only the leaves its program reads once
         donate = tuple(sorted(j for j in donatable
                               if leaf_uses.get(j) == 1)) \
-            if self._chain_donate else ()
+            if self._donate else ()
         key = (((hd.spec.fn, hd.spec.cls, node_descs[0]),),
                (wave_spec.fn, wave_spec.cls), wave_descs, donate)
         hash(key)    # unhashable static -> the caller's failure path
@@ -1590,7 +1543,7 @@ class XlaDevice(Device):
         (``jit_parsec_<CLS>``, the one a link that is never held runs):
         a head forced without its successor adds no program."""
         flat = [a.array if isinstance(a, Deferred) else a for a in hd.flat]
-        donate = self._chain_donate \
+        donate = self._donate \
             and not self._donation_hazard(hd.spec, flat)
         return hd.spec.bind_outputs(self._call(hd.spec.jitted(donate), flat))
 

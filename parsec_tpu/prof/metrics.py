@@ -758,6 +758,11 @@ class RuntimeMetrics:
         return out
 
     def _collect_devices(self) -> List[dict]:
+        """Every counter of each device's ``DeviceStats`` (devices/
+        device.py), named from its slot: ``parsec_device_<slot>_total``
+        with the device label, but for ``executed_tasks``, exported as
+        ``parsec_device_tasks_total``, and the high-water mark
+        ``replica_bytes_peak``, a gauge."""
         ctx = self.context
         if ctx is None:
             return []
@@ -767,43 +772,16 @@ class RuntimeMetrics:
             if st is None:
                 continue
             labels = {"device": getattr(d, "name", "?")}
-            for key, metric in (
-                    ("executed_tasks", "parsec_device_tasks_total"),
-                    ("bytes_in", "parsec_device_bytes_in_total"),
-                    ("bytes_out", "parsec_device_bytes_out_total"),
-                    ("evictions", "parsec_device_evictions_total"),
-                    ("chained_launches",
-                     "parsec_device_chained_launches_total"),
-                    ("chained_tasks", "parsec_device_chained_tasks_total"),
-                    ("chain_programs",
-                     "parsec_device_chain_programs_total"),
-                    # the counts at the boundaries of the device
-                    # module's thread-state spans (devices/device.py)
-                    ("launches", "parsec_device_launches_total"),
-                    ("held_tasks", "parsec_device_held_tasks_total"),
-                    ("defused_waves", "parsec_device_defused_waves_total"),
-                    ("starved_waits", "parsec_device_starved_waits_total"),
-                    ("inflight_waits",
-                     "parsec_device_inflight_waits_total"),
-                    ("compiles", "parsec_device_compiles_total"),
-                    ("warm_waits", "parsec_device_warm_waits_total"),
-                    ("release_passes",
-                     "parsec_device_release_passes_total"),
-                    ("resident_flows",
-                     "parsec_device_resident_flows_total"),
-                    ("staged_flows",
-                     "parsec_device_staged_flows_total"),
-                    ("snapshot_flows",
-                     "parsec_device_snapshot_flows_total"),
-                    ("snapshot_bytes",
-                     "parsec_device_snapshot_bytes_total"),
-                    ("direct_submits",
-                     "parsec_device_direct_submits_total"),
-                    ("heads_forced",
-                     "parsec_device_heads_forced_total")):
-                v = getattr(st, key, None)
-                if isinstance(v, (int, float)) and v:
-                    out.append(counter_sample(metric, v, labels))
+            for k, v in st.as_dict().items():
+                if not v:
+                    continue
+                if k == "replica_bytes_peak":
+                    out.append(gauge_sample(
+                        "parsec_device_replica_bytes_peak", v, labels))
+                else:
+                    k = "tasks" if k == "executed_tasks" else k
+                    out.append(counter_sample(
+                        f"parsec_device_{k}_total", v, labels))
         return out
 
     def _collect_summed(self, attr: str, prefix: str) -> List[dict]:

@@ -277,20 +277,6 @@ def test_pallas_blocked_matmul_compiles_to_mosaic(spec):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_pallas_blocked_gram_compiles_to_mosaic(spec):
-    """bn=256 / bk=512 on the 6144 x 512 f32 column block the blocked
-    QR panel hands it; bf16 input is turned away before Mosaic sees it
-    (Mosaic: "Bad lhs type" on tpu.matmul with contract_precision<fp32>)."""
-    import jax.numpy as jnp
-    from parsec_tpu.apps.pallas_kernels import PALLAS, pallas_gram_tile
-    fn = pallas_gram_tile()
-    c = _compile(fn, spec((MB, 512), jnp.float32))
-    assert fn.selected == {(MB, 512): PALLAS}
-    assert "tpu_custom_call" in c.as_text()
-    with pytest.raises(TypeError, match="float32"):
-        _compile(fn, spec((MB, 512), jnp.bfloat16))
-
-
 def test_stencil_sweep_wave_is_in_place(spec):
     """The stencil cell's program (PR 37): an eight-wide wave of the
     in-place Pallas sweep over 4096 x 8192 float32 tiles with every

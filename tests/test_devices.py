@@ -320,14 +320,14 @@ def test_wavefront_fusion_batches_same_class_waves():
             rtol=1e-6)
 
 
-@pytest.mark.parametrize("fuse_panel", [0, 1])
-def test_chain_links_go_alone_on_the_per_kernel_panel_path(fuse_panel):
+def test_chain_links_go_alone_on_the_per_kernel_panel_path():
     """The same 16-wide wave as above, but of a class that names a
-    fuse_chain (POTRF, GEQRT, TSQRT do).  Every link is dispatched
-    alone, chain fusion on or off (PR 31: which links of OTHER panels'
-    chains meet in the queue is timing, and a wave of them costs the
-    heaviest kernel's compile once more per width — minutes for a
-    two-wide TSQRT wave at mb=6144 on a v5e)."""
+    fuse_chain (POTRF, GEQRT, TSQRT do) whose chain flow has no task
+    successor, so no link is ever held.  Every link is dispatched
+    alone (PR 31: which links of OTHER panels' chains meet in the queue
+    is timing, and a wave of them costs the heaviest kernel's compile
+    once more per width — minutes for a two-wide TSQRT wave at mb=6144
+    on a v5e)."""
     import time as _time
 
     from parsec_tpu.core.context import Context
@@ -343,7 +343,6 @@ def test_chain_links_go_alone_on_the_per_kernel_panel_path(fuse_panel):
 
     params.set("device_fuse", 8)
     params.set("device_max", 1)
-    params.set("device_fuse_panel", fuse_panel)
     try:
         with Context(nb_cores=2) as ctx:
             p = PTG("links", MT=MT)
@@ -362,7 +361,6 @@ def test_chain_links_go_alone_on_the_per_kernel_panel_path(fuse_panel):
     finally:
         params.unset("device_fuse")
         params.unset("device_max")
-        params.unset("device_fuse_panel")
     for n in range(MT):
         np.testing.assert_allclose(
             np.asarray(A.data_of(0, n).pull_to_host().payload), 2.0 * n)
@@ -373,39 +371,26 @@ def test_cross_panel_chain_fusion_potrf():
     the device (its deps release eagerly with Deferred payloads) and
     its kernel is traced INTO the TRSM wave's launch, so the panel
     chain rides one dispatch.  The result must match numpy and the
-    chained counters must show the fusion actually ran; the A/B knob
-    (PARSEC_MCA_DEVICE_FUSE_PANEL=0) must reproduce the per-kernel
-    path with zero chained launches."""
+    chained counters must show the fusion actually ran."""
     from parsec_tpu.apps.potrf import potrf_taskpool
 
-    def run(fuse_panel):
-        mb, nt = 16, 5
-        n = nt * mb
-        rng = np.random.default_rng(21)
-        B = rng.standard_normal((n, n)).astype(np.float32)
-        spd = (B @ B.T + n * np.eye(n)).astype(np.float32)
-        A = TwoDimBlockCyclic(mb=mb, nb=mb, lm=n,
-                              ln=n).from_array(spd.copy())
-        params.set("device_fuse_panel", fuse_panel)
-        try:
-            with Context(nb_cores=4) as ctx:
-                if not ctx.device_registry.accelerators:
-                    pytest.skip("no accelerator attached")
-                ctx.add_taskpool(potrf_taskpool(A, device="tpu"))
-                ctx.wait(timeout=120)
-                st = ctx.device_registry.accelerators[0].stats
-                chained = (st.chained_launches, st.chained_tasks)
-        finally:
-            params.unset("device_fuse_panel")
-        L = np.tril(A.to_array())
-        err = np.abs(L @ L.T - spd).max() / np.abs(spd).max()
-        assert err < 1e-4, err
-        return chained
-
-    launches, tasks = run(1)
+    mb, nt = 16, 5
+    n = nt * mb
+    rng = np.random.default_rng(21)
+    B = rng.standard_normal((n, n)).astype(np.float32)
+    spd = (B @ B.T + n * np.eye(n)).astype(np.float32)
+    A = TwoDimBlockCyclic(mb=mb, nb=mb, lm=n, ln=n).from_array(spd.copy())
+    with Context(nb_cores=4) as ctx:
+        if not ctx.device_registry.accelerators:
+            pytest.skip("no accelerator attached")
+        ctx.add_taskpool(potrf_taskpool(A, device="tpu"))
+        ctx.wait(timeout=120)
+        st = ctx.device_registry.accelerators[0].stats
+        launches, tasks = st.chained_launches, st.chained_tasks
+    L = np.tril(A.to_array())
+    err = np.abs(L @ L.T - spd).max() / np.abs(spd).max()
+    assert err < 1e-4, err
     assert launches > 0 and tasks > launches   # chains really fused
-    launches, tasks = run(0)                   # A/B attribution knob
-    assert launches == 0 and tasks == 0
 
 
 def test_cross_panel_chain_fusion_qr_column():

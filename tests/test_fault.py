@@ -530,9 +530,8 @@ def test_dtd_region_ordering_under_delay_plan():
 @pytest.mark.slow
 def test_geqrf_chain_under_dispatch_delay():
     """The r7 geqrf wrong-R flake's replay conditions: chained panel
-    dispatch (device_fuse_panel=1, the default) with seeded
-    delay_dispatch perturbation.  The r8 regression guard (chained
-    launches never donate, device_fuse_donate=0) must keep R correct."""
+    dispatch with seeded delay_dispatch perturbation must keep R
+    correct."""
     from parsec_tpu.apps.qr import qr_taskpool
     from parsec_tpu.core.context import Context
     from parsec_tpu.data.matrix import TwoDimBlockCyclic
@@ -680,28 +679,20 @@ def test_flightrec_autopsy_names_bundle(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# the donation soak (ISSUE 8 satellite): device_fuse_donate default flip
+# the donation soak: chained launches donate the leaves they read once
 # ---------------------------------------------------------------------------
-
-def test_fuse_donate_default_on():
-    """Post-soak default: chained launches donate; the knob remains the
-    off-switch."""
-    assert int(params.get("device_fuse_donate", 1)) == 1
-
 
 @pytest.mark.slow
 def test_fused_chain_donation_soak():
-    """The ROADMAP-mandated soak behind the device_fuse_donate=1 flip:
-    50+ fused-chain geqrf/potrf iterations under seeded delay_dispatch
+    """The soak that guards donation in chained launches: 50+
+    fused-chain geqrf/potrf iterations under seeded delay_dispatch
     load, asserting ZERO wrong results.  (The r8 wrong-R reproduced at
-    ~2/22 under this load before the device_put_private fix; the flip
-    rides this green loop.)"""
+    ~2/22 under this load before the device_put_private fix.)"""
     from parsec_tpu.apps.potrf import potrf_taskpool
     from parsec_tpu.apps.qr import qr_taskpool
     from parsec_tpu.core.context import Context
     from parsec_tpu.data.matrix import TwoDimBlockCyclic
 
-    assert int(params.get("device_fuse_donate", 1)) == 1
     faultinject.arm("seed=53;delay_dispatch=ms=3,p=0.3")
     try:
         mb, nt = 16, 5

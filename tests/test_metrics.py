@@ -9,6 +9,9 @@ import re
 import socket
 import time
 
+import pytest
+
+from parsec_tpu.devices.device import DeviceStats
 from parsec_tpu.prof.metrics import (BUCKET_BOUNDS, Counter, Family, Gauge,
                                      Histogram, bucket_index,
                                      counter_sample, gauge_sample,
@@ -191,6 +194,27 @@ def test_causal_tracer_keeps_ready_stamp_without_metrics():
             assert not ctx._ready_stamp
     finally:
         params.unset("metrics_enabled")
+
+
+@pytest.mark.parametrize("counter", DeviceStats.__slots__)
+def test_every_device_counter_is_exported(counter):
+    """A counter of DeviceStats reaches the scrape under its own name
+    with the device label, no table to edit: parsec_device_<name>_total,
+    but for executed_tasks (parsec_device_tasks_total) and the
+    high-water mark replica_bytes_peak, a gauge without the suffix."""
+    from parsec_tpu.core.context import Context
+    with Context(nb_cores=1) as ctx:
+        dev = ctx.device_registry.devices[-1]
+        setattr(dev.stats, counter, 7)
+        got = [s for s in ctx.metrics._collect_devices()
+               if s["l"] == {"device": dev.name} and s["v"] == 7.0]
+    if counter == "replica_bytes_peak":
+        want = ("parsec_device_replica_bytes_peak", "gauge")
+    elif counter == "executed_tasks":
+        want = ("parsec_device_tasks_total", "counter")
+    else:
+        want = (f"parsec_device_{counter}_total", "counter")
+    assert [(s["n"], s["t"]) for s in got] == [want]
 
 
 # ---------------------------------------------------------------------------

@@ -90,65 +90,14 @@ def test_gemm_taskpool_with_pallas_kernel():
     assert _rel_err(C.to_array(), a @ b) < 5e-2
 
 
-def test_pallas_gram_matches():
-    """Blocked Gram kernel (the inner-blocked QR panel's HIGHEST hot
-    spot): X^T X with f32 VMEM accumulation over the K-innermost grid."""
-    import jax
-    rng = np.random.default_rng(2)
-    X = rng.standard_normal((512, 256)).astype(np.float32)
-    from parsec_tpu.apps.pallas_kernels import pallas_gram_tile
-    fn = pallas_gram_tile(bn=128, bk=128, interpret=True)
-    got = np.asarray(jax.jit(fn)(X))
-    ref = X.T @ X
-    assert _rel_err(got, ref) < 1e-4
-    assert fn.selected == {(512, 256): PALLAS}
-
-
 def test_pallas_runtime_path_never_interprets():
     """Without ``interpret=True`` the kernels compile for the attached
     device or raise — on this CPU they raise, they do not quietly run
-    in interpret mode — and the Gram kernel turns bf16 input away with
-    a TypeError instead of a Mosaic failure at the first launch."""
+    in interpret mode."""
     import jax
-    import ml_dtypes
-    from parsec_tpu.apps.pallas_kernels import pallas_gram_tile
     a = np.zeros((128, 128), np.float32)
     with pytest.raises(Exception, match="(?i)interpret|pallas|mosaic|tpu"):
         jax.block_until_ready(jax.jit(pallas_gemm_tile(1.0))(a, a, a))
-    with pytest.raises(TypeError, match="float32"):
-        jax.jit(pallas_gram_tile(interpret=True))(
-            np.zeros((256, 128), ml_dtypes.bfloat16))
-
-
-def test_pallas_gram_unaligned_fallback():
-    import jax
-    rng = np.random.default_rng(3)
-    X = rng.standard_normal((100, 36)).astype(np.float32)
-    from parsec_tpu.apps.pallas_kernels import pallas_gram_tile
-    fn = pallas_gram_tile()
-    got = np.asarray(jax.jit(fn)(X))
-    assert _rel_err(got, X.T @ X) < 1e-4
-    assert fn.selected == {(100, 36): XLA}
-
-
-def test_blocked_geqrt_with_pallas_gram():
-    """The blocked panel's Gram products routed through the Pallas
-    kernel (what the qr_pallas_gram MCA knob selects in qr_taskpool);
-    the factorization contract is unchanged."""
-    import jax.numpy as jnp
-    from parsec_tpu.apps.pallas_kernels import pallas_gram_tile
-    from parsec_tpu.apps.qr import _mk_geqrt
-    mb, ib = 256, 128
-    rng = np.random.default_rng(4)
-    T = rng.standard_normal((mb, mb)).astype(np.float32)
-    gram = pallas_gram_tile(interpret=True)
-    out = _mk_geqrt(ib, gram=gram)(
-        jnp.asarray(T), jnp.zeros((mb, mb), jnp.float32))
-    assert set(gram.selected.values()) == {PALLAS}
-    R = np.asarray(out["T"], np.float64)
-    Q = np.asarray(out["Q"], np.float64)
-    assert np.abs(Q.T @ Q - np.eye(mb)).max() < 5e-5
-    assert np.abs(Q @ R - T).max() / np.abs(T).max() < 1e-5
 
 
 def _bf16(rng, n, scale=1.0):
